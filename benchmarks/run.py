@@ -15,9 +15,11 @@ Prints ``name,us_per_call,derived`` CSV rows. Modules:
 ``--preset quick`` runs only the `ticks` module at CI size — the bench
 CI job's configuration. ``--json PATH`` additionally persists every
 emitted row in the bench-trajectory format (schema ``repro-bench/v1``:
-``{"schema", "jax", "device_count", "rows": [{name, us_per_call,
-derived}]}``) consumed by `benchmarks/compare.py` and committed as
-`benchmarks/baseline.json`.
+``{"schema", "jax", "platform", "device_kind", "device_count", "rows":
+[{name, us_per_call, derived}]}``) consumed by `benchmarks/compare.py`.
+
+The harness measures the TPU: it exits non-zero when JAX finds none, so a
+CPU timing is never recorded as a benchmark number.
 """
 from __future__ import annotations
 
@@ -47,6 +49,15 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="write all emitted rows as bench-trajectory JSON")
     args = ap.parse_args()
+
+    import jax
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        sys.exit(f"benchmarks.run measures the TPU; JAX found platform "
+                 f"{dev.platform!r} ({dev.device_kind})")
 
     from benchmarks import (table3_update_time, table4_construction,
                             table5_affected, table6_directed,
@@ -88,8 +99,8 @@ def main() -> None:
     print(f"# {len(all_rows)} rows in {time.time() - t0:.1f}s",
           file=sys.stderr)
     if args.json:
-        import jax
         payload = {"schema": "repro-bench/v1", "jax": jax.__version__,
+                   "platform": dev.platform, "device_kind": dev.device_kind,
                    "device_count": len(jax.devices()),
                    "rows": _rows_to_json(all_rows)}
         with open(args.json, "w") as f:

@@ -377,7 +377,15 @@ def _saturation_loop(name: str, n: int, deg: int, backend: str,
     inside the gate's 25% budget.
     """
     import shutil
+    import sys
     import tempfile
+
+    if jax.default_backend() == "tpu":
+        # This process already holds the chip, and each replica process
+        # would need one of its own (launch/replica.py refuses that).
+        print(f"# {name} skipped: the replica tier needs one TPU chip per "
+              f"process and this process holds the chip", file=sys.stderr)
+        return []
 
     from repro.launch import replica
     from repro.launch.config import (EngineSpec, GraphSpec, ServeSpec,

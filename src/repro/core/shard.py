@@ -55,7 +55,6 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.graphs.coo import (Graph, BatchUpdate, INF_D, apply_batch,
@@ -141,13 +140,13 @@ def shard_build_labelling(mesh, g: Graph, landmarks: jax.Array,
         return dist, hub, highway
 
     rv = P(MAINT_AXES, None)
-    dist, hub, highway = shard_map(
+    dist, hub, highway = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(MAINT_AXES), P(), P()),
         out_specs=(rv, rv, rv),
-        # jax 0.4.37 has no replication rule for while_loop (the fixpoint
-        # sweeps); every output is fully plane-sharded anyway.
-        check_rep=False)(g, landmarks, landmarks, plan)
+        # Every output is fully plane-sharded, so the varying-axis check
+        # has nothing to prove; it stays off for the fixpoint sweeps.
+        check_vma=False)(g, landmarks, landmarks, plan)
     return HighwayLabelling(landmarks.astype(jnp.int32), dist, hub, highway)
 
 
@@ -196,13 +195,12 @@ def shard_batchhl_update(mesh, g_old: Graph, batch: BatchUpdate,
         return ndist, nhub, highway, aff
 
     rv = P(MAINT_AXES, None)
-    ndist, nhub, highway, aff = shard_map(
+    ndist, nhub, highway, aff = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P(), P()),
         out_specs=(rv, rv, rv, rv),
-        # No replication rule for while_loop on this jax pin; outputs are
-        # fully plane-sharded anyway.
-        check_rep=False)(
+        # Outputs are fully plane-sharded; no varying-axis check needed.
+        check_vma=False)(
             g_new, batch, labelling.dist, labelling.hub,
             labelling.landmarks, labelling.landmarks, plan)
     new_labelling = HighwayLabelling(labelling.landmarks, ndist, nhub,
@@ -221,7 +219,7 @@ def affected_vertices(mesh, aff: jax.Array) -> jax.Array:
         any_loc = jnp.any(aff_loc, axis=0).astype(jnp.int32)
         return jax.lax.pmax(any_loc, MAINT_AXES) > 0
 
-    return shard_map(body, mesh=mesh,
+    return jax.shard_map(body, mesh=mesh,
                      in_specs=(P(MAINT_AXES, None),),
                      out_specs=P(None))(aff)
 
@@ -257,11 +255,11 @@ def shard_search_seed(mesh, g_new: Graph, batch: BatchUpdate,
         return seed, seeded, dist, hub_mask
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P()),
         out_specs=(rv, rv, rv, rv),
-        check_rep=False)(g_new, batch, dist, hub, landmarks, landmarks)
+        check_vma=False)(g_new, batch, dist, hub, landmarks, landmarks)
 
 
 @partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"))
@@ -284,11 +282,11 @@ def shard_search_chunk(mesh, g_new: Graph, best: jax.Array, seed: jax.Array,
         return cur, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, P()),
-        check_rep=False)(g_new, best, seed, bound, hub_mask, plan)
+        check_vma=False)(g_new, best, seed, bound, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh",))
@@ -301,11 +299,11 @@ def shard_repair_start(mesh, g_new: Graph, aff: jax.Array, dist: jax.Array,
         return repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask)
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=rv,
-        check_rep=False)(g_new, aff, dist, hub, hub_mask, plan)
+        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"))
@@ -323,11 +321,11 @@ def shard_repair_chunk(mesh, g_new: Graph, cur: jax.Array, aff: jax.Array,
         return out, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, P()),
         out_specs=(rv, P()),
-        check_rep=False)(g_new, cur, aff, hub_mask, plan)
+        check_vma=False)(g_new, cur, aff, hub_mask, plan)
 
 
 # --- fused chunk twins (seed + K sweeps in one dispatch; donated planes) ---
@@ -368,11 +366,11 @@ def shard_fused_search_start(mesh, g_new: Graph, batch: BatchUpdate,
         return best, seed, seeded, bound, hub_mask, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P(), P()),
         out_specs=(rv, rv, rv, rv, rv, P()),
-        check_rep=False)(g_new, batch, dist, hub, landmarks, landmarks,
+        check_vma=False)(g_new, batch, dist, hub, landmarks, landmarks,
                          plan)
 
 
@@ -397,11 +395,11 @@ def shard_fused_search_chunk(mesh, g_new: Graph, best: jax.Array,
         return cur, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, P()),
-        check_rep=False)(g_new, best, seed, bound, hub_mask, plan)
+        check_vma=False)(g_new, best, seed, bound, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"))
@@ -421,11 +419,11 @@ def shard_fused_repair_start_chunk(mesh, g_new: Graph, aff: jax.Array,
         return cur, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, P()),
-        check_rep=False)(g_new, aff, dist, hub, hub_mask, plan)
+        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"), donate_argnums=(2,))
@@ -443,11 +441,11 @@ def shard_fused_repair_chunk(mesh, g_new: Graph, cur: jax.Array,
         return out, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, P()),
         out_specs=(rv, P()),
-        check_rep=False)(g_new, cur, aff, hub_mask, plan)
+        check_vma=False)(g_new, cur, aff, hub_mask, plan)
 
 
 # --- frontier chunk twins (change propagation, DESIGN.md §10) --------------
@@ -491,11 +489,11 @@ def shard_search_chunk_frontier(mesh, g_new: Graph, best: jax.Array,
         return cur, front, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, rv, P()),
         out_specs=(rv, rv, P()),
-        check_rep=False)(g_new, best, front, seed, bound, hub_mask, plan)
+        check_vma=False)(g_new, best, front, seed, bound, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh",))
@@ -510,11 +508,11 @@ def shard_repair_start_frontier(mesh, g_new: Graph, aff: jax.Array,
         return base, plan.frontier.changed_blocks(base < INF_KEY2)
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, rv),
-        check_rep=False)(g_new, aff, dist, hub, hub_mask, plan)
+        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"))
@@ -536,11 +534,11 @@ def shard_repair_chunk_frontier(mesh, g_new: Graph, cur: jax.Array,
         return out, front, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, rv, P()),
-        check_rep=False)(g_new, cur, front, aff, hub_mask, plan)
+        check_vma=False)(g_new, cur, front, aff, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"))
@@ -574,11 +572,11 @@ def shard_fused_search_start_frontier(mesh, g_new: Graph,
         return best, front, seed, seeded, bound, hub_mask, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P(), P()),
         out_specs=(rv, rv, rv, rv, rv, rv, P()),
-        check_rep=False)(g_new, batch, dist, hub, landmarks, landmarks,
+        check_vma=False)(g_new, batch, dist, hub, landmarks, landmarks,
                          plan)
 
 
@@ -602,11 +600,11 @@ def shard_fused_search_chunk_frontier(mesh, g_new: Graph, best: jax.Array,
         return cur, front, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, rv, P()),
         out_specs=(rv, rv, P()),
-        check_rep=False)(g_new, best, front, seed, bound, hub_mask, plan)
+        check_vma=False)(g_new, best, front, seed, bound, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"))
@@ -632,11 +630,11 @@ def shard_fused_repair_start_chunk_frontier(mesh, g_new: Graph,
         return cur, front, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, rv, P()),
-        check_rep=False)(g_new, aff, dist, hub, hub_mask, plan)
+        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"), donate_argnums=(2,))
@@ -657,11 +655,11 @@ def shard_fused_repair_chunk_frontier(mesh, g_new: Graph, cur: jax.Array,
         return out, front, changed > 0
 
     rv = P(MAINT_AXES, None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
         out_specs=(rv, rv, P()),
-        check_rep=False)(g_new, cur, front, aff, hub_mask, plan)
+        check_vma=False)(g_new, cur, front, aff, hub_mask, plan)
 
 
 @partial(jax.jit, static_argnames=("mesh",))
@@ -680,11 +678,11 @@ def shard_update_finish(mesh, aff: jax.Array, settled: jax.Array,
         return ndist, nhub, highway
 
     rv = P(MAINT_AXES, None)
-    ndist, nhub, highway = shard_map(
+    ndist, nhub, highway = jax.shard_map(
         body, mesh=mesh,
         in_specs=(rv, rv, rv, rv, P()),
         out_specs=(rv, rv, rv),
-        check_rep=False)(aff, settled, dist, hub, landmarks)
+        check_vma=False)(aff, settled, dist, hub, landmarks)
     return HighwayLabelling(landmarks.astype(jnp.int32), ndist, nhub,
                             highway)
 
@@ -693,6 +691,7 @@ def shard_update_finish(mesh, aff: jax.Array, settled: jax.Array,
 # Queries
 # ---------------------------------------------------------------------------
 
+@partial(jax.jit, static_argnames=("mesh", "max_steps", "use_kernel"))
 def shard_batched_query(mesh, g: Graph, labelling: HighwayLabelling,
                         s: jax.Array, t: jax.Array, max_steps: int = 64,
                         use_kernel: bool = False,
@@ -707,30 +706,16 @@ def shard_batched_query(mesh, g: Graph, labelling: HighwayLabelling,
     shard the BiBFS batch composition differs from the unsharded run, but
     the returned min(d_sparse, d⊤) is composition-independent: BFS levels
     are exact, so d_sparse is exact whenever it undercuts d⊤ and is
-    dominated by d⊤ otherwise.
+    dominated by d⊤ otherwise. The padded path is locked in by the B=37
+    sweep over data>1 meshes in `_selftest` below (run as
+    tests/test_shard.py::test_multidevice_parity_selftest).
     """
-    # The pad/slice stays *outside* the jitted core: on the pinned jax,
-    # GSPMD mis-reshards a concatenate produced inside the same jit as a
-    # multi-axis shard_map consuming it with P("data") — lanes interleave
-    # across devices. The padded path is locked in by the B=37 sweep over
-    # data>1 meshes in `_selftest` below (run as
-    # tests/test_shard.py::test_multidevice_parity_selftest).
+    _check_planes(labelling.num_landmarks, mesh.shape["model"], "model")
     b = s.shape[0]
     pad = (-b) % mesh.shape["data"]
     if pad:
         s = jnp.concatenate([s, jnp.zeros((pad,), s.dtype)])
         t = jnp.concatenate([t, jnp.zeros((pad,), t.dtype)])
-    out = _shard_query_core(mesh, g, labelling, s, t, max_steps, use_kernel,
-                            plan)
-    return out[:b]
-
-
-@partial(jax.jit, static_argnames=("mesh", "max_steps", "use_kernel"))
-def _shard_query_core(mesh, g: Graph, labelling: HighwayLabelling,
-                      s: jax.Array, t: jax.Array, max_steps: int,
-                      use_kernel: bool,
-                      plan: RelaxPlan | None) -> jax.Array:
-    _check_planes(labelling.num_landmarks, mesh.shape["model"], "model")
 
     def body(g, dist, hub, own, landmarks_full, highway_rows, s, t, plan):
         # Eq. 3 — tropical contraction with the landmark axis sharded:
@@ -765,17 +750,17 @@ def _shard_query_core(mesh, g: Graph, labelling: HighwayLabelling,
         return jnp.where(out >= INF_D, INF_D, out)
 
     qv = P("model", None)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), qv, qv, P("model"), P(), qv, P("data"), P("data"),
                   P()),
         out_specs=P("data"),
-        # check_rep can't see through the BiBFS while_loop; replication
-        # over `model` holds by construction (all body inputs are either
-        # replicated or pmin-merged before the loop).
-        check_rep=False)(
+        # Replication over `model` holds by construction (all body inputs
+        # are either replicated or pmin-merged before the BiBFS loop), so
+        # the varying-axis check is off.
+        check_vma=False)(
             g, labelling.dist, labelling.hub, labelling.landmarks,
-            labelling.landmarks, labelling.highway, s, t, plan)
+            labelling.landmarks, labelling.highway, s, t, plan)[:b]
 
 
 # ---------------------------------------------------------------------------

@@ -143,6 +143,25 @@ def grow(g: Graph, *, capacity: int | None = None,
                  jnp.concatenate([g.w, jnp.zeros((pad,), jnp.int32)]), n)
 
 
+def _deletion_hits(g: Graph, b: BatchUpdate) -> jax.Array:
+    """bool[E2]: slots whose undirected canonical (min, max) endpoints
+    match a valid deletion row of `b`. The [E2, U] compare fuses into the
+    reduction under jit; run eagerly it would materialize E2·U bools
+    (8 GiB at 2^23 slots and U = 1024)."""
+    del_mask_u = b.is_del & b.valid
+    g_lo = jnp.minimum(g.src, g.dst)
+    g_hi = jnp.maximum(g.src, g.dst)
+    b_lo = jnp.where(del_mask_u, jnp.minimum(b.src, b.dst), -1)
+    b_hi = jnp.where(del_mask_u, jnp.maximum(b.src, b.dst), -1)
+    return jnp.any((g_lo[:, None] == b_lo[None, :])
+                   & (g_hi[:, None] == b_hi[None, :]), axis=1)
+
+
+@jax.jit
+def _freed_slots(g: Graph, b: BatchUpdate) -> jax.Array:
+    return jnp.sum(_deletion_hits(g, b) & g.valid)
+
+
 def batch_requirements(g: Graph, b: BatchUpdate) -> tuple[int, int]:
     """Host-side (required_capacity, required_n) to apply `b` to `g`.
 
@@ -161,14 +180,7 @@ def batch_requirements(g: Graph, b: BatchUpdate) -> tuple[int, int]:
     # Re-weights update a live slot in place — they consume no capacity.
     n_ins = int(((~is_del) & (~is_rew) & valid).sum())
     occupied_pairs = int(jnp.sum(g.valid)) // 2
-    del_mask_u = b.is_del & b.valid
-    g_lo = jnp.minimum(g.src, g.dst)
-    g_hi = jnp.maximum(g.src, g.dst)
-    b_lo = jnp.where(del_mask_u, jnp.minimum(b.src, b.dst), -1)
-    b_hi = jnp.where(del_mask_u, jnp.maximum(b.src, b.dst), -1)
-    hit = jnp.any((g_lo[:, None] == b_lo[None, :])
-                  & (g_hi[:, None] == b_hi[None, :]), axis=1) & g.valid
-    freed_pairs = int(jnp.sum(hit)) // 2
+    freed_pairs = int(_freed_slots(g, b)) // 2
     ids = np.concatenate([np.asarray(b.src)[valid], np.asarray(b.dst)[valid]])
     required_n = int(ids.max()) + 1 if ids.size else 0
     return occupied_pairs - freed_pairs + n_ins, required_n
@@ -221,14 +233,7 @@ def apply_batch(g: Graph, b: BatchUpdate) -> Graph:
     tick. One compile per (capacity, batch-pad) shape pair.
     """
     # --- deletions ---------------------------------------------------------
-    # Undirected match on canonical (min, max) endpoints; [E2, U] compare.
-    del_mask_u = b.is_del & b.valid
-    g_lo = jnp.minimum(g.src, g.dst)
-    g_hi = jnp.maximum(g.src, g.dst)
-    b_lo = jnp.where(del_mask_u, jnp.minimum(b.src, b.dst), -1)
-    b_hi = jnp.where(del_mask_u, jnp.maximum(b.src, b.dst), -1)
-    hit = jnp.any((g_lo[:, None] == b_lo[None, :])
-                  & (g_hi[:, None] == b_hi[None, :]), axis=1)
+    hit = _deletion_hits(g, b)
     valid = g.valid & ~hit
     # Freed slots drop their weight with their validity, so a graph's slot
     # arrays are a pure function of its update history (split-batch
@@ -241,6 +246,8 @@ def apply_batch(g: Graph, b: BatchUpdate) -> Graph:
     # live in G after this batch's deletions, and both direction slots of
     # the pair update together.
     rew_mask_u = b.is_rew & b.valid
+    g_lo = jnp.minimum(g.src, g.dst)
+    g_hi = jnp.maximum(g.src, g.dst)
     r_lo = jnp.where(rew_mask_u, jnp.minimum(b.src, b.dst), -1)
     r_hi = jnp.where(rew_mask_u, jnp.maximum(b.src, b.dst), -1)
     rhit = ((g_lo[:, None] == r_lo[None, :])

@@ -6,13 +6,24 @@ GraphCast-style configs; molecule batches feed SchNet/DimeNet/MACE.
 """
 from __future__ import annotations
 
+import functools
 import math
 
 import numpy as np
 
 
 def barabasi_albert(n: int, m: int, seed: int = 0) -> np.ndarray:
-    """BA preferential attachment; returns unique undirected edges [E, 2]."""
+    """BA preferential attachment; returns unique undirected edges [E, 2].
+
+    The generator is a Python loop (about half a minute at 2^20
+    vertices), so each (n, m, seed) is built once per process; callers
+    get their own copy.
+    """
+    return _barabasi_albert(n, m, seed).copy()
+
+
+@functools.lru_cache(maxsize=4)
+def _barabasi_albert(n: int, m: int, seed: int) -> np.ndarray:
     rng = np.random.default_rng(seed)
     targets = list(range(m))
     repeated: list[int] = []

@@ -45,8 +45,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 INF32 = 1 << 29  # plain int: pallas kernels must not capture traced constants
+LANES = 128      # TPU vector lane width: tile rows are padded to a multiple
 
 
 def _relax_kernel(keys_ref, src_ref, dstloc_ref, valid_ref, step_ref, o_ref):
@@ -65,54 +67,68 @@ def _relax_kernel(keys_ref, src_ref, dstloc_ref, valid_ref, step_ref, o_ref):
     o_ref[...] = out[None, None, :]
 
 
-def _relax_sweep_kernel(keys_ref, hub_ref, src_ref, dstloc_ref, mask_ref,
-                        w_ref, params_ref, o_ref):
-    """Generalized sweep: weighted extend (step·w / saturate-at-inf /
-    hub bit-clear) + mask."""
-    keys = keys_ref[...]          # [V] int32 (full shard)
-    hub = hub_ref[0, 0]           # [BV] int32: dst-block hub flags
-    src = src_ref[0, 0]           # [BE]
-    dstloc = dstloc_ref[0, 0]     # [BE] local dst in [0, BV)
-    mask = mask_ref[0, 0]         # [BE] int32: per-sweep edge validity
-    w = w_ref[0, 0]               # [BE] int32: per-sweep edge weight
+def _relax_sweep_kernel(params_ref, keys_ref, dstloc_ref, mask_ref, w_ref,
+                        *refs):
+    """Generalized sweep over one tile row: weighted extend (step·w /
+    saturate-at-inf / hub bit-clear) + mask, then a destination min.
+
+    Edge streams arrive as [C, 128] lane rows (C = BE/128); the source
+    keys and destination hub flags were gathered per edge in XLA, since
+    Mosaic lowers no 1-D gather or scatter. The scatter-min becomes a
+    broadcast compare-and-min: every 128-edge lane row is compared with
+    the [BV] destination ids laid along sublanes and min-folded into a
+    [BV, 128] accumulator, which one transpose + sublane min reduces to
+    the [1, BV] output tile. `refs` is (hub_ref, o_ref), or (o_ref,) for
+    a sweep without hub bit-clearing.
+    """
+    *hub_ref, o_ref = refs
     step = params_ref[0]
     inf = params_ref[1]
     clear = params_ref[2]
+    bv = o_ref.shape[-1]
+    v_ids = jax.lax.broadcasted_iota(jnp.int32, (bv, LANES), 0)
 
-    gathered = jnp.take(keys, src, axis=0)
-    # Saturating weighted extend: keys and step·w are both non-negative
-    # (step ≤ 4, w ≤ INF_D keeps the product in range), so the int32 sum
-    # overflows iff it wraps negative — clamp those to inf rather than
-    # letting a near-inf key pass a max-weight edge as a small key.
-    s = gathered + step * w
-    cand = jnp.minimum(jnp.where(s < 0, inf, s), inf)
-    hub_e = jnp.take(hub, dstloc, axis=0)
-    cand = jnp.where(hub_e != 0, cand & ~clear, cand)
-    cand = jnp.where(mask != 0, cand, inf)
-    out = jnp.full((o_ref.shape[-1],), inf, jnp.int32)
-    out = out.at[dstloc].min(cand)
-    o_ref[...] = out[None, None, :]
+    def fold(c, acc):
+        row = pl.ds(c, 1)
+        # Saturating weighted extend: keys and step·w are both
+        # non-negative (step ≤ 4, w ≤ INF_D keeps the product in range),
+        # so the int32 sum overflows iff it wraps negative — clamp those
+        # to inf rather than letting a near-inf key pass a max-weight
+        # edge as a small key.
+        s = keys_ref[row, :] + step * w_ref[row, :]
+        cand = jnp.minimum(jnp.where(s < 0, inf, s), inf)
+        if hub_ref:
+            cand = jnp.where(hub_ref[0][row, :] != 0, cand & ~clear, cand)
+        cand = jnp.where(mask_ref[row, :] != 0, cand, inf)     # [1, 128]
+        hit = v_ids == dstloc_ref[row, :]                        # [BV, 128]
+        return jnp.minimum(acc, jnp.where(hit, cand, inf))
+
+    acc = jax.lax.fori_loop(0, keys_ref.shape[0], fold,
+                            jnp.full((bv, LANES), inf, jnp.int32))
+    o_ref[...] = jnp.min(acc.T, axis=0, keepdims=True)
 
 
 def block_edges_topology(src: np.ndarray, dst: np.ndarray, keep: np.ndarray,
                          n: int, block_v: int, block_e: int | None = None):
     """Host-side tiling: group the kept edge slots by destination block.
 
-    Returns (src_t [NR, BE], dstloc_t [NR, BE], perm_t [NR, BE],
-    slot_t [NR, BE], rowblk [NR], block_v). `perm_t` maps each tile slot
+    Returns (src_t [NR, W], dstloc_t [NR, W], perm_t [NR, W],
+    slot_t [NR, W], rowblk [NR], block_v). `perm_t` maps each tile slot
     back to its original edge index so per-sweep masks (validity churn,
     repair boundary/interior masks) can be re-tiled on device with one
     gather; `slot_t` is 0 on padding slots. Done once per graph topology.
 
-    Without `block_e`, BE is the largest per-block edge count and NR = NB:
-    one tile row per destination block (`rowblk` is the identity). On
-    power-law graphs that single hub block inflates every row, so a tuned
-    `block_e` caps BE and *chunks* oversized blocks into ceil(count/BE)
-    rows — `rowblk[r]` names the destination block row r feeds, rows of
-    one block are consecutive, and total padding is bounded by NB·BE
-    instead of NB·max-degree-block. Every block keeps at least one row
+    Each row holds at most BE edges and is padded to the lane width
+    W = ceil(BE / 128)·128, the row shape the TPU kernel tiles. A block
+    with more than BE edges is *chunked* into ceil(count/BE) rows —
+    `rowblk[r]` names the destination block row r feeds, rows of one
+    block are consecutive, and every block keeps at least one row
     (possibly all-padding) so reducing rows by `rowblk` yields a value
-    for every block.
+    for every block. `block_e` sets BE; without it BE is the largest
+    per-block count, capped at the mean per-block count rounded up to
+    128. On power-law graphs that cap keeps the hub block from widening
+    every row: total slots stay within NB·(mean + 128) + E instead of
+    NB·max-block.
     """
     keep = np.asarray(keep, bool)
     idx = np.flatnonzero(keep).astype(np.int64)
@@ -121,13 +137,19 @@ def block_edges_topology(src: np.ndarray, dst: np.ndarray, keep: np.ndarray,
     order = np.argsort(dst_k // block_v, kind="stable")
     src_k, dst_k, idx = src_k[order], dst_k[order], idx[order]
     counts = np.bincount(dst_k // block_v, minlength=nb)
-    be = block_e or max(int(counts.max() if counts.size else 0), 8)
+    if block_e:
+        be = block_e
+    else:
+        mean = -(-src_k.size // nb) if nb else 0
+        be = max(min(int(counts.max() if counts.size else 0),
+                     -(-mean // LANES) * LANES), 8)
+    width = -(-be // LANES) * LANES
     rows_per_block = np.maximum(-(-counts // be), 1)
     nr = int(rows_per_block.sum())
-    src_t = np.zeros((nr, be), np.int32)
-    dst_t = np.zeros((nr, be), np.int32)
-    perm_t = np.zeros((nr, be), np.int32)
-    slot_t = np.zeros((nr, be), np.int32)
+    src_t = np.zeros((nr, width), np.int32)
+    dst_t = np.zeros((nr, width), np.int32)
+    perm_t = np.zeros((nr, width), np.int32)
+    slot_t = np.zeros((nr, width), np.int32)
     rowblk = np.repeat(np.arange(nb, dtype=np.int32),
                        rows_per_block).astype(np.int32)
     starts = np.concatenate([[0], np.cumsum(counts)])
@@ -176,10 +198,11 @@ def shard_tiling(shards: int, nb: int, rowblk: np.ndarray,
     are bit-identical for every S.
 
     Returns (rowblk_t [S, NR_loc] of *local* block ids, nb_loc,
-    *tiles [S, NR_loc, BE]). Shards with fewer rows pad with all-zero
-    rows mapped to the shard's last local block (keeps each shard's
-    rowblk sorted — the row→block reduction relies on it); padding rows
-    have slot_t=0 everywhere, so they only contribute `inf`.
+    *tiles [S, NR_loc, BE]). NR_loc is the largest shard's row count,
+    rounded up by at most 1/16 of itself. Shards with fewer rows pad with
+    all-zero rows mapped to the shard's last local block (keeps each
+    shard's rowblk sorted — the row→block reduction relies on it);
+    padding rows have slot_t=0 everywhere, so they only contribute `inf`.
     """
     if shards < 1:
         raise ValueError(f"shards must be >= 1, got {shards}")
@@ -187,6 +210,10 @@ def shard_tiling(shards: int, nb: int, rowblk: np.ndarray,
     shard_of = rowblk // nb_loc                       # rows sorted by block,
     row_counts = np.bincount(shard_of, minlength=shards)  # so shards are
     nr_loc = max(int(row_counts.max()), 1)                # contiguous runs
+    # Round NR_loc up by at most 1/16: insert churn that adds a few rows
+    # then keeps the tile shapes, and the jitted sweeps their executables.
+    unit = 1 << max(nr_loc.bit_length() - 5, 0)
+    nr_loc = -(-nr_loc // unit) * unit
     row_starts = np.concatenate([[0], np.cumsum(row_counts)])
     be = tiles[0].shape[1]
     rowblk_t = np.full((shards, nr_loc), nb_loc - 1, np.int32)
@@ -254,15 +281,16 @@ def edge_relax_pallas(keys: jax.Array, src_t: jax.Array, dstloc_t: jax.Array,
 
 @functools.partial(jax.jit, static_argnames=("n", "block_v", "nb",
                                              "interpret"))
-def relax_sweep_pallas(keys: jax.Array, hub_t: jax.Array, src_t: jax.Array,
+def relax_sweep_pallas(keys: jax.Array, hub_t: jax.Array | None,
+                       src_t: jax.Array,
                        dstloc_t: jax.Array, mask_t: jax.Array,
                        w_t: jax.Array,
                        step: jax.Array, inf: jax.Array, clear_bit: jax.Array,
                        n: int, block_v: int, interpret: bool = True,
                        rowblk_t: jax.Array | None = None,
                        nb: int | None = None) -> jax.Array:
-    """Generalized sweep: keys [V] + per-row hub tiles [S, NR, BV] + tiled
-    edges/weights [S, NR, BE] → [V].
+    """Generalized sweep: keys [V] + per-row hub tiles [S, NR, BV] (None:
+    no hub bit-clearing) + tiled edges/weights [S, NR, W] → [V].
 
     cand[v] = min over masked edges (u, v) of
         clear_hub_bit_if_hub(v, sat(keys[u] + step·w(u,v), inf));
@@ -272,27 +300,36 @@ def relax_sweep_pallas(keys: jax.Array, hub_t: jax.Array, src_t: jax.Array,
     block_e-chunked tiling (`rowblk_t`/`nb` set) several rows feed one
     destination block and a sorted segment-min folds the per-row partials
     — bit-identical to the unchunked reduction (min-of-mins).
+
+    The per-edge gathers (source key, destination hub flag) run in XLA;
+    the kernel gets every edge stream as [C, 128] lane rows and the three
+    scalars in SMEM.
     """
-    s, nr, be = src_t.shape
+    s, nr, w = src_t.shape
+    if w % LANES:
+        raise ValueError(f"tile rows must be a multiple of {LANES} wide, "
+                         f"got {w} (see block_edges_topology)")
     params = jnp.stack([jnp.asarray(step, jnp.int32),
                         jnp.asarray(inf, jnp.int32),
                         jnp.asarray(clear_bit, jnp.int32)])
-
+    lanes = (s, nr, w // LANES, LANES)
+    streams = [jnp.take(keys, src_t, axis=0), dstloc_t, mask_t, w_t]
+    if hub_t is not None:
+        streams.append(jnp.take_along_axis(hub_t, dstloc_t, axis=2))
+    edge = pl.BlockSpec((None, None, w // LANES, LANES),
+                        lambda j, i: (j, i, 0, 0))
     out = pl.pallas_call(
         _relax_sweep_kernel,
         grid=(s, nr),
-        in_specs=[
-            pl.BlockSpec(keys.shape, lambda j, i: (0,) * keys.ndim),
-            pl.BlockSpec((1, 1, block_v), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((1, 1, be), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((1, 1, be), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((1, 1, be), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((1, 1, be), lambda j, i: (j, i, 0)),
-            pl.BlockSpec((3,), lambda j, i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, block_v), lambda j, i: (j, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, nr, block_v), jnp.int32),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [edge] * len(streams),
+        out_specs=pl.BlockSpec((None, None, 1, block_v),
+                               lambda j, i: (j, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((s, nr, 1, block_v), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(keys, hub_t, src_t, dstloc_t, mask_t, w_t, params)
-    out = _reduce_rows(out, rowblk_t, nb, jnp.asarray(inf, jnp.int32))
+    )(params, *(x.reshape(lanes) for x in streams))
+    out = _reduce_rows(out.reshape(s, nr, block_v), rowblk_t, nb,
+                       jnp.asarray(inf, jnp.int32))
     return out.reshape(-1)[:n]

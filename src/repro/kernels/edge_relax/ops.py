@@ -53,11 +53,12 @@ class BlockedGraph:
     n: int
     block_v: int
     nb: int              # destination blocks per shard (NR >= nb)
-    chunked: bool        # some destination block spans several tile rows
-    # `chunked` is recorded at prepare time from the pre-shard row count:
-    # post-shard shapes cannot distinguish a chunked tiling whose extra
-    # rows fit inside a short last shard (NR_loc == nb_loc) from an
-    # unchunked one, and skipping the row fold there drops relaxations.
+    chunked: bool        # tile rows are not one per destination block
+    # `chunked` is recorded at prepare time from the pre-shard row count
+    # and the padded NR_loc: post-shard shapes cannot distinguish a
+    # chunked tiling whose extra rows fit inside a short last shard
+    # (NR_loc == nb_loc) from an unchunked one, and skipping the row fold
+    # there drops relaxations.
 
     @property
     def shards(self) -> int:
@@ -254,10 +255,10 @@ def prepare(src, dst, valid, n: int, block_v: int = 512,
     valid_t = (np.where(slot_t != 0, valid[perm_t].astype(np.int32), 0)
                if len(valid) else np.zeros_like(slot_t))
     nb = -(-n // bv)
-    chunked = len(rowblk) != nb
     rowblk_t, nb_loc, src_t, dstloc_t, valid_t, perm_t, slot_t = \
         kernel.shard_tiling(shards, nb, rowblk, src_t, dstloc_t,
                             valid_t.astype(np.int32), perm_t, slot_t)
+    chunked = len(rowblk) != nb or src_t.shape[1] != nb_loc
     return BlockedGraph(jnp.asarray(src_t), jnp.asarray(dstloc_t),
                         jnp.asarray(valid_t), jnp.asarray(perm_t),
                         jnp.asarray(slot_t), jnp.asarray(rowblk_t),
@@ -289,9 +290,9 @@ def prepare_topology(src, dst, keep, n: int, block_v: int = 512,
         np.asarray(src), np.asarray(dst), np.asarray(keep, bool), n, block_v,
         block_e)
     nb = -(-n // bv)
-    chunked = len(rowblk) != nb
     rowblk_t, nb_loc, src_t, dstloc_t, perm_t, slot_t = kernel.shard_tiling(
         shards, nb, rowblk, src_t, dstloc_t, perm_t, slot_t)
+    chunked = len(rowblk) != nb or src_t.shape[1] != nb_loc
     return BlockedGraph(jnp.asarray(src_t), jnp.asarray(dstloc_t),
                         jnp.asarray(slot_t), jnp.asarray(perm_t),
                         jnp.asarray(slot_t), jnp.asarray(rowblk_t),
@@ -349,11 +350,8 @@ def relax_sweep(keys: jax.Array, bg: BlockedGraph, edge_mask: jax.Array,
     """
     mask_t = bg.tile_mask(edge_mask)
     w_t = bg.tile_w(w)
-    if hub is None:
-        s, nr, _ = bg.src_t.shape
-        hub_t = jnp.zeros((s, nr, bg.block_v), jnp.int32)
-    else:
-        hub_t = bg.tile_plane_rows(hub.astype(jnp.int32), 0)
+    hub_t = (None if hub is None
+             else bg.tile_plane_rows(hub.astype(jnp.int32), 0))
     interpret = jax.default_backend() != "tpu"
     rowblk_t = bg.rowblk_t if bg.chunked else None
     return kernel.relax_sweep_pallas(keys, hub_t, bg.src_t, bg.dstloc_t,

@@ -17,8 +17,9 @@ labels and a `pmin` over the mesh finishes the reduction. P = R recovers
 the full (unsharded) bound. INF padding is the min-plus identity, so the
 padded contraction is exact.
 
-The inner contraction loops over the PP rows of H instead of materialising
-the [BB, PP, RP] cube (which would blow VMEM at 8 MB+ per block).
+The inner contraction loops over the P real rows of H instead of
+materialising the [BB, PP, RP] cube (which would blow VMEM at 8 MB+ per
+block).
 """
 from __future__ import annotations
 
@@ -34,21 +35,18 @@ DEFAULT_BB = 256   # query-batch tile
 LANES = 128        # TPU vector lane width; landmark axis padded to this
 
 
-def _minplus_kernel(s_ref, h_ref, t_ref, o_ref):
+def _minplus_kernel(s_ref, h_ref, t_ref, o_ref, *, p: int):
     s = s_ref[...]          # [BB, PP] int32
     h = h_ref[...]          # [PP, RP]
-    t = t_ref[...]          # [BB, RP]
-    pp, rp = h.shape
-
-    def body(i, acc):
-        # acc[b, j] = min(acc[b, j], s[b, i] + h[i, j])
-        s_col = jax.lax.dynamic_slice(s, (0, i), (s.shape[0], 1))   # [BB, 1]
-        h_row = jax.lax.dynamic_slice(h, (i, 0), (1, rp))           # [1, RP]
-        return jnp.minimum(acc, jnp.minimum(s_col + h_row, INF32))
-
-    acc = jnp.full((s.shape[0], rp), INF32, jnp.int32)
-    acc = jax.lax.fori_loop(0, pp, body, acc)
-    o_ref[...] = jnp.min(jnp.minimum(acc + t, INF32), axis=1, keepdims=True)
+    acc = jnp.full((s.shape[0], h.shape[1]), INF32, jnp.int32)
+    # acc[b, j] = min over i of s[b, i] + h[i, j]. The loop is unrolled
+    # over the p real rows with static slices (Mosaic lowers no dynamic
+    # lane slice); the INF padding rows past p are min-plus identities.
+    for i in range(p):
+        acc = jnp.minimum(acc, jnp.minimum(s[:, i:i + 1] + h[i:i + 1, :],
+                                           INF32))
+    o_ref[...] = jnp.min(jnp.minimum(acc + t_ref[...], INF32), axis=1,
+                         keepdims=True)
 
 
 @functools.partial(jax.jit, static_argnames=("block_b", "interpret"))
@@ -75,7 +73,7 @@ def minplus_pallas(s: jax.Array, h: jax.Array, t: jax.Array,
     pad_h = jnp.full((pp, rp), INF32, jnp.int32).at[:p, :r].set(h)
 
     out = pl.pallas_call(
-        _minplus_kernel,
+        functools.partial(_minplus_kernel, p=p),
         grid=(bp // block_b,),
         in_specs=[
             pl.BlockSpec((block_b, pp), lambda i: (i, 0)),

@@ -40,8 +40,10 @@ import argparse
 import dataclasses
 import json
 import warnings
+from typing import TYPE_CHECKING
 
-from repro.launch.serve import ServeConfig
+if TYPE_CHECKING:  # the serve module imports JAX; specs must not
+    from repro.launch.serve import ServeConfig
 
 
 def _f(default, help_: str, choices: tuple | None = None, arg_type=None):
@@ -293,6 +295,8 @@ class ServeSpec:
         have no flat counterpart (they configure processes around the
         loop, not the loop itself).
         """
+        from repro.launch.serve import ServeConfig
+
         flat_names = {f.name for f in dataclasses.fields(ServeConfig)}
         flat: dict = {}
         for attr, _ in SPEC_GROUPS:

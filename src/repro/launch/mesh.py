@@ -11,12 +11,14 @@ expert/landmark parallel, `pod` = extra data parallelism across pods.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(model: int = 1):
@@ -34,4 +36,8 @@ def make_host_mesh(model: int = 1):
     if model < 1 or n % model:
         raise ValueError(
             f"model-axis size {model} must divide the {n} local devices")
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    # Auto axes: `core/shard.py` places its outputs with shard_map specs
+    # and lets the compiler propagate shardings outside it; Explicit axes
+    # (the make_mesh default) would type-check every op on them instead.
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(AxisType.Auto,) * 2)

@@ -706,6 +706,39 @@ class RouterClient:
 # Orchestrator: spawn + supervise the topology, drive the client stream
 # ---------------------------------------------------------------------------
 
+class ChipOversubscribedError(RuntimeError):
+    """The topology would put more chip-holding processes on a TPU host
+    than it has chips.
+
+    A TPU chip belongs to one process at a time, and nothing yet assigns
+    one chip per updater/reader process (ROADMAP Reach item 4), so the
+    surplus processes would hang or die on the TPU library's lock.
+    Raised by `ReplicaTopology.start` before anything is spawned.
+    """
+
+    def __init__(self, processes: int, chips: int):
+        super().__init__(
+            f"replica topology needs {processes} chip-holding processes "
+            f"(1 updater + {processes - 1} readers) but this TPU host has "
+            f"{chips} chip(s), and a chip serves one process at a time; "
+            f"run the single-process serve loop (repro.launch.serve) "
+            f"on TPU instead")
+        self.processes = processes
+        self.chips = chips
+
+
+def host_tpu_chips() -> int:
+    """TPU chips a JAX process on this host would claim, counted from the
+    device nodes so the caller never imports JAX (which would claim them
+    itself). 0 when `JAX_PLATFORMS` selects no TPU or none is attached."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    accel = [d for d in os.listdir("/dev") if d.startswith("accel")]
+    vfio = (os.listdir("/dev/vfio") if os.path.isdir("/dev/vfio") else [])
+    return len(accel) or sum(d.isdigit() for d in vfio)
+
+
 def free_port(host: str = "127.0.0.1") -> int:
     with socket.socket() as s:
         s.bind((host, 0))
@@ -792,6 +825,10 @@ class ReplicaTopology:
             "--port", str(self.reader_ports[k]))
 
     def start(self, timeout_s: float = 180.0) -> None:
+        chips = host_tpu_chips()
+        processes = 1 + self.spec.topology.readers
+        if chips and processes > chips:
+            raise ChipOversubscribedError(processes, chips)
         os.makedirs(self.publish_dir, exist_ok=True)
         self.spec.save_json(self.config_path)
         self.updater = self._spawn("updater")
@@ -966,7 +1003,12 @@ def verify_answers(publish_dir: str, answers: list[AnswerRecord],
 
 
 def serve_main(spec, publish_dir: str, verify_limit: int | None) -> None:
-    """The ``serve`` role: run the whole topology + a client stream."""
+    """The ``serve`` role: run the whole topology + a client stream.
+
+    This parent stays off JAX while its children run: on a TPU host the
+    children hold the chips. The oracle check imports JAX, so it runs
+    after the topology has stopped.
+    """
     topo = ReplicaTopology(spec, publish_dir)
     total = spec.stream.queries * spec.stream.batches
     try:
@@ -987,15 +1029,15 @@ def serve_main(spec, publish_dir: str, verify_limit: int | None) -> None:
             raise SystemExit(
                 f"staleness contract violated: max "
                 f"{report.max_staleness()} > 1")
-        if spec.stream.verify:
-            wrong = verify_answers(publish_dir, report.answers,
-                                   limit=verify_limit)
-            checked = len(report.answers[:verify_limit])
-            print(f"verify: {wrong}/{checked} mismatches", flush=True)
-            if wrong:
-                raise SystemExit(f"verify FAILED: {wrong} mismatches")
     finally:
         topo.stop()
+    if spec.stream.verify:
+        wrong = verify_answers(publish_dir, report.answers,
+                               limit=verify_limit)
+        checked = len(report.answers[:verify_limit])
+        print(f"verify: {wrong}/{checked} mismatches", flush=True)
+        if wrong:
+            raise SystemExit(f"verify FAILED: {wrong} mismatches")
 
 
 # ---------------------------------------------------------------------------
@@ -1039,6 +1081,9 @@ def main() -> None:
                  "process of one deployment shares one serialized spec)")
 
     signal.signal(signal.SIGTERM, lambda *a: sys.exit(0))
+    if args.role in ("updater", "reader"):  # the roles that run JAX
+        from repro.launch.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if args.role == "updater":
         updater_main(spec, args.publish_dir)
     elif args.role == "reader":

@@ -669,7 +669,9 @@ def main() -> None:
     # roles; `--config <spec.json>` launches from a serialized ServeSpec
     # and flat flags remain as the (warned) legacy override surface.
     from repro.launch import config as cfgmod
+    from repro.launch.compile_cache import enable_compile_cache
 
+    enable_compile_cache()
     ap = cfgmod.build_parser(__doc__.splitlines()[0])
     args = ap.parse_args()
     spec = cfgmod.spec_from_cli(args, ap)

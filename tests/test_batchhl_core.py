@@ -139,7 +139,8 @@ def test_noop_batch_is_identity():
     edges, g, landmarks, lab = _setup(3, 24, 3)
     batch = make_batch([(0, 1, False)], pad_to=4)
     batch = batch.__class__(batch.src, batch.dst, batch.is_del,
-                            jnp.zeros_like(batch.valid))  # all padding
+                            jnp.zeros_like(batch.valid),  # all padding
+                            batch.w, batch.is_rew)
     g2, lab2, aff = batchhl_update(g, batch, lab)
     assert not bool(jnp.any(aff))
     assert bool(jnp.all(lab2.dist == lab.dist))

@@ -108,7 +108,8 @@ def test_directed_batch_update_and_queries(seed, n, n_ins, n_del):
     if not ups:
         batch = make_batch([(0, 1, False)], pad_to=1)
         batch = batch.__class__(batch.src, batch.dst, batch.is_del,
-                                jnp.zeros_like(batch.valid))
+                                jnp.zeros_like(batch.valid), batch.w,
+                                batch.is_rew)
 
     g2, lab2, _ = batchhl_update_directed(g, batch, lab)
     adj2 = ref.apply_updates_directed(_adj_out(g), ups)

@@ -63,7 +63,9 @@ def test_edge_relax_shapes(n, e, bv):
     bg = ero.prepare(src, dst, valid, n, block_v=bv)
     got = erk.edge_relax_pallas(jnp.asarray(keys), bg.src_t, bg.dstloc_t,
                                 bg.valid_t, 2, bg.n, bg.block_v,
-                                interpret=True)
+                                interpret=True,
+                                rowblk_t=bg.rowblk_t if bg.chunked else None,
+                                nb=bg.nb)
     want = err.edge_relax(jnp.asarray(keys), jnp.asarray(src),
                           jnp.asarray(dst), jnp.asarray(valid), 2, n)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))

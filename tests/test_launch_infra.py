@@ -2,9 +2,14 @@
 registry completeness — cheap tests that guard the dry-run tooling."""
 from __future__ import annotations
 
+import os
+
 import numpy as np
+import pytest
 
 from repro.launch.dryrun import parse_collective_bytes, _shape_bytes
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_parse_collective_bytes():
@@ -61,3 +66,27 @@ def test_lm_param_specs_match_param_shapes():
             jax.tree.map(check, shapes, specs,
                          is_leaf=lambda x: isinstance(x, jax.ShapeDtypeStruct)
                          or hasattr(x, "_partitions"))
+
+
+@pytest.mark.parametrize("env_dir", [None, "custom"])
+def test_compile_cache_placement(tmp_path, monkeypatch, env_dir):
+    """The entry points' compile cache lands in $JAX_COMPILATION_CACHE_DIR
+    when it is set, and otherwise in the checkout's fixed, gitignored
+    `.jax_cache/` — never a path built per run."""
+    import jax
+    from repro.launch import compile_cache
+
+    if env_dir is None:
+        monkeypatch.delenv(compile_cache.ENV, raising=False)
+        want = os.path.join(REPO, ".jax_cache")
+    else:
+        want = str(tmp_path / env_dir)
+        monkeypatch.setenv(compile_cache.ENV, want)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        assert compile_cache.enable_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+    with open(os.path.join(REPO, ".gitignore")) as fh:
+        assert ".jax_cache/" in fh.read().split()

@@ -12,6 +12,7 @@ import json
 import os
 import socket
 import subprocess
+import sys
 import threading
 import time
 import warnings
@@ -388,6 +389,47 @@ def test_realized_n_road_rounds_to_grid():
 # Crash recovery: kill a reader mid-stream, restart from CURRENT,
 # zero wrong answers at each answer's served version, staleness <= 1.
 # ---------------------------------------------------------------------------
+
+# ---------------------------------------------------------------------------
+# One process per chip
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("chips,readers", [(1, 1), (4, 4)])
+def test_topology_refuses_more_processes_than_chips(tmp_path, monkeypatch,
+                                                    chips, readers):
+    """On a TPU host the topology fails fast, before spawning anything,
+    when 1 updater + N readers outnumber the chips — instead of leaving
+    the surplus processes to hang on the TPU library's lock."""
+    monkeypatch.setattr(replica, "host_tpu_chips", lambda: chips)
+    spawned = []
+    monkeypatch.setattr(replica.ReplicaTopology, "_spawn",
+                        lambda self, *a: spawned.append(a))
+    spec = ServeSpec(topology=TopologySpec(readers=readers))
+    topo = replica.ReplicaTopology(spec, str(tmp_path / "pub"))
+    with pytest.raises(replica.ChipOversubscribedError) as err:
+        topo.start()
+    assert (err.value.processes, err.value.chips) == (readers + 1, chips)
+    assert not spawned and not (tmp_path / "pub").exists()
+
+
+def test_host_tpu_chips_off_tpu(monkeypatch):
+    """A host whose JAX platform list names no TPU holds no chips for the
+    topology, whatever device nodes exist."""
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert replica.host_tpu_chips() == 0
+
+
+def test_serve_parent_stays_off_jax():
+    """The serve role's parent imports neither JAX nor a module that
+    does: on a TPU host its children hold the chips."""
+    code = ("import sys; import repro.launch.replica, repro.launch.config;"
+            " sys.exit('jax' in sys.modules)")
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, PYTHONPATH=src)
+    assert subprocess.run([sys.executable, "-c", code],
+                          env=env).returncode == 0
+
 
 @pytest.mark.slow
 def test_reader_crash_recovery(tmp_path):

@@ -170,7 +170,6 @@ def test_minplus_kernel_inside_shard_map():
     model-sharded highway rows must reproduce the full contraction."""
     import jax
     from functools import partial as fpartial
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.kernels.minplus import ops as minplus_ops
 
@@ -188,9 +187,9 @@ def test_minplus_kernel_inside_shard_map():
                                              use_pallas=True)
             return jax.lax.pmin(part, "model")
 
-        return shard_map(body, mesh=mesh,
-                         in_specs=(P(None, "model"), P("model"), P()),
-                         out_specs=P(), check_rep=False)(s, h, t)
+        return jax.shard_map(body, mesh=mesh,
+                             in_specs=(P(None, "model"), P("model"), P()),
+                             out_specs=P(), check_vma=False)(s, h, t)
 
     want = mpr.minplus_bound(s, h, t)
     got = sharded_bound(mesh, s, h, t)
