@@ -83,12 +83,19 @@ def query_upper_bound(labelling: HighwayLabelling, s: jax.Array,
 @partial(jax.jit, static_argnames=("max_steps",))
 def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
                   bound: jax.Array, max_steps: int = 64,
-                  plan: RelaxPlan | None = None) -> jax.Array:
+                  plan: RelaxPlan | None = None
+                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
     """Distance-bounded bidirectional search on G[V\\R], batched over
     queries.
 
-    Returns d_{G[V\\R]}(s,t) clamped at `bound` (if the sparsified distance
-    is >= bound the return is >= bound, which is all the caller needs).
+    Returns `(d, waves, live_waves)`. `d` [B] is d_{G[V\\R]}(s,t) clamped
+    at `bound` (if the sparsified distance is >= bound the return is
+    >= bound, which is all the caller needs). `waves` is the loop's trip
+    count; `live_waves` [B] counts, per query, the waves at whose start
+    that query could still improve. All queries run until the slowest is
+    done, so sum(live_waves) / (waves · B) is the share of lane-waves
+    that did useful work.
+
     Expansion is a Bellman-Ford wave — an engine-dispatched relaxation
     sweep over each side's whole distance plane, vmapped over the query
     batch (`plan` selects the backend, None = jnp). After k waves a side
@@ -132,13 +139,16 @@ def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
     def best_meet(ds, dt):
         return jnp.min(jnp.minimum(ds + dt, inf), axis=1)     # [B]
 
+    def can_improve(ls, lt, best):
+        return (ls + lt + 1) * wmin < jnp.minimum(best, bound)     # [B]
+
     def cond(state):
-        ds, dt, ls, lt, fs, ft, best, step = state
-        can_improve = (ls + lt + 1) * wmin < jnp.minimum(best, bound)
-        return jnp.any(can_improve) & (step < max_steps)
+        ds, dt, ls, lt, fs, ft, best, step, live = state
+        return jnp.any(can_improve(ls, lt, best)) & (step < max_steps)
 
     def body(state):
-        ds, dt, ls, lt, fs, ft, best, step = state
+        ds, dt, ls, lt, fs, ft, best, step, live = state
+        live = live + can_improve(ls, lt, best).astype(jnp.int32)
         # Expand the side whose last wave changed fewer entries (the
         # paper's smaller-frontier BiBFS optimization; on w ≡ 1 graphs
         # the changed count IS the new frontier size). lax.cond executes
@@ -159,29 +169,32 @@ def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
         ds, dt, ls, lt, fs, ft = jax.lax.cond(expand_s, s_side, t_side,
                                               (ds, dt, ls, lt, fs, ft))
         best = jnp.minimum(best, best_meet(ds, dt))
-        return ds, dt, ls, lt, fs, ft, best, step + 1
+        return ds, dt, ls, lt, fs, ft, best, step + 1, live
 
     best0 = best_meet(dist_s, dist_t)
     state = (dist_s, dist_t, jnp.zeros((), jnp.int32),
              jnp.zeros((), jnp.int32),
              jnp.sum(dist_s == 0), jnp.sum(dist_t == 0),
-             best0, jnp.zeros((), jnp.int32))
-    *_, best, _ = jax.lax.while_loop(cond, body, state)
-    return best
+             best0, jnp.zeros((), jnp.int32), jnp.zeros((b,), jnp.int32))
+    *_, best, waves, live = jax.lax.while_loop(cond, body, state)
+    return best, waves, live
 
 
 def batched_query(g: Graph, labelling: HighwayLabelling, s: jax.Array,
                   t: jax.Array, max_steps: int = 64,
                   use_kernel: bool = False,
-                  plan: RelaxPlan | None = None) -> jax.Array:
+                  plan: RelaxPlan | None = None, counters: bool = False):
     """Exact distances Q(s,t) = min(d_{G[V\\R]}(s,t), d⊤) — paper §4.
 
     `use_kernel` dispatches the upper bound to the minplus kernel; `plan`
     dispatches the BiBFS sweeps to the edge_relax kernel (both default to
-    the jnp reference paths).
+    the jnp reference paths). With `counters` the return is
+    `(d, waves, live_waves)`, the BiBFS's counters beside the answers
+    (see `bounded_bibfs`).
     """
     d_top = query_upper_bound(labelling, s, t, use_kernel=use_kernel)
-    d_sparse = bounded_bibfs(g, labelling.landmarks, s, t, d_top, max_steps,
-                             plan)
+    d_sparse, waves, live = bounded_bibfs(g, labelling.landmarks, s, t,
+                                          d_top, max_steps, plan)
     out = jnp.minimum(d_sparse, d_top)
-    return jnp.where(out >= INF_D, INF_D, out)
+    d = jnp.where(out >= INF_D, INF_D, out)
+    return (d, waves, live) if counters else d

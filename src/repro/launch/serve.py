@@ -40,6 +40,15 @@ compose as before; in pipeline mode the maintenance chunks use the
 data×model plane grouping while interleaved query microbatches regroup
 over model — overlapped on the device queue instead of serialized.
 
+Tracing: every layer boundary of the loop is a ``serve.*`` host span
+(`launch/trace.py`): the tick, each host preparation step
+(``serve.prepare.*``), the update dispatch or chunks, the commit, each
+microbatch and each open-loop wait, and construction's phases
+(``serve.construct.*``). Each tick's self seconds by span land in
+`TickStats.host_s`, construction's in `ServeReport.construct_s`, and
+each microbatch records its service time and the BiBFS's wave counters;
+a finished run publishes these host records (`trace.last_run()`).
+
 Checkpointing: ``--ckpt-dir`` persists the *full* serve state each tick
 (graph topology + labelling + version + the host edge list);
 ``--resume`` restarts from the newest checkpoint and continues the
@@ -58,6 +67,7 @@ required sizes before anything is dispatched.
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import time
 
@@ -83,6 +93,7 @@ from repro.core import ref
 from repro.checkpoint import manager as ckpt
 from repro.data.scenarios import get_scenario
 from repro.launch.mesh import make_host_mesh
+from repro.launch import trace as tracing
 
 
 @dataclasses.dataclass
@@ -152,13 +163,25 @@ class MicrobatchRecord:
     qt: np.ndarray
     answers: np.ndarray         # int32 [m]
     latencies: np.ndarray       # float64 [m] seconds, arrival → answered
+    #: dispatch → answer (the `serve.microbatch` span); a query's queue
+    #: wait is its latency less this
+    service_s: float
+    #: BiBFS waves of the microbatch (None on the mesh path)
+    waves: int | None
+    #: sum over the m real lanes of the waves each lane could still
+    #: improve in; pad lanes are never counted (None on the mesh path)
+    live_lane_waves: int | None
 
 
 @dataclasses.dataclass
 class TickStats:
     tick: int
     version: int                # committed version after this tick
-    update_s: float             # dispatch start → commit
+    #: from the tick's open-loop origin, taken just before the
+    #: `apply_batch` dispatch and the re-tile, until the updated
+    #: labelling is ready; the commit is not in it, and in pipeline mode
+    #: the microbatches served between chunks are
+    update_s: float
     affected: int
     label_size: int
     queries: int
@@ -166,6 +189,9 @@ class TickStats:
     grew: bool = False          # this tick grew capacity/planes (§6)
     capacity: int = 0           # edge capacity after this tick
     graph_n: int = 0            # vertex slots after this tick
+    #: host self seconds by span name (`launch/trace.py`); the values
+    #: sum to the tick's wall time
+    host_s: dict[str, float] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -180,6 +206,9 @@ class ServeReport:
     history: dict[int, Snapshot] = dataclasses.field(default_factory=dict)
     #: grow-in-place events, in tick order (empty without --grow)
     growth: list[GrowthEvent] = dataclasses.field(default_factory=list)
+    #: construction's host self seconds by `serve.construct.*` span
+    #: (empty on a resumed run)
+    construct_s: dict[str, float] = dataclasses.field(default_factory=dict)
 
     def latencies(self) -> np.ndarray:
         if not self.microbatches:
@@ -241,6 +270,10 @@ class ServeLoop:
                                   frontier_threshold=cfg.frontier_threshold)
         self.store: SnapshotStore | None = None
         self.report: ServeReport | None = None
+        #: host spans at every layer boundary of the loop
+        self.trace = tracing.SpanRecorder()
+        #: (waves, live_waves [B]) of the last `_answer`, None on the mesh
+        self._counters = None
         # host-side current edge set, maintained incrementally: a
         # swap-remove list + position map keeps each tick O(batch); the
         # *order* is serve state (deletion sampling depends on it), so it
@@ -271,11 +304,13 @@ class ServeLoop:
 
     def _fresh_snapshot(self) -> Snapshot:
         cfg = self.cfg
-        if cfg.graph == "road":
-            edges = gen.road_grid(cfg.n, max_weight=max(
-                2, self.scenario.max_weight), seed=0)
-        else:
-            edges = gen.barabasi_albert(cfg.n, cfg.deg, seed=0)
+        span = self.trace.span
+        with span("serve.construct.generate"):
+            if cfg.graph == "road":
+                edges = gen.road_grid(cfg.n, max_weight=max(
+                    2, self.scenario.max_weight), seed=0)
+            else:
+                edges = gen.barabasi_albert(cfg.n, cfg.deg, seed=0)
         # Explicit --capacity starts the run at that size (the grow-in-place
         # entry point: pair with --grow to start small and let the stream
         # grow the slots); the default provisions the scenario's worst case
@@ -283,20 +318,26 @@ class ServeLoop:
         cap = cfg.capacity if cfg.capacity is not None else (
             edges.shape[0]
             + self.scenario.max_inserts(cfg.batches, cfg.batch_size) + 64)
-        g = from_edges(cfg.n, edges, cap)
-        landmarks = select_landmarks_by_degree(g, cfg.landmarks)
-        plan = self.engine.prepare(g)
+        with span("serve.construct.load"):
+            g = from_edges(cfg.n, edges, cap)
+        with span("serve.construct.landmarks"):
+            landmarks = select_landmarks_by_degree(g, cfg.landmarks)
+        with span("serve.construct.tile"):
+            plan = self.engine.prepare(g)
         t0 = time.time()
-        if self.mesh is not None:
-            lab = shard_build_labelling(self.mesh, g, landmarks, plan=plan)
-        else:
-            lab = build_labelling(g, landmarks, plan=plan)
-        jax.block_until_ready(lab.dist)
-        self._edge_list = [(int(min(a, b)), int(max(a, b)))
-                           for a, b in edges[:, :2]]
-        self._edge_pos = {e: i for i, e in enumerate(self._edge_list)}
-        self._edge_w = {e: (int(row[2]) if edges.shape[1] > 2 else 1)
-                        for e, row in zip(self._edge_list, edges)}
+        with span("serve.construct.label"):
+            if self.mesh is not None:
+                lab = shard_build_labelling(self.mesh, g, landmarks,
+                                            plan=plan)
+            else:
+                lab = build_labelling(g, landmarks, plan=plan)
+            jax.block_until_ready(lab.dist)
+        with span("serve.construct.index"):
+            self._edge_list = [(int(min(a, b)), int(max(a, b)))
+                               for a, b in edges[:, :2]]
+            self._edge_pos = {e: i for i, e in enumerate(self._edge_list)}
+            self._edge_w = {e: (int(row[2]) if edges.shape[1] > 2 else 1)
+                            for e, row in zip(self._edge_list, edges)}
         self._log(f"constructed labelling: {cfg.n} vertices, "
                   f"{edges.shape[0]} edges, R={cfg.landmarks}, "
                   f"size={int(lab.label_size())}, {time.time() - t0:.2f}s "
@@ -359,15 +400,19 @@ class ServeLoop:
 
     def _answer(self, snap: Snapshot, qs: jax.Array,
                 qt: jax.Array) -> jax.Array:
+        """The microbatch's answers, ready; its BiBFS counters go to
+        `self._counters` (same program, so ready with the answers)."""
         if self.mesh is None:
-            d = batched_query(snap.graph, snap.labelling, qs, qt,
-                              use_kernel=self.cfg.use_minplus_kernel,
-                              plan=snap.plan)
+            d, *self._counters = batched_query(
+                snap.graph, snap.labelling, qs, qt,
+                use_kernel=self.cfg.use_minplus_kernel, plan=snap.plan,
+                counters=True)
         else:
             d = shard_batched_query(self.mesh, snap.graph, snap.labelling,
                                     qs, qt,
                                     use_kernel=self.cfg.use_minplus_kernel,
                                     plan=snap.plan)
+            self._counters = None
         jax.block_until_ready(d)
         return d
 
@@ -392,15 +437,22 @@ class ServeLoop:
             pad_idx = np.concatenate(
                 [idx, np.full(cfg.microbatch - take, idx[0])])
             snap = self.store.committed
-            d = self._answer(snap, jnp.asarray(qs[pad_idx]),
-                             jnp.asarray(qt[pad_idx]))
+            with self.trace.span("serve.microbatch", tick=tick,
+                                 mb=len(out)) as sp:
+                d = self._answer(snap, jnp.asarray(qs[pad_idx]),
+                                 jnp.asarray(qt[pad_idx]))
             t_done = time.time()
+            waves = live = None
+            if self._counters is not None:
+                waves = int(self._counters[0])
+                live = int(np.asarray(self._counters[1])[:take].sum())
             out.append(MicrobatchRecord(
                 tick=tick, version=snap.version,
                 staleness=head_version - snap.version,
                 qs=qs[idx].copy(), qt=qt[idx].copy(),
                 answers=np.asarray(d)[:take].copy(),
-                latencies=t_done - (tick_t0 + offsets[idx])))
+                latencies=t_done - (tick_t0 + offsets[idx]),
+                service_s=sp.seconds, waves=waves, live_lane_waves=live))
             served += take
         return served
 
@@ -413,7 +465,8 @@ class ServeLoop:
         while served < q:
             wait = tick_t0 + offsets[served] - time.time()
             if wait > 0:
-                time.sleep(wait)
+                with self.trace.span("serve.wait", tick=tick):
+                    time.sleep(wait)
             served = self._drain_arrived(tick, tick_t0, offsets, qs, qt,
                                          served, head_version, out)
         return served
@@ -445,12 +498,15 @@ class ServeLoop:
                                chunk_sweeps=cfg.chunk_sweeps,
                                fused=cfg.fused)
         head = snap.version + 1
-        while True:
-            try:
-                next(upd)
-            except StopIteration as stop:
-                nxt, aff = stop.value
-                break
+        for chunk in itertools.count():
+            with self.trace.span("serve.update_chunk", tick=tick,
+                                 chunk=chunk) as sp:
+                try:
+                    sp.set(tag=next(upd))
+                except StopIteration as stop:
+                    sp.set(tag="finish")
+                    nxt, aff = stop.value
+                    break
             served_box[0] = self._drain_arrived(
                 tick, tick_t0, offsets, qs, qt, served_box[0], head, out)
         jax.block_until_ready(nxt.labelling.dist)
@@ -500,80 +556,81 @@ class ServeLoop:
 
     # -- the loop -----------------------------------------------------------
 
-    def run(self) -> ServeReport:
+    def _tick(self, tick: int, out: list[MicrobatchRecord],
+              growth: list[GrowthEvent],
+              history: dict[int, Snapshot]) -> TickStats:
+        """One tick: draw and dispatch the update batch, serve the tick's
+        queries, commit, and fold the batch into the host edge set."""
         cfg = self.cfg
-        resumable = (cfg.resume and cfg.ckpt_dir
-                     and ckpt.latest_step(cfg.ckpt_dir) is not None)
-        snap0 = self._resumed_snapshot() if resumable \
-            else self._fresh_snapshot()
-        self.store = SnapshotStore(snap0)
-        if self.on_start is not None:
-            self.on_start(snap0)
-        ticks: list[TickStats] = []
-        out: list[MicrobatchRecord] = []
-        growth: list[GrowthEvent] = []
-        history: dict[int, Snapshot] = {}
-        if cfg.keep_history:
-            history[snap0.version] = snap0
-        self._last_aff = None
-
-        for tick in range(snap0.version, cfg.batches):
-            snap = self.store.committed
-            n_ins, n_del, n_rew = self.scenario.update_counts(
-                tick, cfg.batch_size)
+        span = self.trace.span
+        snap = self.store.committed
+        n_ins, n_del, n_rew = self.scenario.update_counts(
+            tick, cfg.batch_size)
+        with span("serve.prepare.snapshot_edges"):
             cur_edges = np.asarray(self._edge_list, np.int32)
+        with span("serve.prepare.draw_updates"):
             ups = gen.random_batch_updates(
                 cur_edges, cfg.n, n_ins=n_ins, n_del=n_del,
                 seed=100 + tick, existing=self._edge_pos, n_rew=n_rew,
                 max_weight=self.scenario.max_weight)
+        with span("serve.prepare.make_batch"):
             batch = make_batch(ups, pad_to=cfg.batch_size)
+        with span("serve.prepare.queries"):
             offsets, qs, qt = self._tick_queries(tick)
-            # Insert ops alone move topology slots; deletions flip
-            # validity in place and reweights touch only the w column,
-            # so a reweight-only tick reuses the committed tiling.
-            has_ins = any(not int(up[2]) for up in ups)
+        # Insert ops alone move topology slots; deletions flip
+        # validity in place and reweights touch only the w column,
+        # so a reweight-only tick reuses the committed tiling.
+        has_ins = any(not int(up[2]) for up in ups)
 
-            # Grow-in-place check *before* any dispatch (DESIGN.md §6): an
-            # overflowing batch grows the working snapshot — same version,
-            # larger slots/planes — or raises a typed CapacityError naming
-            # this tick. The committed snapshot keeps serving queries
-            # untouched either way; the grown shapes first become visible
-            # to readers at the next commit's pointer swap.
+        # Grow-in-place check *before* any dispatch (DESIGN.md §6): an
+        # overflowing batch grows the working snapshot — same version,
+        # larger slots/planes — or raises a typed CapacityError naming
+        # this tick. The committed snapshot keeps serving queries
+        # untouched either way; the grown shapes first become visible
+        # to readers at the next commit's pointer swap.
+        with span("serve.prepare.capacity"):
             work, event = ensure_capacity(snap, batch, self.growth_policy,
                                           grow=cfg.grow, tick=tick)
-            if event is not None:
-                growth.append(event)
-                self._log(f"  grow: capacity {event.old_capacity}->"
-                          f"{event.new_capacity}, n {event.old_n}->"
-                          f"{event.new_n} (needed {event.required_capacity}"
-                          f"/{event.required_n})")
+        if event is not None:
+            growth.append(event)
+            self._log(f"  grow: capacity {event.old_capacity}->"
+                      f"{event.new_capacity}, n {event.old_n}->"
+                      f"{event.new_n} (needed {event.required_capacity}"
+                      f"/{event.required_n})")
 
-            served_box = [0]
-            tick_t0 = time.time()
-            # One tiling per tick, prepared from the post-update snapshot
-            # (the engine contract); the keyed plan cache keeps the
-            # committed snapshot's tiling alive alongside it. Growth moved
-            # topology slots (capacity/n changed → new fingerprint), so it
-            # forces a clean retile exactly like an insertion does.
+        served_box = [0]
+        tick_t0 = time.time()
+        # One tiling per tick, prepared from the post-update snapshot
+        # (the engine contract); the keyed plan cache keeps the
+        # committed snapshot's tiling alive alongside it. Growth moved
+        # topology slots (capacity/n changed → new fingerprint), so it
+        # forces a clean retile exactly like an insertion does.
+        with span("serve.apply_batch", tick=tick):
             g_next = apply_batch(work.graph, batch)
+        retiles = self.engine.retile_count
+        with span("serve.prepare.retile", tick=tick) as sp:
             plan = self.engine.prepare(
                 g_next, topology_changed=has_ins or event is not None)
-            if cfg.pipeline:
-                nxt = self._update_pipelined(work, batch, plan, g_next,
-                                             tick, tick_t0, offsets, qs, qt,
-                                             served_box, out)
-            else:
+            sp.set(retiled=self.engine.retile_count > retiles)
+        if cfg.pipeline:
+            nxt = self._update_pipelined(work, batch, plan, g_next,
+                                         tick, tick_t0, offsets, qs, qt,
+                                         served_box, out)
+        else:
+            with span("serve.update", tick=tick):
                 nxt = self._update_sync(work, batch, plan, g_next)
-            t_upd = time.time() - tick_t0
+        t_upd = time.time() - tick_t0
+        with span("serve.commit", tick=tick):
             self.store.commit(nxt)
-            if cfg.keep_history:
-                history[nxt.version] = nxt
-            served_box[0] = self._drain_rest(
-                tick, tick_t0, offsets, qs, qt, served_box[0],
-                nxt.version, out)
+        if cfg.keep_history:
+            history[nxt.version] = nxt
+        served_box[0] = self._drain_rest(
+            tick, tick_t0, offsets, qs, qt, served_box[0],
+            nxt.version, out)
 
-            # Fold the tick's updates into the incremental edge set
-            # (op 0 = insert, 1 = delete, 2 = reweight).
+        # Fold the tick's updates into the incremental edge set
+        # (op 0 = insert, 1 = delete, 2 = reweight).
+        with span("serve.prepare.fold"):
             for up in ups:
                 u, v, op = up[0], up[1], int(up[2])
                 w = int(up[3]) if len(up) > 3 else 1
@@ -594,11 +651,12 @@ class ServeLoop:
                     self._edge_list.append(k)
                     self._edge_w[k] = w
 
-            tick_mbs = [m for m in out if m.tick == tick]
-            lat = (np.concatenate([m.latencies for m in tick_mbs])
-                   if tick_mbs else np.zeros((1,)))
-            stale = sum(int(m.staleness > 0) * m.qs.shape[0]
-                        for m in tick_mbs)
+        tick_mbs = [m for m in out if m.tick == tick]
+        lat = (np.concatenate([m.latencies for m in tick_mbs])
+               if tick_mbs else np.zeros((1,)))
+        stale = sum(int(m.staleness > 0) * m.qs.shape[0]
+                    for m in tick_mbs)
+        with span("serve.prepare.stats"):
             stats = TickStats(
                 tick=tick, version=nxt.version, update_s=t_upd,
                 affected=int(jnp.sum(self._last_aff)),
@@ -606,36 +664,70 @@ class ServeLoop:
                 queries=int(served_box[0]),
                 grew=event is not None,
                 capacity=nxt.graph.capacity, graph_n=nxt.graph.n)
-            self._log(
-                f"tick {tick}: update {t_upd * 1e3:.1f}ms "
-                f"({stats.affected} affected, v{nxt.version}) | "
-                f"{stats.queries} queries p50 "
-                f"{np.percentile(lat, 50) * 1e3:.1f}ms p99 "
-                f"{np.percentile(lat, 99) * 1e3:.1f}ms "
-                f"({stale} stale) | label size {stats.label_size}")
+        self._log(
+            f"tick {tick}: update {t_upd * 1e3:.1f}ms "
+            f"({stats.affected} affected, v{nxt.version}) | "
+            f"{stats.queries} queries p50 "
+            f"{np.percentile(lat, 50) * 1e3:.1f}ms p99 "
+            f"{np.percentile(lat, 99) * 1e3:.1f}ms "
+            f"({stale} stale) | label size {stats.label_size} | "
+            f"host prep {self.trace.seconds('serve.prepare.') * 1e3:.1f}ms"
+            f" | BiBFS waves {[m.waves for m in tick_mbs]}")
 
-            if cfg.verify:
-                snapshots = {snap.version: snap, nxt.version: nxt}
-                stats.verify_mismatches = self._verify_tick(
-                    tick, tick_mbs, snapshots)
+        if cfg.verify:
+            snapshots = {snap.version: snap, nxt.version: nxt}
+            stats.verify_mismatches = self._verify_tick(
+                tick, tick_mbs, snapshots)
+
+        if cfg.ckpt_dir:
+            edge_rows = np.asarray(
+                [(u, v, self._edge_w.get((u, v), 1))
+                 for u, v in self._edge_list],
+                np.int32).reshape(-1, 3)
+            save_snapshot(
+                cfg.ckpt_dir, nxt,
+                extra={"edge_list": edge_rows,
+                       "base_n": np.int64(cfg.n)})
+        if self.on_commit is not None:
+            self.on_commit(tick, nxt)
+        return stats
+
+    def run(self) -> ServeReport:
+        cfg = self.cfg
+        resumable = (cfg.resume and cfg.ckpt_dir
+                     and ckpt.latest_step(cfg.ckpt_dir) is not None)
+        snap0 = self._resumed_snapshot() if resumable \
+            else self._fresh_snapshot()
+        construct_s = self.trace.take()
+        self.store = SnapshotStore(snap0)
+        if self.on_start is not None:
+            self.on_start(snap0)
+        ticks: list[TickStats] = []
+        out: list[MicrobatchRecord] = []
+        growth: list[GrowthEvent] = []
+        history: dict[int, Snapshot] = {}
+        if cfg.keep_history:
+            history[snap0.version] = snap0
+        self._last_aff = None
+
+        span = self.trace.span
+        for tick in range(snap0.version, cfg.batches):
+            with span("serve.tick", tick=tick):
+                stats = self._tick(tick, out, growth, history)
+            stats.host_s = self.trace.take()
             ticks.append(stats)
-
-            if cfg.ckpt_dir:
-                edge_rows = np.asarray(
-                    [(u, v, self._edge_w.get((u, v), 1))
-                     for u, v in self._edge_list],
-                    np.int32).reshape(-1, 3)
-                save_snapshot(
-                    cfg.ckpt_dir, nxt,
-                    extra={"edge_list": edge_rows,
-                           "base_n": np.int64(cfg.n)})
-            if self.on_commit is not None:
-                self.on_commit(tick, nxt)
 
         self.report = ServeReport(config=cfg, ticks=ticks, microbatches=out,
                                   final=self.store.committed,
                                   backend=self.engine.backend,
-                                  history=history, growth=growth)
+                                  history=history, growth=growth,
+                                  construct_s=construct_s)
+        tracing.publish(tracing.RunRecord(
+            host_s=tuple(t.host_s for t in ticks),
+            microbatches=tuple(tracing.MicrobatchHost(
+                len(m.qs), m.service_s, m.waves, m.live_lane_waves)
+                for m in out),
+            construct_s=construct_s))
         pct = self.report.latency_percentiles()
         mode = "pipeline" if cfg.pipeline else "sync"
         engine = self.engine

@@ -171,17 +171,31 @@ def test_pallas_update_matches_oracle():
         assert got[k] == want
 
 
-@pytest.mark.parametrize("n,extra,bv", SHAPES)
-def test_bibfs_parity(n, extra, bv):
+@pytest.mark.parametrize(
+    "n,extra,bv,max_steps",
+    [(*shape, 32) for shape in SHAPES] + [(*SHAPES[2], 0)],
+    ids=[f"{n}-{e}-{bv}" for n, e, bv in SHAPES] + ["capped0"])
+def test_bibfs_parity(n, extra, bv, max_steps):
+    """Distances and the wave counters agree across backends; a lane is
+    live in at most every wave, and a 0-wave cap runs none."""
     edges, g, landmarks, lab = _instance(n + 5, n, extra)
     plan = _plan(g, bv)
     rng = np.random.default_rng(n)
     s = jnp.asarray(rng.integers(0, n, 10), jnp.int32)
     t = jnp.asarray(rng.integers(0, n, 10), jnp.int32)
     bound = jnp.full((10,), INF_D, jnp.int32)
-    dj = bounded_bibfs(g, lab.landmarks, s, t, bound, 32)
-    dp = bounded_bibfs(g, lab.landmarks, s, t, bound, 32, plan)
+    dj, wj, lj = bounded_bibfs(g, lab.landmarks, s, t, bound, max_steps)
+    dp, wp, lp = bounded_bibfs(g, lab.landmarks, s, t, bound, max_steps,
+                               plan)
     np.testing.assert_array_equal(np.asarray(dp), np.asarray(dj))
+    assert int(wp) == int(wj) <= max_steps
+    np.testing.assert_array_equal(np.asarray(lp), np.asarray(lj))
+    assert np.all((np.asarray(lj) >= 0) & (np.asarray(lj) <= int(wj)))
+    if max_steps == 0:
+        assert int(wj) == 0
+    else:
+        # the slowest lane is live in every wave of the loop
+        assert int(wj) > 0 and int(np.asarray(lj).max()) == int(wj)
 
 
 @pytest.mark.parametrize("n,extra,bv", SHAPES)

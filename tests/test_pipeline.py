@@ -193,6 +193,9 @@ def test_pipeline_serving_exact_at_version(backend):
         want = batched_query(snap.graph, snap.labelling,
                              jnp.asarray(m.qs), jnp.asarray(m.qt))
         np.testing.assert_array_equal(m.answers, np.asarray(want))
+        # the BiBFS counters ride along on either backend
+        assert 0 < m.waves <= 64
+        assert 0 <= m.live_lane_waves <= m.waves * m.qs.shape[0]
     # the pipeline actually overlapped: some answers were served against
     # the stale committed snapshot while the update was in flight
     assert any(m.staleness == 1 for m in rep.microbatches)
