@@ -45,6 +45,7 @@ from repro.graphs.coo import Graph
 from repro.graphs.segment import masked_segment_min
 from repro.core import autotune as tune_mod
 from repro.kernels.edge_relax import ops as er_ops
+from repro.kernels.edge_relax import ref as er_ref
 from repro.kernels.edge_relax.ops import BlockedGraph, FrontierTiles, SortedGraph
 
 BACKENDS = ("jnp", "pallas")
@@ -116,6 +117,30 @@ def relax_sweep(plan: RelaxPlan | None, g: Graph, keys: jax.Array,
                                              hub=hub, w=g.w)
         return er_ops.relax_sweep(keys, plan.tiles, mask, step, inf,
                                   clear_bit=clear_bit, hub=hub, w=g.w)
+    raise ValueError(f"unknown backend {plan.backend!r}; pick from {BACKENDS}")
+
+
+def frontier_or_sweep(plan: RelaxPlan | None, g: Graph, lanes: int):
+    """The bit-packed BFS's sweep on `g`'s valid edges, as a function
+    `sweep(words)`: packed frontier words [W, V] uint32 → the OR of the
+    words of every in-neighbour [W, V] (bit q of a word is one query's
+    lane, of `lanes` in all). Unweighted: it is a BFS level step, not a
+    relaxation.
+
+    What does not change across waves — the validity mask re-tiled to
+    the plan's edge order — is computed here, once per search, outside
+    the wave loop. Dispatch follows `relax_sweep`, but plan=None, "jnp"
+    and a "sorted" plan alike reduce the COO arrays bit by bit, over the
+    min(lanes, 32) bits in use (an OR does not depend on edge order);
+    the "kernel" impl runs the Pallas `frontier_or` kernel.
+    """
+    if plan is None or plan.backend == "jnp" or plan.impl == "sorted":
+        nbits = min(lanes, 32)
+        return lambda words: er_ref.frontier_or(words, g.src, g.dst,
+                                                g.valid, g.n, nbits)
+    if plan.backend == "pallas":
+        dst_t = plan.tiles.masked_dst(g.valid)
+        return lambda words: er_ops.frontier_or(words, plan.tiles, dst_t)
     raise ValueError(f"unknown backend {plan.backend!r}; pick from {BACKENDS}")
 
 

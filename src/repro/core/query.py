@@ -9,7 +9,9 @@ bidirectional BFS runs all queries in lockstep as masked frontier waves
 with a global early-exit; each wave is an edge-relaxation sweep routed
 through the relaxation engine (`core/engine.py`), so passing a `RelaxPlan`
 runs the tiled Pallas `edge_relax` kernel while the default `plan=None`
-runs the jnp segment-min reference — see DESIGN.md §3.
+runs the jnp segment-min reference — see DESIGN.md §3. On a graph whose
+valid edges all weigh 1 the waves are BFS level steps over one packed
+bit plane per side instead (the `frontier_or` sweep; DESIGN.md §2).
 """
 from __future__ import annotations
 
@@ -19,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.graphs.coo import Graph, INF_D
-from repro.core.engine import RelaxPlan, relax_sweep
+from repro.core.engine import RelaxPlan, frontier_or_sweep, relax_sweep
 from repro.core.labelling import HighwayLabelling, landmark_onehot
 
 
@@ -84,17 +86,18 @@ def query_upper_bound(labelling: HighwayLabelling, s: jax.Array,
 def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
                   bound: jax.Array, max_steps: int = 64,
                   plan: RelaxPlan | None = None
-                  ) -> tuple[jax.Array, jax.Array, jax.Array]:
+                  ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """Distance-bounded bidirectional search on G[V\\R], batched over
     queries.
 
-    Returns `(d, waves, live_waves)`. `d` [B] is d_{G[V\\R]}(s,t) clamped
-    at `bound` (if the sparsified distance is >= bound the return is
-    >= bound, which is all the caller needs). `waves` is the loop's trip
-    count; `live_waves` [B] counts, per query, the waves at whose start
-    that query could still improve. All queries run until the slowest is
-    done, so sum(live_waves) / (waves · B) is the share of lane-waves
-    that did useful work.
+    Returns `(d, waves, live_waves, bit_packed)`. `d` [B] is
+    d_{G[V\\R]}(s,t) clamped at `bound` (if the sparsified distance is
+    >= bound the return is >= bound, which is all the caller needs).
+    `waves` is the loop's trip count; `live_waves` [B] counts, per query,
+    the waves at whose start that query could still improve. All queries
+    run until the slowest is done, so sum(live_waves) / (waves · B) is
+    the share of lane-waves that did useful work. `bit_packed` says
+    which of the two expansions below ran.
 
     Expansion is a Bellman-Ford wave — an engine-dispatched relaxation
     sweep over each side's whole distance plane, vmapped over the query
@@ -102,8 +105,18 @@ def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
     is exact on every shortest path of ≤ k edges, so once both sides have
     run ls/lt waves any path still unaccounted for has ≥ ls+lt+1 edges
     and therefore weight ≥ (ls+lt+1)·wmin — the weighted termination
-    bound. With w ≡ 1 (wmin = 1) the waves and the bound degenerate to
-    the level-synchronous BiBFS this replaces, bit-identically.
+    bound.
+
+    When every valid edge has weight 1 (a property of the input, checked
+    on device; `lax.cond` picks the branch) a wave is a BFS level step
+    instead: a vertex changes only when first reached, and then to the
+    side's wave count. All B queries of a side then share one packed
+    word plane — bit q of word q // 32 is "reached by query q" — and a
+    wave is one OR sweep of the frontier words (`frontier_or_sweep`),
+    masked by the visited words and the landmarks, which then writes the
+    new level into the [B, V] distance plane. The planes, the meet, the
+    side choice and the termination test are the Bellman-Ford path's, so
+    `d`, `waves` and `live_waves` are bit-identical to it.
     """
     n = g.n
     b = s.shape[0]
@@ -125,16 +138,7 @@ def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
     # INF_D before the clip).
     wmin = jnp.clip(jnp.min(jnp.where(g.valid, g.w, INF_D), initial=INF_D),
                     1, 1 << 20)
-
-    def expand(dist_x):
-        """One Bellman-Ford wave: relax every live edge from the current
-        plane — the same sweep primitive (and the same kernel) as the
-        update-side searches. Landmark vertices never acquire a distance
-        (the search runs on G[V\\R])."""
-        cand = jax.vmap(
-            lambda k: relax_sweep(plan, g, k, 1, inf))(dist_x)
-        cand = jnp.where(blocked[None, :], inf, cand)
-        return jnp.minimum(dist_x, cand)
+    unit = jnp.all(jnp.where(g.valid, g.w, 1) == 1)
 
     def best_meet(ds, dt):
         return jnp.min(jnp.minimum(ds + dt, inf), axis=1)     # [B]
@@ -142,42 +146,89 @@ def bounded_bibfs(g: Graph, landmarks: jax.Array, s: jax.Array, t: jax.Array,
     def can_improve(ls, lt, best):
         return (ls + lt + 1) * wmin < jnp.minimum(best, bound)     # [B]
 
-    def cond(state):
-        ds, dt, ls, lt, fs, ft, best, step, live = state
-        return jnp.any(can_improve(ls, lt, best)) & (step < max_steps)
+    def search(expand, planes_s, planes_t):
+        """The lockstep loop over both sides. A side's state is a tuple
+        whose first entry is its [B, V] distance plane; `expand(side,
+        level)` returns the side after one wave, and the count of plane
+        entries that wave changed."""
+        def cond(state):
+            xs, xt, ls, lt, fs, ft, best, step, live = state
+            return jnp.any(can_improve(ls, lt, best)) & (step < max_steps)
 
-    def body(state):
-        ds, dt, ls, lt, fs, ft, best, step, live = state
-        live = live + can_improve(ls, lt, best).astype(jnp.int32)
-        # Expand the side whose last wave changed fewer entries (the
-        # paper's smaller-frontier BiBFS optimization; on w ≡ 1 graphs
-        # the changed count IS the new frontier size). lax.cond executes
-        # only the chosen side's sweep — the edge-array read per wave is
-        # the memory floor here.
-        expand_s = fs <= ft
+        def body(state):
+            xs, xt, ls, lt, fs, ft, best, step, live = state
+            live = live + can_improve(ls, lt, best).astype(jnp.int32)
+            # Expand the side whose last wave changed fewer entries (the
+            # paper's smaller-frontier BiBFS optimization; on w ≡ 1
+            # graphs the changed count IS the new frontier size).
+            # lax.cond executes only the chosen side's sweep — the
+            # edge-array read per wave is the memory floor here.
+            def s_side(args):
+                xs, xt, ls, lt, fs, ft = args
+                xs, fs = expand(xs, ls + 1)
+                return xs, xt, ls + 1, lt, fs, ft
 
-        def s_side(args):
-            ds, dt, ls, lt, fs, ft = args
-            nd = expand(ds)
-            return nd, dt, ls + 1, lt, jnp.sum(nd != ds), ft
+            def t_side(args):
+                xs, xt, ls, lt, fs, ft = args
+                xt, ft = expand(xt, lt + 1)
+                return xs, xt, ls, lt + 1, fs, ft
 
-        def t_side(args):
-            ds, dt, ls, lt, fs, ft = args
-            nd = expand(dt)
-            return ds, nd, ls, lt + 1, fs, jnp.sum(nd != dt)
+            xs, xt, ls, lt, fs, ft = jax.lax.cond(
+                fs <= ft, s_side, t_side, (xs, xt, ls, lt, fs, ft))
+            best = jnp.minimum(best, best_meet(xs[0], xt[0]))
+            return xs, xt, ls, lt, fs, ft, best, step + 1, live
 
-        ds, dt, ls, lt, fs, ft = jax.lax.cond(expand_s, s_side, t_side,
-                                              (ds, dt, ls, lt, fs, ft))
-        best = jnp.minimum(best, best_meet(ds, dt))
-        return ds, dt, ls, lt, fs, ft, best, step + 1, live
+        zero = jnp.zeros((), jnp.int32)
+        state = (planes_s, planes_t, zero, zero,
+                 jnp.sum(dist_s == 0), jnp.sum(dist_t == 0),
+                 best_meet(dist_s, dist_t), zero,
+                 jnp.zeros((b,), jnp.int32))
+        *_, best, waves, live = jax.lax.while_loop(cond, body, state)
+        return best, waves, live
 
-    best0 = best_meet(dist_s, dist_t)
-    state = (dist_s, dist_t, jnp.zeros((), jnp.int32),
-             jnp.zeros((), jnp.int32),
-             jnp.sum(dist_s == 0), jnp.sum(dist_t == 0),
-             best0, jnp.zeros((), jnp.int32), jnp.zeros((b,), jnp.int32))
-    *_, best, waves, live = jax.lax.while_loop(cond, body, state)
-    return best, waves, live
+    def bellman_ford():
+        def expand(side, level):
+            """One Bellman-Ford wave: relax every live edge from the
+            current plane — the same sweep primitive (and the same
+            kernel) as the update-side searches. Landmark vertices never
+            acquire a distance (the search runs on G[V\\R])."""
+            (dist_x,) = side
+            cand = jax.vmap(
+                lambda k: relax_sweep(plan, g, k, 1, inf))(dist_x)
+            cand = jnp.where(blocked[None, :], inf, cand)
+            nd = jnp.minimum(dist_x, cand)
+            return (nd,), jnp.sum(nd != dist_x)
+
+        return search(expand, (dist_s,), (dist_t,))
+
+    def bit_packed():
+        lane = jnp.arange(b)
+        word, bit = lane // 32, (lane % 32).astype(jnp.uint32)
+        sweep = frontier_or_sweep(plan, g, b)
+
+        def seed(x, ok):
+            # Lanes own distinct bits, so adding them ORs them.
+            return jnp.zeros((-(-b // 32), n), jnp.uint32).at[word, x].add(
+                jnp.where(ok, jnp.uint32(1) << bit, 0))
+
+        def expand(side, level):
+            """One BFS level: the unvisited, non-landmark vertices with a
+            frontier in-neighbour, per lane, at distance `level`."""
+            dist_x, front, seen = side
+            new = sweep(front) & ~seen
+            hit = (new[word] >> bit[:, None]) & 1 != 0           # [B, V]
+            changed = jnp.sum(jax.lax.population_count(new).astype(
+                jnp.int32))
+            return (jnp.where(hit, level, dist_x), new, seen | new), changed
+
+        # Landmarks count as visited in every lane, so never reached.
+        walls = jnp.where(blocked, ~jnp.uint32(0), jnp.uint32(0))
+        front_s, front_t = seed(s, s_ok), seed(t, t_ok)
+        return search(expand, (dist_s, front_s, front_s | walls),
+                      (dist_t, front_t, front_t | walls))
+
+    best, waves, live = jax.lax.cond(unit, bit_packed, bellman_ford)
+    return best, waves, live, unit
 
 
 def batched_query(g: Graph, labelling: HighwayLabelling, s: jax.Array,
@@ -189,12 +240,12 @@ def batched_query(g: Graph, labelling: HighwayLabelling, s: jax.Array,
     `use_kernel` dispatches the upper bound to the minplus kernel; `plan`
     dispatches the BiBFS sweeps to the edge_relax kernel (both default to
     the jnp reference paths). With `counters` the return is
-    `(d, waves, live_waves)`, the BiBFS's counters beside the answers
-    (see `bounded_bibfs`).
+    `(d, waves, live_waves, bit_packed)`, the BiBFS's counters beside the
+    answers (see `bounded_bibfs`).
     """
     d_top = query_upper_bound(labelling, s, t, use_kernel=use_kernel)
-    d_sparse, waves, live = bounded_bibfs(g, labelling.landmarks, s, t,
-                                          d_top, max_steps, plan)
+    d_sparse, *counts = bounded_bibfs(g, labelling.landmarks, s, t, d_top,
+                                      max_steps, plan)
     out = jnp.minimum(d_sparse, d_top)
     d = jnp.where(out >= INF_D, INF_D, out)
-    return (d, waves, live) if counters else d
+    return (d, *counts) if counters else d

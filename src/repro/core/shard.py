@@ -746,8 +746,8 @@ def shard_batched_query(mesh, g: Graph, labelling: HighwayLabelling,
         # Bounded BiBFS on the local query shard (replicated over model).
         # The BiBFS's wave counters stay inside the shard_map: the mesh
         # path returns answers only.
-        d_sparse, _, _ = bounded_bibfs(g, landmarks_full, s, t, d_top,
-                                       max_steps, plan)
+        d_sparse, *_ = bounded_bibfs(g, landmarks_full, s, t, d_top,
+                                     max_steps, plan)
         out = jnp.minimum(d_sparse, d_top)
         return jnp.where(out >= INF_D, INF_D, out)
 

@@ -333,3 +333,91 @@ def relax_sweep_pallas(keys: jax.Array, hub_t: jax.Array | None,
     out = _reduce_rows(out.reshape(s, nr, block_v), rowblk_t, nb,
                        jnp.asarray(inf, jnp.int32))
     return out.reshape(-1)[:n]
+
+
+def _frontier_or_kernel(rowblk_ref, words_ref, dst_ref, o_ref):
+    """OR sweep over one tile row: each edge's packed source word lands
+    on its destination, `acc |= where(hit, word, 0)` in place of the
+    relaxation's compare-and-min.
+
+    Edge streams arrive as [C, 128] lane rows, as in `_relax_sweep_kernel`;
+    masked-off and padding slots carry destination -1 and never hit. The
+    [BV, 128] accumulator is transposed and OR-folded over sublanes to
+    [8, BV]; the caller ORs the last 8 together. Rows of one destination
+    block are consecutive grid steps on the same output tile (the
+    scalar-prefetched `rowblk_ref` names each row's block), so the first
+    row of a block writes the tile and the others OR into it.
+    """
+    j, i = pl.program_id(1), pl.program_id(2)
+    bv = o_ref.shape[-1]
+    v_ids = jax.lax.broadcasted_iota(jnp.int32, (bv, LANES), 0)
+
+    def fold(c, acc):
+        row = pl.ds(c, 1)
+        hit = v_ids == dst_ref[row, :]                           # [BV, 128]
+        return acc | jnp.where(hit, words_ref[row, :], 0)
+
+    acc = jax.lax.fori_loop(0, dst_ref.shape[0], fold,
+                            jnp.zeros((bv, LANES), jnp.uint32))
+    part = acc.T                                                 # [128, BV]
+    while part.shape[0] > 8:
+        half = part.shape[0] // 2
+        part = part[:half] | part[half:]
+    flat = j * pl.num_programs(2) + i
+    first = (i == 0) | (rowblk_ref[flat]
+                        != rowblk_ref[jnp.maximum(flat - 1, 0)])
+
+    @pl.when(first)
+    def _():
+        o_ref[...] = part
+
+    @pl.when(jnp.logical_not(first))
+    def _():
+        o_ref[...] = o_ref[...] | part
+
+
+@functools.partial(jax.jit, static_argnames=("n", "block_v", "nb",
+                                             "interpret"))
+def frontier_or_pallas(words: jax.Array, src_t: jax.Array, dst_t: jax.Array,
+                       rowblk_t: jax.Array, n: int, block_v: int, nb: int,
+                       interpret: bool = True) -> jax.Array:
+    """Packed frontier words [W, V] uint32 + tiled edges [S, NR, BE] →
+    [W, V] uint32: out[:, v] = OR of words[:, u] over the slots (u, v).
+
+    `dst_t` is the destination local to its block, -1 on slots the
+    caller masks off (computed once per graph, not per wave); `rowblk_t`
+    [S, NR] names each row's local destination block (the identity on
+    an unchunked tiling). The grid walks (word, vertex shard, tile row)
+    and chunked rows fold into their block inside the kernel. Like
+    `relax_sweep_pallas`, the per-edge source gather runs in XLA; here
+    it is one packed word per slot instead of one key per plane.
+    """
+    s, nr, w = src_t.shape
+    if w % LANES:
+        raise ValueError(f"tile rows must be a multiple of {LANES} wide, "
+                         f"got {w} (see block_edges_topology)")
+    nw = words.shape[0]
+    lanes = (s, nr, w // LANES, LANES)
+    gathered = jnp.take(words, src_t, axis=1).reshape((nw,) + lanes)
+    out = pl.pallas_call(
+        _frontier_or_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nw, s, nr),
+            in_specs=[
+                pl.BlockSpec((None, None, None, w // LANES, LANES),
+                             lambda q, j, i, rb: (q, j, i, 0, 0)),
+                pl.BlockSpec((None, None, w // LANES, LANES),
+                             lambda q, j, i, rb: (j, i, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec(
+                (None, None, None, 8, block_v),
+                lambda q, j, i, rb: (q, j, rb[j * nr + i], 0, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((nw, s, nb, 8, block_v), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interpret,
+    )(rowblk_t.reshape(-1), gathered, dst_t.reshape(lanes))
+    out = jax.lax.reduce(out, np.uint32(0), jax.lax.bitwise_or, (3,))
+    return out.reshape(nw, -1)[:, :n]

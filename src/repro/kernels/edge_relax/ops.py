@@ -85,6 +85,12 @@ class BlockedGraph:
             return self.slot_t
         return jnp.where(self.slot_t != 0, w[self.perm_t], 0).astype(jnp.int32)
 
+    def masked_dst(self, edge_mask: jax.Array) -> jax.Array:
+        """Local destinations [S, NR, BE] with -1 on the slots that
+        `edge_mask` (original slot order) or padding leaves out: the
+        OR sweep's one per-edge stream besides the source words."""
+        return jnp.where(self.tile_mask(edge_mask) != 0, self.dstloc_t, -1)
+
     def tile_plane(self, plane: jax.Array, fill) -> jax.Array:
         """Pad + reshape a per-vertex plane [V] to dst tiles [S, NB, BV]."""
         s = self.src_t.shape[0]
@@ -385,3 +391,16 @@ def relax_sweep_sorted(keys: jax.Array, sg: SortedGraph,
     out = jax.ops.segment_min(cand, sg.dst_s, num_segments=sg.n,
                               indices_are_sorted=True)
     return jnp.minimum(out, inf)   # empty segments fill with int32-max
+
+
+def frontier_or(words: jax.Array, bg: BlockedGraph,
+                dst_t: jax.Array) -> jax.Array:
+    """The OR sweep of packed frontier words [W, V] on the tiled graph.
+
+    `dst_t` is `bg.masked_dst(edge_mask)`, which stays fixed across the
+    waves of one search. Runs interpret-mode Pallas off-TPU, like
+    `relax_sweep`, so parity tests exercise the kernel that runs on TPU.
+    """
+    return kernel.frontier_or_pallas(
+        words, bg.src_t, dst_t, bg.rowblk_t, bg.n, bg.block_v, bg.nb,
+        interpret=jax.default_backend() != "tpu")

@@ -22,3 +22,21 @@ def edge_relax(keys: jax.Array, src: jax.Array, dst: jax.Array,
     cand = jnp.where(valid, cand, INF32)
     out = jax.ops.segment_min(cand, dst, num_segments=n)
     return jnp.minimum(out, INF32)
+
+
+def frontier_or(words: jax.Array, src: jax.Array, dst: jax.Array,
+                valid: jax.Array, n: int, nbits: int = 32) -> jax.Array:
+    """out[:, v] = OR of words[:, u] over valid edges (u, v); 0 if none.
+
+    `words` is [W, V] uint32 (a packed bit plane per word). XLA has no
+    OR scatter, so each bit reduces by destination on its own (a segment
+    max of 0/1) and the bits are packed back. Only the low `nbits` bits
+    of each word are reduced (fewer queries than 32 leave the rest 0), so
+    a small batch costs no more than one int32 plane per query.
+    """
+    bits = jnp.arange(nbits, dtype=jnp.uint32)
+    got = (words[:, src].T[..., None] >> bits) & 1         # [E, W, nbits]
+    got = jnp.where(valid[:, None, None], got, 0).astype(jnp.uint8)
+    hit = jax.ops.segment_max(got, dst, num_segments=n)
+    return jnp.sum(hit.astype(jnp.uint32) << bits, axis=-1,
+                   dtype=jnp.uint32).T

@@ -171,6 +171,9 @@ class MicrobatchRecord:
     #: sum over the m real lanes of the waves each lane could still
     #: improve in; pad lanes are never counted (None on the mesh path)
     live_lane_waves: int | None
+    #: the BiBFS ran the bit-packed unit-weight path, not Bellman-Ford
+    #: waves (None on the mesh path)
+    bit_packed: bool | None
 
 
 @dataclasses.dataclass
@@ -272,7 +275,8 @@ class ServeLoop:
         self.report: ServeReport | None = None
         #: host spans at every layer boundary of the loop
         self.trace = tracing.SpanRecorder()
-        #: (waves, live_waves [B]) of the last `_answer`, None on the mesh
+        #: (waves, live_waves [B], bit_packed) of the last `_answer`, None
+        #: on the mesh
         self._counters = None
         # host-side current edge set, maintained incrementally: a
         # swap-remove list + position map keeps each tick O(batch); the
@@ -442,17 +446,19 @@ class ServeLoop:
                 d = self._answer(snap, jnp.asarray(qs[pad_idx]),
                                  jnp.asarray(qt[pad_idx]))
             t_done = time.time()
-            waves = live = None
+            waves = live = packed = None
             if self._counters is not None:
                 waves = int(self._counters[0])
                 live = int(np.asarray(self._counters[1])[:take].sum())
+                packed = bool(self._counters[2])
             out.append(MicrobatchRecord(
                 tick=tick, version=snap.version,
                 staleness=head_version - snap.version,
                 qs=qs[idx].copy(), qt=qt[idx].copy(),
                 answers=np.asarray(d)[:take].copy(),
                 latencies=t_done - (tick_t0 + offsets[idx]),
-                service_s=sp.seconds, waves=waves, live_lane_waves=live))
+                service_s=sp.seconds, waves=waves, live_lane_waves=live,
+                bit_packed=packed))
             served += take
         return served
 
@@ -672,7 +678,9 @@ class ServeLoop:
             f"{np.percentile(lat, 99) * 1e3:.1f}ms "
             f"({stale} stale) | label size {stats.label_size} | "
             f"host prep {self.trace.seconds('serve.prepare.') * 1e3:.1f}ms"
-            f" | BiBFS waves {[m.waves for m in tick_mbs]}")
+            f" | BiBFS waves {[m.waves for m in tick_mbs]}"
+            f" ({sum(bool(m.bit_packed) for m in tick_mbs)}/{len(tick_mbs)}"
+            f" bit-packed)")
 
         if cfg.verify:
             snapshots = {snap.version: snap, nxt.version: nxt}
@@ -725,7 +733,8 @@ class ServeLoop:
         tracing.publish(tracing.RunRecord(
             host_s=tuple(t.host_s for t in ticks),
             microbatches=tuple(tracing.MicrobatchHost(
-                len(m.qs), m.service_s, m.waves, m.live_lane_waves)
+                len(m.qs), m.service_s, m.waves, m.live_lane_waves,
+                m.bit_packed)
                 for m in out),
             construct_s=construct_s))
         pct = self.report.latency_percentiles()

@@ -103,6 +103,7 @@ class MicrobatchHost(NamedTuple):
     service_s: float                # dispatch -> answer
     waves: int | None               # BiBFS waves (None on the mesh path)
     live_lane_waves: int | None     # waves the real lanes could improve in
+    bit_packed: bool | None = None  # the BiBFS took the unit-weight path
 
 
 @dataclasses.dataclass(frozen=True)

@@ -184,9 +184,9 @@ def test_bibfs_parity(n, extra, bv, max_steps):
     s = jnp.asarray(rng.integers(0, n, 10), jnp.int32)
     t = jnp.asarray(rng.integers(0, n, 10), jnp.int32)
     bound = jnp.full((10,), INF_D, jnp.int32)
-    dj, wj, lj = bounded_bibfs(g, lab.landmarks, s, t, bound, max_steps)
-    dp, wp, lp = bounded_bibfs(g, lab.landmarks, s, t, bound, max_steps,
-                               plan)
+    dj, wj, lj, _ = bounded_bibfs(g, lab.landmarks, s, t, bound, max_steps)
+    dp, wp, lp, _ = bounded_bibfs(g, lab.landmarks, s, t, bound, max_steps,
+                                  plan)
     np.testing.assert_array_equal(np.asarray(dp), np.asarray(dj))
     assert int(wp) == int(wj) <= max_steps
     np.testing.assert_array_equal(np.asarray(lp), np.asarray(lj))

@@ -144,8 +144,8 @@ def test_router_requeued_counts_queries_not_entries(tmp_path):
     def accept_and_drop():
         conn, _ = srv.accept()
         replica.recv_msg(conn)       # take the dispatched batch...
-        conn.close()                 # ...and die before answering
         srv.close()                  # no reconnect: one failure exactly
+        conn.close()                 # ...and die before answering
 
     threading.Thread(target=accept_and_drop, daemon=True).start()
     router = _router(tmp_path, microbatch=8, max_queue=16, readers=[addr])

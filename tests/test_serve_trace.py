@@ -156,6 +156,12 @@ def test_live_lane_waves_within_waves(served):
         assert 0 <= m.live_lane_waves <= m.waves * m.qs.shape[0]
 
 
+def test_unit_weight_microbatches_take_the_bit_packed_path(served):
+    rep, _ = served
+    assert rep.microbatches
+    assert all(m.bit_packed is True for m in rep.microbatches)
+
+
 @pytest.mark.parametrize("pipeline", [False, True], ids=["sync", "pipeline"])
 def test_capped_search_counts_no_waves(monkeypatch, pipeline):
     """The benchmark's control (every BiBFS capped at 0 waves) runs none."""
@@ -176,5 +182,6 @@ def test_finished_run_publishes_its_host_records():
     assert rec.host_s == tuple(t.host_s for t in rep.ticks)
     assert rec.construct_s == rep.construct_s
     assert rec.microbatches == tuple(
-        (m.qs.shape[0], m.service_s, m.waves, m.live_lane_waves)
+        (m.qs.shape[0], m.service_s, m.waves, m.live_lane_waves,
+         m.bit_packed)
         for m in rep.microbatches)

@@ -93,6 +93,27 @@ def test_relax_sweep_compiles_for_v5e(one_chip, planes, hub):
     _assert_tpu_kernel(compiled)
 
 
+@pytest.mark.parametrize("batch", [32, QUERY_BATCH],
+                         ids=["microbatch", "query_batch"])
+def test_frontier_or_compiles_for_v5e(one_chip, batch):
+    """The bit-packed BiBFS's OR sweep at deployment width: one packed
+    word per 32 queries, for the serve loop's 32-query microbatch and a
+    1024-query batch, over the chunked BA tiling."""
+    nb = V // BLOCK_V
+
+    def sweep(words, src, dst, rowblk):
+        return er_kernel.frontier_or_pallas(
+            words, src, dst, rowblk, n=V, block_v=BLOCK_V, nb=nb,
+            interpret=False)
+
+    tile = _shape(one_chip, 1, ROWS, WIDTH)
+    words = jax.ShapeDtypeStruct((batch // 32, V), jnp.uint32,
+                                 sharding=one_chip)
+    compiled = jax.jit(sweep).lower(words, tile, tile,
+                                    _shape(one_chip, 1, ROWS)).compile()
+    _assert_tpu_kernel(compiled)
+
+
 @pytest.mark.parametrize("p", [R, R // 2], ids=["full", "model_shard"])
 def test_minplus_compiles_for_v5e(one_chip, p):
     """The Eq.-3 bound at B = 1024, R = 32: the full contraction and the
