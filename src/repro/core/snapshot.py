@@ -19,10 +19,11 @@ blocking with two pieces:
 * **`pipelined_update`** — the BatchHL update (batch search Algos 2–3 +
   batch repair Algo 4) as a generator of *bounded* device dispatches:
   seed, then fixpoint sweeps in chunks of `chunk_sweeps` waves, then
-  repair likewise, then finalize. The caller interleaves query
-  microbatches at every yield; because each chunk is a fixed number of
-  relaxation sweeps, a query enqueued behind it waits at most one chunk
-  (a few sweeps) instead of the full update. The chunk bodies are the
+  repair likewise, then finalize. Each yield comes once the chunk has
+  finished, and the caller interleaves query microbatches there;
+  because each chunk is a fixed number of relaxation sweeps, a query
+  waits at most for the chunk in flight (a few sweeps) instead of the
+  full update. The chunk bodies are the
   *same* seed/step functions the monolithic fixpoints use
   (`core/batch.py`), and the fixpoint is monotone, so the committed
   labelling is bit-identical to `batchhl_update` — extra converged
@@ -459,13 +460,13 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
     """BatchHL update against `snapshot` as a generator of bounded
     dispatches; returns (snapshot N+1, aff[R, V]) via StopIteration.
 
-    Yields a phase tag after *dispatching* each chunk and syncs on the
-    chunk's `changed` flag only after resuming — the caller serves query
-    microbatches against the committed snapshot at every yield, and each
-    enqueues behind at most one chunk (`chunk_sweeps` relaxation waves)
-    on the device queue. Like `batchhl_update`, a Pallas `plan` must be
-    prepared from the post-update snapshot (pass the materialized graph
-    as `g_new` to skip the recompute). With `mesh`, chunks run through
+    Yields a phase tag once each chunk (`chunk_sweeps` relaxation waves)
+    has finished on the device — the caller serves query microbatches
+    against the committed snapshot at every yield, and each finds the
+    device free: a query waits for the chunk in flight, never for one
+    dispatched after it arrived. Like `batchhl_update`, a Pallas `plan`
+    must be prepared from the post-update snapshot (pass the
+    materialized graph as `g_new` to skip the recompute). With `mesh`, chunks run through
     the `core/shard.py` wrappers on the maintenance plane grouping.
 
     `fused=True` runs the megakernel chunk variants: each phase's
@@ -521,8 +522,9 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
     if g_new is None:
         g_new = apply_batch(snapshot.graph, batch)
     # Seeds must cross deletion/re-weight edges at their pre-update weight
-    # (see coo.resolve_seed_weights); apply_batch above already consumed
-    # the original post-update weights.
+    # (see coo.resolve_seed_weights, a program of its own so that its
+    # [U, E2] match fuses); apply_batch above already consumed the
+    # original post-update weights.
     batch = resolve_seed_weights(snapshot.graph, batch)
 
     if use_frontier(plan, g_new):
@@ -577,12 +579,14 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
             g_new, batch, lab.dist, lab.hub, lab.landmarks,
             improved=improved)
         best, changed = seed, True
+    jax.block_until_ready(best)
     yield "search-seed"
     while bool(changed):
         # A donated `best` (fused path) is dead after this dispatch; the
         # rebind below is the only reference kept.
         best, changed = chunk_fn(g_new, best, seed, bound, hub_mask, plan,
                                  improved=improved, sweeps=chunk_sweeps)
+        jax.block_until_ready(best)
         yield "search"
     aff = search_finish(best, seeded, improved=improved)
 
@@ -592,10 +596,12 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
     else:
         cur = rstart_fn(g_new, aff, lab.dist, lab.hub, hub_mask, plan)
         changed = True
+    jax.block_until_ready(cur)
     yield "repair-seed"
     while bool(changed):
         cur, changed = rchunk_fn(g_new, cur, aff, hub_mask, plan,
                                  sweeps=chunk_sweeps)
+        jax.block_until_ready(cur)
         yield "repair"
 
     new_lab = finish_fn(aff, cur, lab.dist, lab.hub, lab.landmarks)

@@ -285,6 +285,7 @@ def apply_batch(g: Graph, b: BatchUpdate) -> Graph:
     return Graph(src, dst, valid, w, g.n)
 
 
+@jax.jit
 def resolve_seed_weights(g_old: Graph, b: BatchUpdate) -> BatchUpdate:
     """Replace `b.w` with the *seed* weight of each row against G (pre-update).
 
@@ -294,10 +295,16 @@ def resolve_seed_weights(g_old: Graph, b: BatchUpdate) -> BatchUpdate:
     have used it); for a re-weight it is min(old, new) — the smaller weight
     seeds a smaller key, which marks a superset of the vertices affected by
     either direction of the change (repair then recomputes exactly).
-    Jax-traceable; one [U, E2] canonical-endpoint compare, the same cost as
-    `apply_batch`'s deletion match. Rows are left untouched for padding,
-    and unmatched delete/re-weight rows fall back to weight 1 (they are
+    One [U, E2] canonical-endpoint compare, the same cost as
+    `apply_batch`'s deletion match. Padding rows get weight 1, and
+    unmatched delete/re-weight rows fall back to weight 1 (they are
     no-ops in `apply_batch` anyway).
+
+    Jitted, so that a call outside any program (the pipelined update's)
+    fuses the compare into its max-reduction over E2, as it is inside
+    `batchhl_update`: run op by op it materialises [U, E2] boolean arrays,
+    6 GiB each at U = 1024 and 6.4M edge slots, more than a chip has
+    beside the labelling.
     """
     need_old = (b.is_del | b.is_rew) & b.valid
     g_lo = jnp.minimum(g_old.src, g_old.dst)

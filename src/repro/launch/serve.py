@@ -16,9 +16,10 @@ modes (DESIGN.md §5):
 * **``--pipeline``**: the update runs as *bounded chunks*
   (`core/snapshot.pipelined_update`, ``--chunk-sweeps`` relaxation waves
   per dispatch) against snapshot N+1 while query microbatches keep
-  dispatching against the immutable committed snapshot N; the commit is
-  an atomic version swap. A query waits for at most one chunk instead of
-  the whole update, answers stay exact at the version they were served
+  dispatching against the immutable committed snapshot N, one between
+  each two chunks; the commit is an atomic version swap. A query waits
+  for at most the chunk in flight and one microbatch instead of the
+  whole update, answers stay exact at the version they were served
   (staleness ≤ 1 version, reported), and the final labelling is
   bit-identical to the synchronous loop's.
 
@@ -47,7 +48,10 @@ microbatch and each open-loop wait, and construction's phases
 (``serve.construct.*``). Each tick's self seconds by span land in
 `TickStats.host_s`, construction's in `ServeReport.construct_s`, and
 each microbatch records its service time and the BiBFS's wave counters;
-a finished run publishes these host records (`trace.last_run()`).
+in pipeline mode each tick counts its update dispatches by phase tag
+(`TickStats.update_chunks`) and each microbatch whether it ran between
+two of them (`between_chunks`). A finished run publishes these host
+records (`trace.last_run()`).
 
 Checkpointing: ``--ckpt-dir`` persists the *full* serve state each tick
 (graph topology + labelling + version + the host edge list);
@@ -66,6 +70,7 @@ required sizes before anything is dispatched.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import itertools
 import math
@@ -174,6 +179,9 @@ class MicrobatchRecord:
     #: the BiBFS ran the bit-packed unit-weight path, not Bellman-Ford
     #: waves (None on the mesh path)
     bit_packed: bool | None
+    #: dispatched between two chunks of the tick's pipelined update, while
+    #: it was still in flight (so answered one version behind the head)
+    between_chunks: bool = False
 
 
 @dataclasses.dataclass
@@ -195,6 +203,9 @@ class TickStats:
     #: host self seconds by span name (`launch/trace.py`); the values
     #: sum to the tick's wall time
     host_s: dict[str, float] = dataclasses.field(default_factory=dict)
+    #: pipelined update dispatches (`serve.update_chunk` spans) by the
+    #: phase tag of each; empty on the sync path
+    update_chunks: dict[str, int] = dataclasses.field(default_factory=dict)
 
 
 @dataclasses.dataclass
@@ -422,11 +433,13 @@ class ServeLoop:
 
     def _drain_arrived(self, tick: int, tick_t0: float, offsets: np.ndarray,
                        qs: np.ndarray, qt: np.ndarray, served: int,
-                       head_version: int,
-                       out: list[MicrobatchRecord]) -> int:
+                       head_version: int, out: list[MicrobatchRecord],
+                       between_chunks: bool = False) -> int:
         """Answer every query that has arrived by now, in microbatches of
-        at most cfg.microbatch, against the committed snapshot. Returns
-        the new served count."""
+        at most cfg.microbatch, against the committed snapshot. Between
+        two update chunks (`between_chunks`) serve one microbatch at
+        most, so the update advances a chunk per microbatch. Returns the
+        new served count."""
         cfg = self.cfg
         q = offsets.shape[0]
         while served < q:
@@ -458,8 +471,10 @@ class ServeLoop:
                 answers=np.asarray(d)[:take].copy(),
                 latencies=t_done - (tick_t0 + offsets[idx]),
                 service_s=sp.seconds, waves=waves, live_lane_waves=live,
-                bit_packed=packed))
+                bit_packed=packed, between_chunks=between_chunks))
             served += take
+            if between_chunks:
+                break
         return served
 
     def _drain_rest(self, tick: int, tick_t0: float, offsets: np.ndarray,
@@ -496,8 +511,11 @@ class ServeLoop:
 
     def _update_pipelined(self, snap: Snapshot, batch, plan, g_next,
                           tick: int, tick_t0: float, offsets, qs, qt,
-                          served_box: list, out) -> Snapshot:
-        """The chunked update: serve arrived microbatches at every yield."""
+                          served_box: list, out,
+                          chunks: collections.Counter) -> Snapshot:
+        """The chunked update: serve one microbatch of the arrived
+        queries at every yield, once the chunk before it has finished.
+        Counts each dispatch by its phase tag into `chunks`."""
         cfg = self.cfg
         upd = pipelined_update(snap, batch, plan=plan, g_new=g_next,
                                mesh=self.mesh, improved=True,
@@ -508,13 +526,16 @@ class ServeLoop:
             with self.trace.span("serve.update_chunk", tick=tick,
                                  chunk=chunk) as sp:
                 try:
-                    sp.set(tag=next(upd))
+                    tag = next(upd)
                 except StopIteration as stop:
-                    sp.set(tag="finish")
-                    nxt, aff = stop.value
-                    break
+                    tag, (nxt, aff) = "finish", stop.value
+                sp.set(tag=tag)
+                chunks[tag] += 1
+            if tag == "finish":
+                break
             served_box[0] = self._drain_arrived(
-                tick, tick_t0, offsets, qs, qt, served_box[0], head, out)
+                tick, tick_t0, offsets, qs, qt, served_box[0], head, out,
+                between_chunks=True)
         jax.block_until_ready(nxt.labelling.dist)
         self._last_aff = aff
         return nxt
@@ -618,10 +639,11 @@ class ServeLoop:
             plan = self.engine.prepare(
                 g_next, topology_changed=has_ins or event is not None)
             sp.set(retiled=self.engine.retile_count > retiles)
+        chunks = collections.Counter()
         if cfg.pipeline:
             nxt = self._update_pipelined(work, batch, plan, g_next,
                                          tick, tick_t0, offsets, qs, qt,
-                                         served_box, out)
+                                         served_box, out, chunks)
         else:
             with span("serve.update", tick=tick):
                 nxt = self._update_sync(work, batch, plan, g_next)
@@ -669,7 +691,8 @@ class ServeLoop:
                 label_size=int(nxt.labelling.label_size()),
                 queries=int(served_box[0]),
                 grew=event is not None,
-                capacity=nxt.graph.capacity, graph_n=nxt.graph.n)
+                capacity=nxt.graph.capacity, graph_n=nxt.graph.n,
+                update_chunks=dict(chunks))
         self._log(
             f"tick {tick}: update {t_upd * 1e3:.1f}ms "
             f"({stats.affected} affected, v{nxt.version}) | "
@@ -680,7 +703,10 @@ class ServeLoop:
             f"host prep {self.trace.seconds('serve.prepare.') * 1e3:.1f}ms"
             f" | BiBFS waves {[m.waves for m in tick_mbs]}"
             f" ({sum(bool(m.bit_packed) for m in tick_mbs)}/{len(tick_mbs)}"
-            f" bit-packed)")
+            f" bit-packed)"
+            + (f" | {sum(chunks.values())} update chunks {dict(chunks)}, "
+               f"{sum(m.between_chunks for m in tick_mbs)} microbatches "
+               f"between them" if chunks else ""))
 
         if cfg.verify:
             snapshots = {snap.version: snap, nxt.version: nxt}
@@ -734,9 +760,10 @@ class ServeLoop:
             host_s=tuple(t.host_s for t in ticks),
             microbatches=tuple(tracing.MicrobatchHost(
                 len(m.qs), m.service_s, m.waves, m.live_lane_waves,
-                m.bit_packed)
+                m.bit_packed, m.between_chunks)
                 for m in out),
-            construct_s=construct_s))
+            construct_s=construct_s,
+            update_chunks=tuple(t.update_chunks for t in ticks)))
         pct = self.report.latency_percentiles()
         mode = "pipeline" if cfg.pipeline else "sync"
         engine = self.engine

@@ -104,6 +104,7 @@ class MicrobatchHost(NamedTuple):
     waves: int | None               # BiBFS waves (None on the mesh path)
     live_lane_waves: int | None     # waves the real lanes could improve in
     bit_packed: bool | None = None  # the BiBFS took the unit-weight path
+    between_chunks: bool = False    # dispatched inside a pipelined update
 
 
 @dataclasses.dataclass(frozen=True)
@@ -112,6 +113,9 @@ class RunRecord:
     host_s: tuple[dict[str, float], ...]        # per committed tick
     microbatches: tuple[MicrobatchHost, ...]    # in answer order
     construct_s: dict[str, float]               # by serve.construct.* span
+    #: per committed tick, pipelined update dispatches by phase tag
+    #: (empty dicts on the sync path)
+    update_chunks: tuple[dict[str, int], ...] = ()
 
 
 _last_run: RunRecord | None = None

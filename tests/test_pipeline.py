@@ -19,8 +19,9 @@ import pytest
 import jax.numpy as jnp
 
 from repro.graphs import generators as gen
-from repro.graphs.coo import apply_batch, from_edges, make_batch, \
-    to_numpy_adj
+from repro.graphs.coo import (OP_DEL, OP_INS, OP_REW, apply_batch,
+                              from_edges, make_batch, resolve_seed_weights,
+                              to_numpy_adj)
 from repro.core.construct import build_labelling, select_landmarks_by_degree
 from repro.core.batch import batchhl_update
 from repro.core.engine import RelaxEngine
@@ -63,6 +64,66 @@ def test_pipelined_update_matches_monolithic(improved, chunk_sweeps):
             np.asarray(getattr(labm, f)))
     np.testing.assert_array_equal(np.asarray(nxt.graph.valid),
                                   np.asarray(gm.valid))
+
+
+def _weighted_instance(seed=4, n=120, extra=160, r=8, max_w=6):
+    edges = gen.random_connected(n, extra_edges=extra, seed=seed)
+    w = np.random.default_rng(seed + 1).integers(1, max_w + 1,
+                                                 size=edges.shape[0])
+    ew = np.concatenate([edges, w[:, None]], axis=1).astype(np.int32)
+    g = from_edges(n, ew, edges.shape[0] + 64)
+    lab = build_labelling(g, select_landmarks_by_degree(g, r))
+    return g, lab, ew
+
+
+@pytest.mark.parametrize("mix", ["deletions", "weighted_deletions_reweights"])
+def test_pipelined_update_matches_monolithic_on_deletions(mix):
+    """Drained dry, the chunked update commits what `batchhl_update` does
+    on a batch whose seeds take the pre-update weights (deletions, and
+    re-weights at min(old, new)): the seed-weight resolution runs as a
+    program of its own here and inside the monolith's there."""
+    if mix == "deletions":
+        g, lab, _ = _instance()
+        edges = np.stack([np.asarray(g.src), np.asarray(g.dst)], 1)[
+            np.asarray(g.valid)][::2]
+        ups = gen.random_batch_updates(edges, g.n, n_ins=0, n_del=12,
+                                       seed=5)
+    else:
+        g, lab, ew = _weighted_instance()
+        ups = gen.random_batch_updates(ew, g.n, n_ins=4, n_del=8, seed=6,
+                                       n_rew=8, max_weight=6)
+        assert any(int(u[2]) == 2 for u in ups)
+    batch = make_batch(ups, pad_to=24)
+    gm, labm, affm = batchhl_update(g, batch, lab)
+    nxt, aff = run_pipelined_update(pipelined_update(
+        Snapshot(0, g, lab, None), batch))
+    np.testing.assert_array_equal(np.asarray(aff), np.asarray(affm))
+    for f in ("dist", "hub", "highway"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(nxt.labelling, f)),
+            np.asarray(getattr(labm, f)))
+    np.testing.assert_array_equal(np.asarray(nxt.graph.w),
+                                  np.asarray(gm.w))
+
+
+def test_resolve_seed_weights_semantics():
+    """Insert → its new weight; delete → the edge's weight in G; re-weight
+    → min(old, new); an unmatched row and a padding row → 1."""
+    ew = np.array([[0, 1, 5], [1, 2, 2], [2, 3, 4]], np.int32)
+    g = from_edges(5, ew, 6)
+    batch = make_batch([(3, 4, OP_INS, 3),      # insert
+                        (1, 0, OP_DEL, 9),      # delete, reversed ends
+                        (2, 3, OP_REW, 7),      # re-weight up: old 4
+                        (1, 2, OP_REW, 1),      # re-weight down: new 1
+                        (0, 4, OP_DEL, 9),      # no such edge
+                        (0, 3, OP_REW, 6)],     # no such edge
+                       pad_to=8)
+    got = resolve_seed_weights(g, batch)
+    np.testing.assert_array_equal(np.asarray(got.w),
+                                  [3, 5, 4, 1, 1, 1, 1, 1])
+    for f in ("src", "dst", "is_del", "valid", "is_rew"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, f)),
+                                      np.asarray(getattr(batch, f)))
 
 
 def test_pipelined_update_pallas_plan():

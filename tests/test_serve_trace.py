@@ -4,6 +4,7 @@ the records a tiny serve loop keeps in both serving modes (host seconds
 per tick, service time per microbatch, BiBFS waves and live lanes)."""
 from __future__ import annotations
 
+import collections
 import glob
 import time
 
@@ -183,5 +184,94 @@ def test_finished_run_publishes_its_host_records():
     assert rec.construct_s == rep.construct_s
     assert rec.microbatches == tuple(
         (m.qs.shape[0], m.service_s, m.waves, m.live_lane_waves,
-         m.bit_packed)
+         m.bit_packed, m.between_chunks)
         for m in rep.microbatches)
+    assert rec.update_chunks == tuple(t.update_chunks for t in rep.ticks)
+
+
+# --- the pipelined update's counters ----------------------------------------
+
+PHASES = {"search-seed", "search", "repair-seed", "repair", "finish"}
+
+
+class KeepingRecorder(SpanRecorder):
+    """Keeps the (name, tag) of every span each `take()` clears: the first
+    take is construction's, then one per tick."""
+
+    def __init__(self):
+        super().__init__()
+        self.taken: list[list[tuple[str, str | None]]] = []
+
+    def take(self):
+        self.taken.append([(sp.name, sp.ids.get("tag"))
+                           for sp in self.spans])
+        return super().take()
+
+
+@pytest.fixture(scope="module", params=[False, True],
+                ids=["sync", "pipeline"])
+def kept(request):
+    loop = ServeLoop(_tiny(request.param, batches=3))
+    loop.trace = KeepingRecorder()
+    rep = loop.run()
+    return rep, loop.trace.taken[1:], trace.last_run()
+
+
+def test_update_dispatches_counted_by_phase(kept):
+    """Per tick, the dispatch counts by phase tag are the tick's
+    `serve.update_chunk` spans by tag: one of each seed and the finish,
+    search and repair chunks between. The sync path counts none."""
+    rep, per_tick, _ = kept
+    assert len(per_tick) == len(rep.ticks) == 3
+    for t, spans in zip(rep.ticks, per_tick):
+        chunk_tags = collections.Counter(
+            tag for name, tag in spans if name == "serve.update_chunk")
+        assert t.update_chunks == dict(chunk_tags)
+        if not rep.config.pipeline:
+            assert t.update_chunks == {}
+            continue
+        assert set(t.update_chunks) <= PHASES
+        assert all(t.update_chunks[p] == 1
+                   for p in ("search-seed", "repair-seed", "finish"))
+        assert sum(t.update_chunks.values()) == len(
+            [n for n, _ in spans if n == "serve.update_chunk"])
+
+
+def test_between_chunks_marks_the_stale_microbatches(kept):
+    """A microbatch is marked between chunks exactly when it was served
+    one version behind the head; on the sync path none is."""
+    rep, _, _ = kept
+    assert rep.microbatches
+    for m in rep.microbatches:
+        assert m.between_chunks == (m.staleness == 1)
+    if rep.config.pipeline:
+        assert any(m.between_chunks for m in rep.microbatches)
+    else:
+        assert not any(m.between_chunks for m in rep.microbatches)
+
+
+def test_last_run_carries_the_pipeline_counters(kept):
+    rep, _, rec = kept
+    assert rec.update_chunks == tuple(t.update_chunks for t in rep.ticks)
+    assert [m.between_chunks for m in rec.microbatches] == \
+        [m.between_chunks for m in rep.microbatches]
+
+
+def test_one_microbatch_between_two_chunks(kept):
+    """Between two update dispatches the loop serves one microbatch at
+    most (the rest of the arrived queries wait for the next chunk's
+    end), and those are the microbatches marked `between_chunks`."""
+    rep, per_tick, _ = kept
+    for t, spans in zip(rep.ticks, per_tick):
+        order = [n for n, _ in spans
+                 if n in ("serve.update_chunk", "serve.microbatch")]
+        if not rep.config.pipeline:
+            assert "serve.update_chunk" not in order
+            continue
+        last = len(order) - 1 - order[::-1].index("serve.update_chunk")
+        inside = order[order.index("serve.update_chunk"):last + 1]
+        assert "serve.microbatch serve.microbatch" not in " ".join(inside)
+        marked = sum(m.between_chunks for m in rep.microbatches
+                     if m.tick == t.tick)
+        assert marked == inside.count("serve.microbatch")
+        assert marked <= sum(t.update_chunks.values()) - 1

@@ -1,4 +1,5 @@
-"""Ahead-of-time compiles of the serving path's Pallas kernels for TPU v5e.
+"""Ahead-of-time compiles of the serving path's Pallas kernels for TPU v5e,
+and of the pipelined update's seed-weight resolution.
 
 No chip is needed: the TPU compiler compiles for a described `v5e:2x2`
 topology, and refuses what the chip's compiler would refuse (block shapes
@@ -24,6 +25,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
+from repro.graphs import coo
 from repro.kernels.edge_relax import kernel as er_kernel
 from repro.kernels.minplus import kernel as mp_kernel
 
@@ -33,6 +35,8 @@ BLOCK_V = 512        # serve loop's default destination block
 ROWS, WIDTH = 2944, 4096   # default tiling of the BA(2^20, 4) graph
 QUERY_BATCH = 1024
 CHIP_HBM = 16 * 2 ** 30    # one v5e chip
+UPDATES = 1024             # a churn tick's batch (configs/batchhl.py)
+EDGE_SLOTS = 2 * 3_211_264  # youtube-stale1's directed slots (3·2^20 + 2^16)
 
 
 @pytest.fixture(scope="module")
@@ -125,3 +129,23 @@ def test_minplus_compiles_for_v5e(one_chip, p):
         _shape(one_chip, QUERY_BATCH, p), _shape(one_chip, p, R),
         _shape(one_chip, QUERY_BATCH, R)).compile()
     _assert_tpu_kernel(compiled)
+
+
+def test_seed_weight_resolution_fuses_for_v5e(one_chip):
+    """The pipelined update's seed-weight resolution, compiled as it is
+    called (`coo.resolve_seed_weights`, a program of its own) at a churn
+    tick's 1024 updates over the Youtube-scale graph's edge slots: its
+    [U, E2] endpoint match fuses into the reduction, so the compiled
+    temporaries stay under 1 GiB, where each unfused [U, E2] boolean
+    array is 6 GiB."""
+    def arr(n, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct((n,), dtype, sharding=one_chip)
+
+    g = coo.Graph(arr(EDGE_SLOTS), arr(EDGE_SLOTS), arr(EDGE_SLOTS, bool),
+                  arr(EDGE_SLOTS), V)
+    b = coo.BatchUpdate(arr(UPDATES), arr(UPDATES), arr(UPDATES, bool),
+                        arr(UPDATES, bool), arr(UPDATES),
+                        arr(UPDATES, bool))
+    compiled = coo.resolve_seed_weights.lower(g, b).compile()
+    assert UPDATES * EDGE_SLOTS > 6 * 2 ** 30
+    assert compiled.memory_analysis().temp_size_in_bytes < 2 ** 30
