@@ -30,8 +30,9 @@ import numpy as np
 
 from repro.graphs.coo import (Graph, BatchUpdate, INF_D, apply_batch,
                               resolve_seed_weights)
-from repro.core.engine import (RelaxEngine, RelaxPlan, gather_rows,
-                               relax_rows, relax_sweep)
+from repro.core.engine import (RelaxEngine, RelaxPlan, SweepStreams,
+                               gather_rows, relax_rows, relax_sweep,
+                               sweep_streams)
 from repro.core.labelling import (
     HighwayLabelling, INF_KEY2, INF_KEY4,
     key2_dist, key2_hub, key2_make,
@@ -63,8 +64,10 @@ def _per_plane_hub_mask(labelling: HighwayLabelling, n: int) -> jax.Array:
     return per_plane_hub_mask(labelling.landmarks, labelling.landmarks, n)
 
 
-def _fixpoint(body_fn, init: jax.Array) -> jax.Array:
-    """Iterate x <- body_fn(x) (monotone, elementwise) until unchanged."""
+def _fixpoint(body_fn, init: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """Iterate x <- body_fn(x) (monotone, elementwise) until unchanged →
+    (fixpoint, waves): the waves run, the last of them the one that
+    changed nothing."""
     def cond(state):
         _, changed, it = state
         return changed & (it < _MAX_WAVES_CAP)
@@ -74,9 +77,17 @@ def _fixpoint(body_fn, init: jax.Array) -> jax.Array:
         nx = body_fn(x)
         return nx, jnp.any(nx != x), it + 1
 
-    out, _, _ = jax.lax.while_loop(cond, body,
-                                   (init, jnp.asarray(True), jnp.asarray(0)))
-    return out
+    out, _, waves = jax.lax.while_loop(
+        cond, body, (init, jnp.asarray(True), jnp.asarray(0)))
+    return out, waves
+
+
+def _over_planes(one, streams: SweepStreams | None, *planes: jax.Array):
+    """`one(*plane_slices, streams_p)` vmapped over the landmark planes,
+    the phase's `streams` split by `SweepStreams.plane_axes`."""
+    axes = None if streams is None else streams.plane_axes()
+    return jax.vmap(one, in_axes=(0,) * len(planes) + (axes,))(*planes,
+                                                               streams)
 
 
 # ---------------------------------------------------------------------------
@@ -134,8 +145,10 @@ def frontier_wave(plan: RelaxPlan, g: Graph, full_step, masked_step,
 
 
 def _frontier_fixpoint(plan: RelaxPlan, g: Graph, full_step, masked_step,
-                       init: jax.Array, front0: jax.Array) -> jax.Array:
-    """Iterate `frontier_wave` until the changed-block frontier empties."""
+                       init: jax.Array, front0: jax.Array
+                       ) -> tuple[jax.Array, jax.Array]:
+    """Iterate `frontier_wave` until the changed-block frontier empties
+    → (fixpoint, waves)."""
     def cond(state):
         _, front, it = state
         return jnp.any(front) & (it < _MAX_WAVES_CAP)
@@ -145,8 +158,9 @@ def _frontier_fixpoint(plan: RelaxPlan, g: Graph, full_step, masked_step,
         nx, nfront = frontier_wave(plan, g, full_step, masked_step, x, front)
         return nx, nfront, it + 1
 
-    out, _, _ = jax.lax.while_loop(cond, body, (init, front0, jnp.asarray(0)))
-    return out
+    out, _, waves = jax.lax.while_loop(cond, body,
+                                       (init, front0, jnp.asarray(0)))
+    return out, waves
 
 
 def search_step_rows(rows_g, best: jax.Array, bound_g: jax.Array,
@@ -232,42 +246,52 @@ def search_basic_seed(g_new: Graph, batch: BatchUpdate, dist_g: jax.Array
 
 
 def search_basic_step(plan: RelaxPlan | None, g_new: Graph, best: jax.Array,
-                      seed: jax.Array, dist_g: jax.Array) -> jax.Array:
-    """One Algo-2 relaxation wave over all planes of a slice [P, V]."""
-    def one(best_p, seed_p, dist_p):
-        cand = relax_sweep(plan, g_new, best_p, 1, INF_D)
+                      seed: jax.Array, dist_g: jax.Array,
+                      streams: SweepStreams | None = None) -> jax.Array:
+    """One Algo-2 relaxation wave over all planes of a slice [P, V].
+
+    `streams` are the search phase's (`engine.sweep_streams`; None builds
+    them in the wave); Algo 2 clears no hub bit, so their hub flags are
+    left out."""
+    if streams is not None:
+        streams = dataclasses.replace(streams, hub=None)
+
+    def one(best_p, seed_p, dist_p, streams_p):
+        cand = relax_sweep(plan, g_new, best_p, 1, INF_D, streams=streams_p)
         accept = cand <= dist_p                               # Algo2 line 12
         cand = jnp.where(accept, cand, INF_D)
         return jnp.minimum(best_p, jnp.minimum(cand, seed_p))
-    return jax.vmap(one)(best, seed, dist_g)
+    return _over_planes(one, streams, best, seed, dist_g)
 
 
 def search_basic_planes(g_new: Graph, batch: BatchUpdate, dist_g: jax.Array,
-                        plan: RelaxPlan | None = None) -> jax.Array:
-    """Algo-2 search over an arbitrary plane slice `dist_g` [P, V].
+                        plan: RelaxPlan | None = None,
+                        streams: SweepStreams | None = None
+                        ) -> tuple[jax.Array, jax.Array]:
+    """Algo-2 search over an arbitrary plane slice `dist_g` [P, V] →
+    (aff, waves).
 
     Entirely per-plane (the paper's landmark parallelism): `core/shard.py`
     runs this on each shard's local planes with no cross-shard traffic.
     """
     seed, seeded = search_basic_seed(g_new, batch, dist_g)
+    step = lambda b: search_basic_step(plan, g_new, b, seed, dist_g, streams)
     if use_frontier(plan, g_new):
-        best = _frontier_fixpoint(
-            plan, g_new,
-            lambda b: search_basic_step(plan, g_new, b, seed, dist_g),
+        best, waves = _frontier_fixpoint(
+            plan, g_new, step,
             lambda b, rows_g: search_step_rows(rows_g, b, dist_g, None,
                                                improved=False),
             seed, plan.frontier.changed_blocks(seeded))
     else:
-        best = _fixpoint(
-            lambda b: search_basic_step(plan, g_new, b, seed, dist_g), seed)
-    return seeded | (best < INF_D)
+        best, waves = _fixpoint(step, seed)
+    return seeded | (best < INF_D), waves
 
 
 def batch_search_basic(g_old: Graph, g_new: Graph, batch: BatchUpdate,
                        labelling: HighwayLabelling,
                        plan: RelaxPlan | None = None) -> jax.Array:
     """Returns aff[R, V] bool — the CP-affected supersets, per landmark."""
-    return search_basic_planes(g_new, batch, labelling.dist, plan)
+    return search_basic_planes(g_new, batch, labelling.dist, plan)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -308,42 +332,46 @@ def search_improved_seed(g_new: Graph, batch: BatchUpdate,
 
 def search_improved_step(plan: RelaxPlan | None, g_new: Graph,
                          best: jax.Array, seed: jax.Array, beta: jax.Array,
-                         hub_mask: jax.Array) -> jax.Array:
-    """One Algo-3 relaxation wave over all planes of a slice [P, V]."""
-    def one(best_p, seed_p, beta_p, hub_p):
+                         hub_mask: jax.Array,
+                         streams: SweepStreams | None = None) -> jax.Array:
+    """One Algo-3 relaxation wave over all planes of a slice [P, V].
+
+    `streams` are the search phase's, hub flags from `hub_mask` (None
+    builds them in the wave)."""
+    def one(best_p, seed_p, beta_p, hub_p, streams_p):
         # key4_extend per edge: +4, clamp, clear the l-bit at hub dsts.
         cand = relax_sweep(plan, g_new, best_p, 4, INF_KEY4,
-                           hub=hub_p, clear_bit=2)
+                           hub=hub_p, clear_bit=2, streams=streams_p)
         accept = cand <= beta_p                               # Algo3 line 14
         cand = jnp.where(accept, cand, INF_KEY4)
         return jnp.minimum(best_p, jnp.minimum(cand, seed_p))
-    return jax.vmap(one)(best, seed, beta, hub_mask)
+    return _over_planes(one, streams, best, seed, beta, hub_mask)
 
 
 def search_improved_planes(g_new: Graph, batch: BatchUpdate,
                            dist_g: jax.Array, hub_g: jax.Array,
                            hub_mask: jax.Array,
-                           plan: RelaxPlan | None = None) -> jax.Array:
-    """Algo-3 search over an arbitrary plane slice (dist/hub/hub_mask [P, V]).
+                           plan: RelaxPlan | None = None,
+                           streams: SweepStreams | None = None
+                           ) -> tuple[jax.Array, jax.Array]:
+    """Algo-3 search over an arbitrary plane slice (dist/hub/hub_mask
+    [P, V]) → (aff, waves).
 
     Entirely per-plane; `core/shard.py` runs it on shard-local planes.
     """
     seed, seeded, beta = search_improved_seed(g_new, batch, dist_g, hub_g,
                                               hub_mask)
+    step = lambda b: search_improved_step(plan, g_new, b, seed, beta,
+                                          hub_mask, streams)
     if use_frontier(plan, g_new):
-        best = _frontier_fixpoint(
-            plan, g_new,
-            lambda b: search_improved_step(plan, g_new, b, seed, beta,
-                                           hub_mask),
+        best, waves = _frontier_fixpoint(
+            plan, g_new, step,
             lambda b, rows_g: search_step_rows(rows_g, b, beta, hub_mask,
                                                improved=True),
             seed, plan.frontier.changed_blocks(seeded))
     else:
-        best = _fixpoint(
-            lambda b: search_improved_step(plan, g_new, b, seed, beta,
-                                           hub_mask),
-            seed)
-    return seeded | (best < INF_KEY4)
+        best, waves = _fixpoint(step, seed)
+    return seeded | (best < INF_KEY4), waves
 
 
 def batch_search_improved(g_old: Graph, g_new: Graph, batch: BatchUpdate,
@@ -352,7 +380,7 @@ def batch_search_improved(g_old: Graph, g_new: Graph, batch: BatchUpdate,
     """Returns aff[R, V] bool ⊇ LD-affected vertices, per landmark."""
     hub_mask = _per_plane_hub_mask(labelling, g_new.n)
     return search_improved_planes(g_new, batch, labelling.dist, labelling.hub,
-                                  hub_mask, plan)
+                                  hub_mask, plan)[0]
 
 
 # ---------------------------------------------------------------------------
@@ -360,26 +388,34 @@ def batch_search_improved(g_old: Graph, g_new: Graph, batch: BatchUpdate,
 # ---------------------------------------------------------------------------
 
 def repair_base(plan: RelaxPlan | None, g_new: Graph, aff: jax.Array,
-                key2_g: jax.Array, hub_mask: jax.Array) -> jax.Array:
+                key2_g: jax.Array, hub_mask: jax.Array,
+                streams: SweepStreams | None = None) -> jax.Array:
     """Algo-4 boundary seeds: landmark-distance bounds from *unaffected*
-    neighbours (line 3), INF_KEY2 off the affected sets. [P, V]."""
-    def one(aff_p, key2_p, hub_p):
+    neighbours (line 3), INF_KEY2 off the affected sets. [P, V].
+
+    One sweep: it takes the weight and hub streams of `streams` (the
+    search phase's; None builds them) and builds its boundary mask in
+    the sweep."""
+    def one(aff_p, key2_p, hub_p, streams_p):
         bou_mask = g_new.valid & ~aff_p[g_new.src] & aff_p[g_new.dst]
         base = relax_sweep(plan, g_new, key2_p, 2, INF_KEY2,
-                           hub=hub_p, clear_bit=1, edge_mask=bou_mask)
+                           hub=hub_p, clear_bit=1, edge_mask=bou_mask,
+                           streams=streams_p)
         return jnp.where(aff_p, base, INF_KEY2)
-    return jax.vmap(one)(aff, key2_g, hub_mask)
+    return _over_planes(one, streams, aff, key2_g, hub_mask)
 
 
 def repair_base_frontier(plan: RelaxPlan, g_new: Graph, aff: jax.Array,
-                         key2_g: jax.Array, hub_mask: jax.Array
+                         key2_g: jax.Array, hub_mask: jax.Array,
+                         streams: SweepStreams | None = None
                          ) -> jax.Array:
     """Masked `repair_base`: one sweep over the affected sets' blocks.
 
     Boundary edges end on affected vertices, so the rows of the blocks
     holding *any* plane's affected vertices cover every boundary edge of
     every plane — no propagation hop needed. Falls back to the full
-    sweep when the affected footprint overflows the row budget.
+    sweep (on `streams`) when the affected footprint overflows the row
+    budget.
     """
     ft = plan.frontier
     rows = ft.active_rows(ft.changed_blocks(jnp.any(aff, axis=0)))
@@ -400,21 +436,38 @@ def repair_base_frontier(plan: RelaxPlan, g_new: Graph, aff: jax.Array,
 
     def full(args):
         aff, key2_g, hub_mask = args
-        return repair_base(plan, g_new, aff, key2_g, hub_mask)
+        return repair_base(plan, g_new, aff, key2_g, hub_mask, streams)
 
     return jax.lax.cond(jnp.sum(rows) <= ft.rows_cap, masked, full,
                         (aff, key2_g, hub_mask))
 
 
+def repair_streams(plan: RelaxPlan | None, g_new: Graph, aff: jax.Array,
+                   streams: SweepStreams) -> SweepStreams:
+    """The repair fixpoint's streams: each plane's interior mask (both
+    ends affected, lines 5-15), built once for the phase, over the
+    weight and hub streams of the search phase's `streams`."""
+    int_mask = g_new.valid & aff[:, g_new.src] & aff[:, g_new.dst]
+    return dataclasses.replace(
+        streams, mask=sweep_streams(plan, g_new, int_mask).mask,
+        plane_mask=True)
+
+
 def repair_step(plan: RelaxPlan | None, g_new: Graph, cur: jax.Array,
-                aff: jax.Array, hub_mask: jax.Array) -> jax.Array:
-    """One Algo-4 interior relaxation wave (lines 5-15) over a slice."""
-    def one(cur_p, aff_p, hub_p):
-        int_mask = g_new.valid & aff_p[g_new.src] & aff_p[g_new.dst]
+                aff: jax.Array, hub_mask: jax.Array,
+                streams: SweepStreams | None = None) -> jax.Array:
+    """One Algo-4 interior relaxation wave (lines 5-15) over a slice.
+
+    `streams` are `repair_streams`; None builds the interior masks (and
+    the rest) in the wave."""
+    def one(cur_p, aff_p, hub_p, streams_p):
+        int_mask = (g_new.valid & aff_p[g_new.src] & aff_p[g_new.dst]
+                    if streams_p is None else None)
         cand = relax_sweep(plan, g_new, cur_p, 2, INF_KEY2,
-                           hub=hub_p, clear_bit=1, edge_mask=int_mask)
+                           hub=hub_p, clear_bit=1, edge_mask=int_mask,
+                           streams=streams_p)
         return jnp.minimum(cur_p, cand)
-    return jax.vmap(one)(cur, aff, hub_mask)
+    return _over_planes(one, streams, cur, aff, hub_mask)
 
 
 def repair_merge(aff: jax.Array, settled: jax.Array,
@@ -425,34 +478,38 @@ def repair_merge(aff: jax.Array, settled: jax.Array,
 
 def repair_planes(g_new: Graph, aff: jax.Array, key2_g: jax.Array,
                   hub_mask: jax.Array,
-                  plan: RelaxPlan | None = None) -> jax.Array:
-    """Algo-4 repair over an arbitrary plane slice; returns new key2 [P, V].
+                  plan: RelaxPlan | None = None,
+                  streams: SweepStreams | None = None
+                  ) -> tuple[jax.Array, jax.Array]:
+    """Algo-4 repair over an arbitrary plane slice → (new key2 [P, V],
+    waves).
 
     The paper's ascending-distance wavefront (settle V_min, relax neighbors)
     is realized as a boundary-seeded relaxation fixpoint: identical final
     values by Lemma 5.20 + monotonicity. Entirely per-plane, so
-    `core/shard.py` runs it on shard-local planes.
+    `core/shard.py` runs it on shard-local planes. `streams` are the
+    search phase's; the fixpoint's own are built from them once
+    (`repair_streams`). With None every wave builds its own.
     """
+    rstreams = (None if streams is None
+                else repair_streams(plan, g_new, aff, streams))
+    step = lambda c: repair_step(plan, g_new, c, aff, hub_mask, rstreams)
     if use_frontier(plan, g_new):
-        base = repair_base_frontier(plan, g_new, aff, key2_g, hub_mask)
-        settled = _frontier_fixpoint(
-            plan, g_new,
-            lambda c: repair_step(plan, g_new, c, aff, hub_mask),
+        base = repair_base_frontier(plan, g_new, aff, key2_g, hub_mask,
+                                    streams)
+        settled, waves = _frontier_fixpoint(
+            plan, g_new, step,
             lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask),
             base, plan.frontier.changed_blocks(base < INF_KEY2))
     else:
-        base = repair_base(plan, g_new, aff, key2_g, hub_mask)
-        settled = _fixpoint(
-            lambda c: repair_step(plan, g_new, c, aff, hub_mask), base)
-    return repair_merge(aff, settled, key2_g)
+        base = repair_base(plan, g_new, aff, key2_g, hub_mask, streams)
+        settled, waves = _fixpoint(step, base)
+    return repair_merge(aff, settled, key2_g), waves
 
 
-def batch_repair(g_new: Graph, aff: jax.Array,
-                 labelling: HighwayLabelling,
-                 plan: RelaxPlan | None = None) -> HighwayLabelling:
-    """Settle d^L_{G'} on the affected sets and rewrite labels minimally."""
-    hub_mask = _per_plane_hub_mask(labelling, g_new.n)
-    new_key2 = repair_planes(g_new, aff, labelling.key2(), hub_mask, plan)
+def relabel(labelling: HighwayLabelling, new_key2: jax.Array
+            ) -> HighwayLabelling:
+    """The labelling whose planes the repaired key2 planes encode."""
     dist = jnp.minimum(key2_dist(new_key2), INF_D)
     hub = key2_hub(new_key2) & (dist < INF_D)
     highway = dist[jnp.arange(labelling.num_landmarks)[:, None],
@@ -460,15 +517,62 @@ def batch_repair(g_new: Graph, aff: jax.Array,
     return HighwayLabelling(labelling.landmarks, dist, hub, highway)
 
 
+def batch_repair(g_new: Graph, aff: jax.Array,
+                 labelling: HighwayLabelling,
+                 plan: RelaxPlan | None = None) -> HighwayLabelling:
+    """Settle d^L_{G'} on the affected sets and rewrite labels minimally."""
+    hub_mask = _per_plane_hub_mask(labelling, g_new.n)
+    new_key2, _ = repair_planes(g_new, aff, labelling.key2(), hub_mask, plan)
+    return relabel(labelling, new_key2)
+
+
 # ---------------------------------------------------------------------------
 # BatchHL — Algorithm 1
 # ---------------------------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("improved",))
+def batchhl_update_counted(g_old: Graph, batch: BatchUpdate,
+                           labelling: HighwayLabelling, improved: bool = True,
+                           plan: RelaxPlan | None = None,
+                           g_new: Graph | None = None):
+    """`batchhl_update` as one program that also counts →
+    (G', Γ', aff, counts).
+
+    `counts` is {"update_waves": {"search": n, "repair": n}, the two
+    fixpoints' trip counts, "stream_builds": 2}: the search phase's
+    sweep streams (validity, weights, hub flags) are built once, before
+    its fixpoint, and the repair phase's interior masks once, before
+    its own; `repair_base` reuses the search set's weights and hub flags.
+    """
+    check_labelling_width(g_old, labelling.dist)
+    if g_new is None:
+        g_new = apply_batch(g_old, batch)
+    # Seeds for deletions / re-weights must cross the edge at its
+    # pre-update weight (resp. min of old/new) — resolved against g_old;
+    # apply_batch above takes the *original* batch (post-update weights).
+    batch = resolve_seed_weights(g_old, batch)
+    hub_mask = _per_plane_hub_mask(labelling, g_new.n)
+    streams = sweep_streams(plan, g_new, hub=hub_mask)
+    if improved:
+        aff, search_waves = search_improved_planes(
+            g_new, batch, labelling.dist, labelling.hub, hub_mask, plan,
+            streams)
+    else:
+        aff, search_waves = search_basic_planes(g_new, batch, labelling.dist,
+                                                plan, streams)
+    new_key2, repair_waves = repair_planes(g_new, aff, labelling.key2(),
+                                           hub_mask, plan, streams)
+    counts = {"update_waves": {"search": search_waves,
+                               "repair": repair_waves},
+              "stream_builds": jnp.asarray(2)}
+    return g_new, relabel(labelling, new_key2), aff, counts
+
+
 def batchhl_update(g_old: Graph, batch: BatchUpdate,
                    labelling: HighwayLabelling, improved: bool = True,
                    plan: RelaxPlan | None = None,
-                   g_new: Graph | None = None
+                   g_new: Graph | None = None, *,
+                   stats: dict | None = None
                    ) -> tuple[Graph, HighwayLabelling, jax.Array]:
     """One BatchHL step: apply B, search, repair. Returns (G', Γ', aff).
 
@@ -478,18 +582,13 @@ def batchhl_update(g_old: Graph, batch: BatchUpdate,
     shows the amortized pattern). plan=None runs the jnp reference.
     Callers that already materialized G' (typically for that prepare) can
     pass it as `g_new` to skip the recompute; it must equal
-    apply_batch(g_old, batch).
+    apply_batch(g_old, batch). `stats`, when given, receives the
+    program's counts (`batchhl_update_counted`), as device scalars.
     """
-    check_labelling_width(g_old, labelling.dist)
-    if g_new is None:
-        g_new = apply_batch(g_old, batch)
-    # Seeds for deletions / re-weights must cross the edge at its
-    # pre-update weight (resp. min of old/new) — resolved against g_old;
-    # apply_batch above takes the *original* batch (post-update weights).
-    batch = resolve_seed_weights(g_old, batch)
-    search = batch_search_improved if improved else batch_search_basic
-    aff = search(g_old, g_new, batch, labelling, plan)
-    new_labelling = batch_repair(g_new, aff, labelling, plan)
+    g_new, new_labelling, aff, counts = batchhl_update_counted(
+        g_old, batch, labelling, improved, plan, g_new)
+    if stats is not None:
+        stats.update(counts)
     return g_new, new_labelling, aff
 
 

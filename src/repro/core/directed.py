@@ -194,7 +194,7 @@ def _directed_search(g_new: Graph, batch_src, batch_dst, batch_e,
                                hub=hub_p, clear_bit=2)
             cand = jnp.where(cand <= beta_p, cand, INF_KEY4)
             return jnp.minimum(best, jnp.minimum(cand, seed_p))
-        return _fixpoint(sweep, seed_p)
+        return _fixpoint(sweep, seed_p)[0]
 
     best = jax.vmap(plane_fix)(seed, beta, hub_mask)
     return seeded | (best < INF_KEY4)

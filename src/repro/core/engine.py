@@ -18,9 +18,10 @@ dispatch shape as `query_upper_bound(use_kernel=...)` → the minplus kernel.
 The Pallas path needs a destination-block tiling of the edge list
 (`BlockedGraph`).  Tiling is a host-side O(E log E) sort, so `RelaxEngine`
 caches it per graph snapshot and rebuilds only when topology slots change:
-deletions merely flip validity bits (re-tiled on device each sweep through
-the stored slot permutation), while insertions rewrite src/dst slots and
-invalidate the tiling (see DESIGN.md §3 for the full contract).
+deletions merely flip validity bits (re-tiled on device through the
+stored slot permutation, once per fixpoint phase: `sweep_streams`), while
+insertions rewrite src/dst slots and invalidate the tiling (see DESIGN.md
+§3 for the full contract).
 `launch/serve.py` holds one engine for the serving loop so the tiling is
 amortized across all waves of a tick and across deletion-only ticks.
 
@@ -46,7 +47,8 @@ from repro.graphs.segment import masked_segment_min
 from repro.core import autotune as tune_mod
 from repro.kernels.edge_relax import ops as er_ops
 from repro.kernels.edge_relax import ref as er_ref
-from repro.kernels.edge_relax.ops import BlockedGraph, FrontierTiles, SortedGraph
+from repro.kernels.edge_relax.ops import (BlockedGraph, FrontierTiles,
+                                          SortedGraph, SweepStreams)
 
 BACKENDS = ("jnp", "pallas")
 
@@ -86,10 +88,33 @@ class RelaxPlan:
 JNP_PLAN = RelaxPlan(tiles=None, backend="jnp")
 
 
+def sweep_streams(plan: RelaxPlan | None, g: Graph,
+                  edge_mask: jax.Array | None = None,
+                  hub: jax.Array | None = None) -> SweepStreams:
+    """The per-edge operands of `relax_sweep` that its keys do not touch,
+    in the edge order of the plan's implementation: the edge mask
+    (default g.valid; [E], or [P, E] per landmark plane), g's weights,
+    and the hub flag of each edge's destination (from `hub` [P, V], or
+    None). A fixpoint phase builds them once and passes them to every
+    wave, vmapped over planes with `SweepStreams.plane_axes`.
+    """
+    mask = g.valid if edge_mask is None else edge_mask
+    if plan is None or plan.backend == "jnp":
+        return SweepStreams(mask, g.w,
+                            None if hub is None else hub[..., g.dst],
+                            mask.ndim == 2)
+    if plan.backend == "pallas":
+        if plan.impl == "sorted":
+            return plan.sorted_tiles.streams(mask, g.w, hub)
+        return plan.tiles.streams(mask, g.w, hub)
+    raise ValueError(f"unknown backend {plan.backend!r}; pick from {BACKENDS}")
+
+
 def relax_sweep(plan: RelaxPlan | None, g: Graph, keys: jax.Array,
                 step, inf, *, hub: jax.Array | None = None,
                 clear_bit: int = 0,
-                edge_mask: jax.Array | None = None) -> jax.Array:
+                edge_mask: jax.Array | None = None,
+                streams: SweepStreams | None = None) -> jax.Array:
     """One relaxation wave of `keys` [V] over the edges of `g`.
 
     plan=None (or backend "jnp") runs the segment-min reference on the COO
@@ -98,26 +123,33 @@ def relax_sweep(plan: RelaxPlan | None, g: Graph, keys: jax.Array,
     this). `edge_mask` defaults to g.valid and is always in original
     edge-slot order; `hub`/`clear_bit` realize key2/key4 path extension.
 
+    `streams` (one plane's slice of `sweep_streams`) carries the mask,
+    weights and hub flags built once for the phase; an `edge_mask` given
+    with it replaces its mask for this one sweep. Without `streams` all
+    three are built here.
+
     The metric is weighted: the extend adds step·w(u,v) from the graph's
     per-slot weight column and saturates at `inf` (int32 wrap → inf).
     Unweighted graphs carry w ≡ 1 on occupied slots, which makes the
     weighted extend bit-identical to the historical `keys + step`.
     """
-    mask = g.valid if edge_mask is None else edge_mask
+    if streams is None:
+        streams = sweep_streams(plan, g, edge_mask, hub)
+    elif edge_mask is not None:
+        streams = dataclasses.replace(
+            streams, mask=sweep_streams(plan, g, edge_mask).mask)
     if plan is None or plan.backend == "jnp":
-        s = keys[g.src] + step * g.w
+        s = keys[g.src] + step * streams.w
         cand = jnp.minimum(jnp.where(s < 0, inf, s), inf)
-        if hub is not None and clear_bit:
-            cand = jnp.where(hub[g.dst], cand & ~jnp.int32(clear_bit), cand)
-        return masked_segment_min(cand, g.dst, g.n, mask, inf)
-    if plan.backend == "pallas":
-        if plan.impl == "sorted":
-            return er_ops.relax_sweep_sorted(keys, plan.sorted_tiles, mask,
-                                             step, inf, clear_bit=clear_bit,
-                                             hub=hub, w=g.w)
-        return er_ops.relax_sweep(keys, plan.tiles, mask, step, inf,
-                                  clear_bit=clear_bit, hub=hub, w=g.w)
-    raise ValueError(f"unknown backend {plan.backend!r}; pick from {BACKENDS}")
+        if streams.hub is not None and clear_bit:
+            cand = jnp.where(streams.hub, cand & ~jnp.int32(clear_bit), cand)
+        return masked_segment_min(cand, g.dst, g.n, streams.mask, inf)
+    if plan.impl == "sorted":
+        return er_ops.relax_sweep_sorted(keys, plan.sorted_tiles, None, step,
+                                         inf, clear_bit=clear_bit,
+                                         streams=streams)
+    return er_ops.relax_sweep(keys, plan.tiles, None, step, inf,
+                              clear_bit=clear_bit, streams=streams)
 
 
 def frontier_or_sweep(plan: RelaxPlan | None, g: Graph, lanes: int):

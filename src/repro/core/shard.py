@@ -183,12 +183,12 @@ def shard_batchhl_update(mesh, g_old: Graph, batch: BatchUpdate,
     def body(g_new, batch, dist, hub, own, landmarks_full, plan):
         hub_mask = per_plane_hub_mask(landmarks_full, own, g_new.n)
         if improved:
-            aff = search_improved_planes(g_new, batch, dist, hub, hub_mask,
-                                         plan)
+            aff, _ = search_improved_planes(g_new, batch, dist, hub,
+                                            hub_mask, plan)
         else:
-            aff = search_basic_planes(g_new, batch, dist, plan)
-        new_key2 = repair_planes(g_new, aff, key2_make(dist, hub), hub_mask,
-                                 plan)
+            aff, _ = search_basic_planes(g_new, batch, dist, plan)
+        new_key2, _ = repair_planes(g_new, aff, key2_make(dist, hub),
+                                    hub_mask, plan)
         ndist = jnp.minimum(key2_dist(new_key2), INF_D)
         nhub = key2_hub(new_key2) & (ndist < INF_D)
         highway = ndist[:, landmarks_full]   # local rows [P, R]

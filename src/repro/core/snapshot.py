@@ -56,10 +56,11 @@ from repro.checkpoint import manager as ckpt
 from repro.core.batch import (check_labelling_width, frontier_wave,
                               repair_base, repair_base_frontier,
                               repair_merge, repair_step, repair_step_rows,
-                              search_basic_seed, search_basic_step,
-                              search_improved_seed, search_improved_step,
-                              search_step_rows, use_frontier)
-from repro.core.engine import RelaxPlan
+                              repair_streams, search_basic_seed,
+                              search_basic_step, search_improved_seed,
+                              search_improved_step, search_step_rows,
+                              use_frontier)
+from repro.core.engine import RelaxPlan, SweepStreams, sweep_streams
 from repro.core.labelling import (HighwayLabelling, INF_KEY2, INF_KEY4,
                                   grow_labelling,
                                   key2_dist, key2_hub, key2_make,
@@ -145,40 +146,61 @@ def grow_snapshot(snap: Snapshot, *, capacity: int | None = None,
 # ---------------------------------------------------------------------------
 # Bounded update chunks (unsharded; core/shard.py holds the mesh twins)
 # ---------------------------------------------------------------------------
+#
+# Each fixpoint phase's sweep streams (`engine.sweep_streams`: the tiled
+# edge mask, weights and per-plane hub flags) are built once, in the
+# phase's first dispatch, and passed to every later chunk of the phase:
+# `search_seed` builds the search set, `repair_start` the repair set (the
+# interior masks, over the search set's weights and hub flags). A chunk's
+# wave then gathers only its source keys. The repair starts take the
+# search set donated, so the hub flags it hands on keep their buffer (a
+# copy would hold a third [R, S, NR, W] stream at once); callers rebind
+# to the returned set.
 
 @partial(jax.jit, static_argnames=("improved",))
 def search_seed(g_new: Graph, batch: BatchUpdate, dist: jax.Array,
-                hub: jax.Array, landmarks: jax.Array, improved: bool = True
-                ) -> tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
-    """Batch-search initial state: (seed keys, seeded, bound, hub_mask).
+                hub: jax.Array, landmarks: jax.Array,
+                plan: RelaxPlan | None = None, improved: bool = True):
+    """Batch-search initial state: (seed keys, seeded, bound, hub_mask,
+    streams).
 
     `bound` is the per-vertex accept bound of the search step (β for the
     improved Algo 3, d_G for the basic Algo 2); `hub_mask` is reused by
-    every later phase of the tick.
+    every later phase of the tick, `streams` by every search chunk and
+    by `repair_start`.
     """
     check_labelling_width(g_new, dist)
     hub_mask = per_plane_hub_mask(landmarks, landmarks, g_new.n)
+    streams = sweep_streams(plan, g_new, hub=hub_mask)
     if improved:
         seed, seeded, beta = search_improved_seed(g_new, batch, dist, hub,
                                                   hub_mask)
-        return seed, seeded, beta, hub_mask
+        return seed, seeded, beta, hub_mask, streams
     seed, seeded = search_basic_seed(g_new, batch, dist)
-    return seed, seeded, dist, hub_mask
+    return seed, seeded, dist, hub_mask, streams
+
+
+def _search_waves(plan, g_new, cur, seed, bound, hub_mask, streams,
+                  improved, sweeps):
+    """`sweeps` search waves (Algo 3, or Algo 2) from `cur`."""
+    for _ in range(sweeps):
+        if improved:
+            cur = search_improved_step(plan, g_new, cur, seed, bound,
+                                       hub_mask, streams)
+        else:
+            cur = search_basic_step(plan, g_new, cur, seed, bound, streams)
+    return cur
 
 
 @partial(jax.jit, static_argnames=("improved", "sweeps"))
 def search_chunk(g_new: Graph, best: jax.Array, seed: jax.Array,
                  bound: jax.Array, hub_mask: jax.Array,
-                 plan: RelaxPlan | None, improved: bool = True,
+                 plan: RelaxPlan | None, streams: SweepStreams,
+                 improved: bool = True,
                  sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
     """`sweeps` search waves in one bounded dispatch → (best', changed)."""
-    cur = best
-    for _ in range(sweeps):
-        if improved:
-            cur = search_improved_step(plan, g_new, cur, seed, bound,
-                                       hub_mask)
-        else:
-            cur = search_basic_step(plan, g_new, cur, seed, bound)
+    cur = _search_waves(plan, g_new, best, seed, bound, hub_mask, streams,
+                        improved, sweeps)
     return cur, jnp.any(cur != best)
 
 
@@ -190,22 +212,28 @@ def search_finish(best: jax.Array, seeded: jax.Array,
     return seeded | (best < inf)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnames=("streams",))
 def repair_start(g_new: Graph, aff: jax.Array, dist: jax.Array,
                  hub: jax.Array, hub_mask: jax.Array,
-                 plan: RelaxPlan | None) -> jax.Array:
-    """Algo-4 boundary seeding as one bounded dispatch."""
-    return repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask)
+                 plan: RelaxPlan | None, streams: SweepStreams
+                 ) -> tuple[jax.Array, SweepStreams]:
+    """Algo-4 boundary seeding as one bounded dispatch → (base, the
+    repair phase's streams, built from the search phase's `streams`,
+    which are donated)."""
+    base = repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask,
+                       streams)
+    return base, repair_streams(plan, g_new, aff, streams)
 
 
 @partial(jax.jit, static_argnames=("sweeps",))
 def repair_chunk(g_new: Graph, cur: jax.Array, aff: jax.Array,
                  hub_mask: jax.Array, plan: RelaxPlan | None,
+                 streams: SweepStreams,
                  sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
     """`sweeps` interior repair waves in one bounded dispatch."""
     out = cur
     for _ in range(sweeps):
-        out = repair_step(plan, g_new, out, aff, hub_mask)
+        out = repair_step(plan, g_new, out, aff, hub_mask, streams)
     return out, jnp.any(out != cur)
 
 
@@ -216,18 +244,26 @@ def repair_chunk(g_new: Graph, cur: jax.Array, aff: jax.Array,
 # changed-block bitmap `front` [P, NBf] through the chunk loop as extra
 # carried state; the per-chunk convergence flag becomes "is the frontier
 # empty", which is the same fixpoint condition expressed one wave earlier
-# (values are bit-identical either way — the parity suite pins it).
+# (values are bit-identical either way — the parity suite pins it). The
+# full-sweep fallback of a wave reads the phase's streams.
 
-def _search_wave_fns(plan, g_new, seed, bound, hub_mask, improved):
+def _search_wave_fns(plan, g_new, seed, bound, hub_mask, streams, improved):
     """(full_step, masked_step) pair for one search wave (Algo 2/3)."""
     if improved:
         return (lambda b: search_improved_step(plan, g_new, b, seed, bound,
-                                               hub_mask),
+                                               hub_mask, streams),
                 lambda b, rows_g: search_step_rows(rows_g, b, bound,
                                                    hub_mask, improved=True))
-    return (lambda b: search_basic_step(plan, g_new, b, seed, bound),
+    return (lambda b: search_basic_step(plan, g_new, b, seed, bound,
+                                        streams),
             lambda b, rows_g: search_step_rows(rows_g, b, bound, None,
                                                improved=False))
+
+
+def _repair_wave_fns(plan, g_new, aff, hub_mask, streams):
+    """(full_step, masked_step) pair for one interior repair wave."""
+    return (lambda c: repair_step(plan, g_new, c, aff, hub_mask, streams),
+            lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask))
 
 
 @jax.jit
@@ -240,33 +276,36 @@ def frontier_seed_blocks(plan: RelaxPlan, seeded: jax.Array) -> jax.Array:
 def search_chunk_frontier(g_new: Graph, best: jax.Array, front: jax.Array,
                           seed: jax.Array, bound: jax.Array,
                           hub_mask: jax.Array, plan: RelaxPlan,
-                          improved: bool = True, sweeps: int = 1):
+                          streams: SweepStreams, improved: bool = True,
+                          sweeps: int = 1):
     """`search_chunk` with frontier waves → (best', front', changed)."""
     full, masked = _search_wave_fns(plan, g_new, seed, bound, hub_mask,
-                                    improved)
+                                    streams, improved)
     cur = best
     for _ in range(sweeps):
         cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
     return cur, front, jnp.any(front)
 
 
-@jax.jit
+@partial(jax.jit, donate_argnames=("streams",))
 def repair_start_frontier(g_new: Graph, aff: jax.Array, dist: jax.Array,
                           hub: jax.Array, hub_mask: jax.Array,
-                          plan: RelaxPlan):
-    """`repair_start` masked to the affected blocks → (base, front)."""
+                          plan: RelaxPlan, streams: SweepStreams):
+    """`repair_start` masked to the affected blocks →
+    (base, front, streams)."""
     base = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
-                                hub_mask)
-    return base, plan.frontier.changed_blocks(base < INF_KEY2)
+                                hub_mask, streams)
+    return (base, plan.frontier.changed_blocks(base < INF_KEY2),
+            repair_streams(plan, g_new, aff, streams))
 
 
 @partial(jax.jit, static_argnames=("sweeps",))
 def repair_chunk_frontier(g_new: Graph, cur: jax.Array, front: jax.Array,
                           aff: jax.Array, hub_mask: jax.Array,
-                          plan: RelaxPlan, sweeps: int = 1):
+                          plan: RelaxPlan, streams: SweepStreams,
+                          sweeps: int = 1):
     """`repair_chunk` with frontier waves → (cur', front', changed)."""
-    full = lambda c: repair_step(plan, g_new, c, aff, hub_mask)
-    masked = lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask)
+    full, masked = _repair_wave_fns(plan, g_new, aff, hub_mask, streams)
     out = cur
     for _ in range(sweeps):
         out, front = frontier_wave(plan, g_new, full, masked, out, front)
@@ -279,7 +318,7 @@ def fused_search_start_frontier(g_new: Graph, batch: BatchUpdate,
                                 landmarks: jax.Array, plan: RelaxPlan,
                                 improved: bool = True, sweeps: int = 1):
     """`fused_search_start` with frontier waves →
-    (best, front, seed, seeded, bound, hub_mask, changed).
+    (best, front, seed, seeded, bound, hub_mask, streams, changed).
 
     Returned `best` is a fresh buffer distinct from `seed` (each masked
     wave's scatter-min is functional), so the donation contract of the
@@ -287,6 +326,7 @@ def fused_search_start_frontier(g_new: Graph, batch: BatchUpdate,
     """
     check_labelling_width(g_new, dist)
     hub_mask = per_plane_hub_mask(landmarks, landmarks, g_new.n)
+    streams = sweep_streams(plan, g_new, hub=hub_mask)
     if improved:
         seed, seeded, bound = search_improved_seed(g_new, batch, dist, hub,
                                                    hub_mask)
@@ -295,53 +335,54 @@ def fused_search_start_frontier(g_new: Graph, batch: BatchUpdate,
         bound = dist
     front = plan.frontier.changed_blocks(seeded)
     full, masked = _search_wave_fns(plan, g_new, seed, bound, hub_mask,
-                                    improved)
+                                    streams, improved)
     best = seed
     for _ in range(sweeps):
         best, front = frontier_wave(plan, g_new, full, masked, best, front)
-    return best, front, seed, seeded, bound, hub_mask, jnp.any(front)
+    return (best, front, seed, seeded, bound, hub_mask, streams,
+            jnp.any(front))
 
 
 @partial(jax.jit, static_argnames=("improved", "sweeps"), donate_argnums=(1,))
 def fused_search_chunk_frontier(g_new: Graph, best: jax.Array,
                                 front: jax.Array, seed: jax.Array,
                                 bound: jax.Array, hub_mask: jax.Array,
-                                plan: RelaxPlan, improved: bool = True,
-                                sweeps: int = 1):
+                                plan: RelaxPlan, streams: SweepStreams,
+                                improved: bool = True, sweeps: int = 1):
     """`search_chunk_frontier` with the labelling plane donated."""
     full, masked = _search_wave_fns(plan, g_new, seed, bound, hub_mask,
-                                    improved)
+                                    streams, improved)
     cur = best
     for _ in range(sweeps):
         cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
     return cur, front, jnp.any(front)
 
 
-@partial(jax.jit, static_argnames=("sweeps",))
+@partial(jax.jit, static_argnames=("sweeps",), donate_argnames=("streams",))
 def fused_repair_start_chunk_frontier(g_new: Graph, aff: jax.Array,
                                       dist: jax.Array, hub: jax.Array,
                                       hub_mask: jax.Array, plan: RelaxPlan,
+                                      streams: SweepStreams,
                                       sweeps: int = 1):
     """`fused_repair_start_chunk` with frontier waves →
-    (cur, front, changed)."""
+    (cur, front, streams, changed)."""
     cur = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
-                               hub_mask)
+                               hub_mask, streams)
     front = plan.frontier.changed_blocks(cur < INF_KEY2)
-    full = lambda c: repair_step(plan, g_new, c, aff, hub_mask)
-    masked = lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask)
+    streams = repair_streams(plan, g_new, aff, streams)
+    full, masked = _repair_wave_fns(plan, g_new, aff, hub_mask, streams)
     for _ in range(sweeps):
         cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-    return cur, front, jnp.any(front)
+    return cur, front, streams, jnp.any(front)
 
 
 @partial(jax.jit, static_argnames=("sweeps",), donate_argnums=(1,))
 def fused_repair_chunk_frontier(g_new: Graph, cur: jax.Array,
                                 front: jax.Array, aff: jax.Array,
                                 hub_mask: jax.Array, plan: RelaxPlan,
-                                sweeps: int = 1):
+                                streams: SweepStreams, sweeps: int = 1):
     """`repair_chunk_frontier` with the key2 plane donated."""
-    full = lambda c: repair_step(plan, g_new, c, aff, hub_mask)
-    masked = lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask)
+    full, masked = _repair_wave_fns(plan, g_new, aff, hub_mask, streams)
     out = cur
     for _ in range(sweeps):
         out, front = frontier_wave(plan, g_new, full, masked, out, front)
@@ -373,67 +414,65 @@ def fused_search_start(g_new: Graph, batch: BatchUpdate, dist: jax.Array,
                        sweeps: int = 1):
     """Seed + first `sweeps` search waves in ONE dispatch.
 
-    Returns (best, seed, seeded, bound, hub_mask, changed). Convergence
-    flag semantics match the unfused seed-then-chunk pair: the fixpoint
-    is monotone, so `best == seed` after `sweeps` waves means settled.
+    Returns (best, seed, seeded, bound, hub_mask, streams, changed).
+    Convergence flag semantics match the unfused seed-then-chunk pair:
+    the fixpoint is monotone, so `best == seed` after `sweeps` waves
+    means settled.
     """
     check_labelling_width(g_new, dist)
     hub_mask = per_plane_hub_mask(landmarks, landmarks, g_new.n)
+    streams = sweep_streams(plan, g_new, hub=hub_mask)
     if improved:
         seed, seeded, bound = search_improved_seed(g_new, batch, dist, hub,
                                                    hub_mask)
     else:
         seed, seeded = search_basic_seed(g_new, batch, dist)
         bound = dist
-    best = seed
-    for _ in range(sweeps):
-        if improved:
-            best = search_improved_step(plan, g_new, best, seed, bound,
-                                        hub_mask)
-        else:
-            best = search_basic_step(plan, g_new, best, seed, bound)
-    return best, seed, seeded, bound, hub_mask, jnp.any(best != seed)
+    best = _search_waves(plan, g_new, seed, seed, bound, hub_mask, streams,
+                         improved, sweeps)
+    return (best, seed, seeded, bound, hub_mask, streams,
+            jnp.any(best != seed))
 
 
 @partial(jax.jit, static_argnames=("improved", "sweeps"), donate_argnums=(1,))
 def fused_search_chunk(g_new: Graph, best: jax.Array, seed: jax.Array,
                        bound: jax.Array, hub_mask: jax.Array,
-                       plan: RelaxPlan | None, improved: bool = True,
+                       plan: RelaxPlan | None, streams: SweepStreams,
+                       improved: bool = True,
                        sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
     """`search_chunk` with the labelling plane donated (updated in place
     on backends that honor donation; a perf no-op where they don't)."""
-    cur = best
-    for _ in range(sweeps):
-        if improved:
-            cur = search_improved_step(plan, g_new, cur, seed, bound,
-                                       hub_mask)
-        else:
-            cur = search_basic_step(plan, g_new, cur, seed, bound)
+    cur = _search_waves(plan, g_new, best, seed, bound, hub_mask, streams,
+                        improved, sweeps)
     return cur, jnp.any(cur != best)
 
 
-@partial(jax.jit, static_argnames=("sweeps",))
+@partial(jax.jit, static_argnames=("sweeps",), donate_argnames=("streams",))
 def fused_repair_start_chunk(g_new: Graph, aff: jax.Array, dist: jax.Array,
                              hub: jax.Array, hub_mask: jax.Array,
-                             plan: RelaxPlan | None, sweeps: int = 1
-                             ) -> tuple[jax.Array, jax.Array]:
+                             plan: RelaxPlan | None, streams: SweepStreams,
+                             sweeps: int = 1):
     """Algo-4 boundary seeding + first `sweeps` interior waves in ONE
-    dispatch → (cur, changed); returns a fresh `cur` safe to donate."""
-    cur0 = repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask)
+    dispatch → (cur, streams, changed), the repair phase's streams built
+    from the search phase's; returns a fresh `cur` safe to donate."""
+    cur0 = repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask,
+                       streams)
+    streams = repair_streams(plan, g_new, aff, streams)
     cur = cur0
     for _ in range(sweeps):
-        cur = repair_step(plan, g_new, cur, aff, hub_mask)
-    return cur, jnp.any(cur != cur0)
+        cur = repair_step(plan, g_new, cur, aff, hub_mask, streams)
+    return cur, streams, jnp.any(cur != cur0)
 
 
 @partial(jax.jit, static_argnames=("sweeps",), donate_argnums=(1,))
 def fused_repair_chunk(g_new: Graph, cur: jax.Array, aff: jax.Array,
                        hub_mask: jax.Array, plan: RelaxPlan | None,
+                       streams: SweepStreams,
                        sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
     """`repair_chunk` with the key2 plane donated."""
     out = cur
     for _ in range(sweeps):
-        out = repair_step(plan, g_new, out, aff, hub_mask)
+        out = repair_step(plan, g_new, out, aff, hub_mask, streams)
     return out, jnp.any(out != cur)
 
 
@@ -448,6 +487,80 @@ def update_finish(aff: jax.Array, settled: jax.Array, dist: jax.Array,
     return HighwayLabelling(landmarks, ndist, nhub, highway)
 
 
+def _mesh_twins(mesh, fused: bool) -> dict:
+    """The `core/shard.py` twins of the chunk programs, behind the
+    unsharded programs' signatures. The mesh bodies build their sweep
+    streams in every wave, so these take the streams and return None
+    for them."""
+    from repro.core import shard
+
+    search = shard.shard_fused_search_chunk if fused else \
+        shard.shard_search_chunk
+    repair = shard.shard_fused_repair_chunk if fused else \
+        shard.shard_repair_chunk
+    f_search = shard.shard_fused_search_chunk_frontier if fused else \
+        shard.shard_search_chunk_frontier
+    f_repair = shard.shard_fused_repair_chunk_frontier if fused else \
+        shard.shard_repair_chunk_frontier
+
+    def seed(g, b, dist, hub, lms, plan, improved):
+        return (*shard.shard_search_seed(mesh, g, b, dist, hub, lms,
+                                         improved=improved), None)
+
+    def chunk(g, best, seed, bound, hub_mask, plan, streams, improved,
+              sweeps):
+        return search(mesh, g, best, seed, bound, hub_mask, plan,
+                      improved=improved, sweeps=sweeps)
+
+    def fstart(g, b, dist, hub, lms, plan, improved, sweeps):
+        *out, changed = shard.shard_fused_search_start(
+            mesh, g, b, dist, hub, lms, plan, improved=improved,
+            sweeps=sweeps)
+        return (*out, None, changed)
+
+    def rstart(g, aff, dist, hub, hub_mask, plan, streams):
+        return shard.shard_repair_start(mesh, g, aff, dist, hub, hub_mask,
+                                        plan), None
+
+    def rchunk(g, cur, aff, hub_mask, plan, streams, sweeps):
+        return repair(mesh, g, cur, aff, hub_mask, plan, sweeps=sweeps)
+
+    def frstart(g, aff, dist, hub, hub_mask, plan, streams, sweeps):
+        cur, changed = shard.shard_fused_repair_start_chunk(
+            mesh, g, aff, dist, hub, hub_mask, plan, sweeps=sweeps)
+        return cur, None, changed
+
+    def f_chunk(g, best, front, seed, bound, hub_mask, plan, streams,
+                improved, sweeps):
+        return f_search(mesh, g, best, front, seed, bound, hub_mask, plan,
+                        improved=improved, sweeps=sweeps)
+
+    def f_fstart(g, b, dist, hub, lms, plan, improved, sweeps):
+        *out, changed = shard.shard_fused_search_start_frontier(
+            mesh, g, b, dist, hub, lms, plan, improved=improved,
+            sweeps=sweeps)
+        return (*out, None, changed)
+
+    def f_rstart(g, aff, dist, hub, hub_mask, plan, streams):
+        return (*shard.shard_repair_start_frontier(
+            mesh, g, aff, dist, hub, hub_mask, plan), None)
+
+    def f_rchunk(g, cur, front, aff, hub_mask, plan, streams, sweeps):
+        return f_repair(mesh, g, cur, front, aff, hub_mask, plan,
+                        sweeps=sweeps)
+
+    def f_frstart(g, aff, dist, hub, hub_mask, plan, streams, sweeps):
+        cur, front, changed = shard.shard_fused_repair_start_chunk_frontier(
+            mesh, g, aff, dist, hub, hub_mask, plan, sweeps=sweeps)
+        return cur, front, None, changed
+
+    return dict(seed=seed, chunk=chunk, fstart=fstart, rstart=rstart,
+                rchunk=rchunk, frstart=frstart,
+                finish=partial(shard.shard_update_finish, mesh),
+                f_chunk=f_chunk, f_fstart=f_fstart, f_rstart=f_rstart,
+                f_rchunk=f_rchunk, f_frstart=f_frstart)
+
+
 # ---------------------------------------------------------------------------
 # The pipelined update
 # ---------------------------------------------------------------------------
@@ -456,7 +569,7 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
                      plan: RelaxPlan | None = None,
                      g_new: Graph | None = None, mesh=None,
                      improved: bool = True, chunk_sweeps: int = 1,
-                     fused: bool = False):
+                     fused: bool = False, stats: dict | None = None):
     """BatchHL update against `snapshot` as a generator of bounded
     dispatches; returns (snapshot N+1, aff[R, V]) via StopIteration.
 
@@ -474,6 +587,11 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
     donate the labelling plane so sweeps update it in place (same phase
     tags, same bit-identical result — the fused-parity tests pin it).
 
+    `stats`, when given, receives the counts `batchhl_update` gives:
+    "update_waves", the waves each fixpoint ran (chunks × chunk_sweeps),
+    and "stream_builds", the sweep-stream sets the phases built (0
+    under a mesh, whose bodies build them per wave).
+
     Drive it to completion with `run_pipelined_update`, or manually:
 
         gen = pipelined_update(snap, batch, plan=plan)
@@ -489,7 +607,6 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
         rchunk_fn = fused_repair_chunk if fused else repair_chunk
         frstart_fn = fused_repair_start_chunk
         finish_fn = update_finish
-        f_seed_blocks = frontier_seed_blocks
         f_chunk_fn = (fused_search_chunk_frontier if fused
                       else search_chunk_frontier)
         f_fstart_fn = fused_search_start_frontier
@@ -498,25 +615,13 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
                        else repair_chunk_frontier)
         f_frstart_fn = fused_repair_start_chunk_frontier
     else:
-        from repro.core import shard
-        seed_fn = partial(shard.shard_search_seed, mesh)
-        chunk_fn = partial(shard.shard_fused_search_chunk if fused
-                           else shard.shard_search_chunk, mesh)
-        fstart_fn = partial(shard.shard_fused_search_start, mesh)
-        rstart_fn = partial(shard.shard_repair_start, mesh)
-        rchunk_fn = partial(shard.shard_fused_repair_chunk if fused
-                            else shard.shard_repair_chunk, mesh)
-        frstart_fn = partial(shard.shard_fused_repair_start_chunk, mesh)
-        finish_fn = partial(shard.shard_update_finish, mesh)
-        f_seed_blocks = frontier_seed_blocks
-        f_chunk_fn = partial(shard.shard_fused_search_chunk_frontier if fused
-                             else shard.shard_search_chunk_frontier, mesh)
-        f_fstart_fn = partial(shard.shard_fused_search_start_frontier, mesh)
-        f_rstart_fn = partial(shard.shard_repair_start_frontier, mesh)
-        f_rchunk_fn = partial(shard.shard_fused_repair_chunk_frontier if fused
-                              else shard.shard_repair_chunk_frontier, mesh)
-        f_frstart_fn = partial(shard.shard_fused_repair_start_chunk_frontier,
-                               mesh)
+        tw = _mesh_twins(mesh, fused)
+        seed_fn, chunk_fn, fstart_fn = tw["seed"], tw["chunk"], tw["fstart"]
+        rstart_fn, rchunk_fn = tw["rstart"], tw["rchunk"]
+        frstart_fn, finish_fn = tw["frstart"], tw["finish"]
+        f_chunk_fn, f_fstart_fn = tw["f_chunk"], tw["f_fstart"]
+        f_rstart_fn, f_rchunk_fn = tw["f_rstart"], tw["f_rchunk"]
+        f_frstart_fn = tw["f_frstart"]
 
     lab = snapshot.labelling
     if g_new is None:
@@ -534,77 +639,94 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
         # chunk-carried state like `best`/`cur`, never surfaced to
         # callers.
         fr = {"front": None}
-        base_seed_fn, base_fstart_fn = seed_fn, fstart_fn
+        base_seed_fn = seed_fn
 
-        def seed_fn(g, b, dist, hub, lms, improved):
-            seed, seeded, bound, hub_mask = base_seed_fn(
-                g, b, dist, hub, lms, improved=improved)
-            fr["front"] = f_seed_blocks(plan, seeded)
-            return seed, seeded, bound, hub_mask
+        def seed_fn(g, b, dist, hub, lms, plan_, improved):
+            out = base_seed_fn(g, b, dist, hub, lms, plan_,
+                               improved=improved)
+            fr["front"] = frontier_seed_blocks(plan, out[1])
+            return out
 
-        def chunk_fn(g, best, seed, bound, hub_mask, plan_, improved,
-                     sweeps):
+        def chunk_fn(g, best, seed, bound, hub_mask, plan_, streams,
+                     improved, sweeps):
             best, fr["front"], changed = f_chunk_fn(
-                g, best, fr["front"], seed, bound, hub_mask, plan_,
+                g, best, fr["front"], seed, bound, hub_mask, plan_, streams,
                 improved=improved, sweeps=sweeps)
             return best, changed
 
         def fstart_fn(g, b, dist, hub, lms, plan_, improved, sweeps):
-            (best, fr["front"], seed, seeded, bound, hub_mask,
+            (best, fr["front"], seed, seeded, bound, hub_mask, streams,
              changed) = f_fstart_fn(g, b, dist, hub, lms, plan_,
                                     improved=improved, sweeps=sweeps)
-            return best, seed, seeded, bound, hub_mask, changed
+            return best, seed, seeded, bound, hub_mask, streams, changed
 
-        def rstart_fn(g, aff, dist, hub, hub_mask, plan_):
-            cur, fr["front"] = f_rstart_fn(g, aff, dist, hub, hub_mask,
-                                           plan_)
-            return cur
+        def rstart_fn(g, aff, dist, hub, hub_mask, plan_, streams):
+            cur, fr["front"], streams = f_rstart_fn(g, aff, dist, hub,
+                                                    hub_mask, plan_, streams)
+            return cur, streams
 
-        def rchunk_fn(g, cur, aff, hub_mask, plan_, sweeps):
+        def rchunk_fn(g, cur, aff, hub_mask, plan_, streams, sweeps):
             cur, fr["front"], changed = f_rchunk_fn(
-                g, cur, fr["front"], aff, hub_mask, plan_, sweeps=sweeps)
+                g, cur, fr["front"], aff, hub_mask, plan_, streams,
+                sweeps=sweeps)
             return cur, changed
 
-        def frstart_fn(g, aff, dist, hub, hub_mask, plan_, sweeps):
-            cur, fr["front"], changed = f_frstart_fn(
-                g, aff, dist, hub, hub_mask, plan_, sweeps=sweeps)
-            return cur, changed
+        def frstart_fn(g, aff, dist, hub, hub_mask, plan_, streams, sweeps):
+            cur, fr["front"], streams, changed = f_frstart_fn(
+                g, aff, dist, hub, hub_mask, plan_, streams, sweeps=sweeps)
+            return cur, streams, changed
 
+    waves = {"search": 0, "repair": 0}
+    builds = 0
     if fused:
-        best, seed, seeded, bound, hub_mask, changed = fstart_fn(
+        best, seed, seeded, bound, hub_mask, streams, changed = fstart_fn(
             g_new, batch, lab.dist, lab.hub, lab.landmarks, plan,
             improved=improved, sweeps=chunk_sweeps)
+        waves["search"] += chunk_sweeps
     else:
-        seed, seeded, bound, hub_mask = seed_fn(
-            g_new, batch, lab.dist, lab.hub, lab.landmarks,
+        seed, seeded, bound, hub_mask, streams = seed_fn(
+            g_new, batch, lab.dist, lab.hub, lab.landmarks, plan,
             improved=improved)
         best, changed = seed, True
+    builds += streams is not None
     jax.block_until_ready(best)
     yield "search-seed"
     while bool(changed):
         # A donated `best` (fused path) is dead after this dispatch; the
         # rebind below is the only reference kept.
         best, changed = chunk_fn(g_new, best, seed, bound, hub_mask, plan,
-                                 improved=improved, sweeps=chunk_sweeps)
+                                 streams, improved=improved,
+                                 sweeps=chunk_sweeps)
+        waves["search"] += chunk_sweeps
         jax.block_until_ready(best)
         yield "search"
     aff = search_finish(best, seeded, improved=improved)
 
+    # The rebinds of `streams` below drop the search phase's set: only
+    # its weights and hub flags live on, in the repair set.
     if fused:
-        cur, changed = frstart_fn(g_new, aff, lab.dist, lab.hub, hub_mask,
-                                  plan, sweeps=chunk_sweeps)
+        cur, streams, changed = frstart_fn(g_new, aff, lab.dist, lab.hub,
+                                           hub_mask, plan, streams,
+                                           sweeps=chunk_sweeps)
+        waves["repair"] += chunk_sweeps
     else:
-        cur = rstart_fn(g_new, aff, lab.dist, lab.hub, hub_mask, plan)
+        cur, streams = rstart_fn(g_new, aff, lab.dist, lab.hub, hub_mask,
+                                 plan, streams)
         changed = True
+    builds += streams is not None
     jax.block_until_ready(cur)
     yield "repair-seed"
     while bool(changed):
-        cur, changed = rchunk_fn(g_new, cur, aff, hub_mask, plan,
+        cur, changed = rchunk_fn(g_new, cur, aff, hub_mask, plan, streams,
                                  sweeps=chunk_sweeps)
+        waves["repair"] += chunk_sweeps
         jax.block_until_ready(cur)
         yield "repair"
+    del streams
 
     new_lab = finish_fn(aff, cur, lab.dist, lab.hub, lab.landmarks)
+    if stats is not None:
+        stats.update(update_waves=waves, stream_builds=builds)
     return Snapshot(snapshot.version + 1, g_new, new_lab, plan), aff
 
 

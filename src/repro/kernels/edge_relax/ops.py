@@ -7,11 +7,14 @@ tile array; S=1 is the classic unsharded tiling). Tile rows are
 (NR = NB), with one a tuned cap that chunks oversized blocks into several
 consecutive rows (`rowblk_t` names each row's block — see
 `kernel.block_edges_topology`). The tiling is purely topological
-(src / local-dst / original-slot permutation): per-sweep edge validity —
-which churns with every batch update and with the repair
-boundary/interior masks — is re-tiled on device with a single gather
-through `perm_t`, so re-tiling on host is needed only when topology slots
-change (insertions rewrite src/dst), not per wave and not per deletion.
+(src / local-dst / original-slot permutation): edge validity — which
+churns with every batch update and with the repair boundary/interior
+masks — is re-tiled on device with a single gather through `perm_t`, so
+re-tiling on host is needed only when topology slots change (insertions
+rewrite src/dst), not per deletion. The per-edge operands a sweep reads
+besides the source keys (mask, weights, destination hub flags) form a
+`SweepStreams`; a fixpoint builds them once per phase and hands them to
+every wave, so a wave gathers only its source keys.
 Because no destination block straddles a shard boundary, sweep results are
 bit-identical for every S — the shard axis only shapes the launch grid
 (and, under a mesh, which slice a device owns). `core/engine.py` owns the
@@ -34,8 +37,44 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.edge_relax import kernel, ref
+
+
+@partial(jax.tree_util.register_dataclass,
+         data_fields=("mask", "w", "hub"), meta_fields=("plane_mask",))
+@dataclasses.dataclass(frozen=True)
+class SweepStreams:
+    """The per-edge operands of a sweep that do not depend on its keys,
+    in the edge order of the sweep's implementation (`[S, NR, W]` tile
+    slots, dst-sorted slots, or the COO slots).
+
+    They stay fixed across the waves of one fixpoint phase, so the phase
+    builds them once and every wave reads them; the wave itself gathers
+    only the source keys. Bit-identical to building them inside the wave:
+    the same gathers on the same operands.
+    """
+    mask: jax.Array          # edge mask (int32 tiles, else bool);
+                             # [P, ...] when `plane_mask`
+    w: jax.Array | None      # edge weights (None: the unit metric)
+    hub: jax.Array | None    # hub flag of each edge's destination, [P, ...]
+                             # (None: no hub bit-clear)
+    plane_mask: bool = False  # the mask differs per landmark plane
+
+    def plane_axes(self) -> "SweepStreams":
+        """`jax.vmap` in_axes over the landmark planes: the hub flags
+        and a per-plane mask are split, the weights are shared."""
+        return SweepStreams(0 if self.plane_mask else None, None,
+                            None if self.hub is None else 0,
+                            self.plane_mask)
+
+
+def _planewise(fn, x: jax.Array, ndim: int) -> jax.Array:
+    """`fn` on `x`, or on each plane of `x` if it has a leading plane
+    axis over the `ndim` dimensions `fn` takes."""
+    return fn(x) if x.ndim == ndim else jax.vmap(fn)(x)
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -110,6 +149,23 @@ class BlockedGraph:
         if not self.chunked:
             return blocks
         return jnp.take_along_axis(blocks, self.rowblk_t[..., None], axis=1)
+
+    def tile_hub(self, hub: jax.Array) -> jax.Array:
+        """Per-vertex hub flags [V] → the flag of each tile slot's
+        destination, int32 [S, NR, BE]."""
+        return jnp.take_along_axis(
+            self.tile_plane_rows(hub.astype(jnp.int32), 0), self.dstloc_t,
+            axis=2)
+
+    def streams(self, edge_mask: jax.Array, w: jax.Array | None,
+                hub: jax.Array | None) -> SweepStreams:
+        """The sweep's streams on this tiling. `edge_mask` is [E] or
+        per plane [P, E], `hub` per plane [P, V] (or [V] for one plane),
+        both in original slot / vertex order."""
+        return SweepStreams(
+            _planewise(self.tile_mask, edge_mask, 1), self.tile_w(w),
+            None if hub is None else _planewise(self.tile_hub, hub, 1),
+            edge_mask.ndim == 2)
 
 
 @partial(jax.tree_util.register_dataclass,
@@ -249,6 +305,16 @@ class SortedGraph:
     perm_s: jax.Array  # int32[M] original edge-slot index
     n: int
 
+    def streams(self, edge_mask: jax.Array, w: jax.Array | None,
+                hub: jax.Array | None) -> SweepStreams:
+        """The sweep's streams in dst-sorted order (see
+        `BlockedGraph.streams` for the shapes)."""
+        return SweepStreams(
+            edge_mask[..., self.perm_s],
+            None if w is None else jnp.take(w, self.perm_s, axis=0),
+            None if hub is None else jnp.take(hub, self.dst_s, axis=-1),
+            edge_mask.ndim == 2)
+
 
 def prepare(src, dst, valid, n: int, block_v: int = 512,
             shards: int = 1, block_e: int | None = None) -> BlockedGraph:
@@ -341,7 +407,8 @@ def edge_relax(keys: jax.Array, bg: BlockedGraph, step,
 def relax_sweep(keys: jax.Array, bg: BlockedGraph, edge_mask: jax.Array,
                 step, inf, clear_bit=0,
                 hub: jax.Array | None = None,
-                w: jax.Array | None = None) -> jax.Array:
+                w: jax.Array | None = None,
+                streams: SweepStreams | None = None) -> jax.Array:
     """Generalized relaxation sweep on the tiled graph (Pallas path).
 
     cand[v] = min over edges (u, v) with edge_mask of
@@ -351,25 +418,67 @@ def relax_sweep(keys: jax.Array, bg: BlockedGraph, edge_mask: jax.Array,
     `edge_mask` and `w` are in original edge-slot order (length = edge
     capacity); `w=None` is the unweighted metric (w ≡ 1 on real slots).
     `hub` is a per-vertex bool plane [V] (or None for plain relaxation).
+    `streams` (`bg.streams`, built once per fixpoint phase) stands for
+    all three; without it they are built here, for this one sweep.
     Runs interpret-mode Pallas off-TPU so parity tests exercise the same
     kernel that runs compiled on TPU.
     """
-    mask_t = bg.tile_mask(edge_mask)
-    w_t = bg.tile_w(w)
-    hub_t = (None if hub is None
-             else bg.tile_plane_rows(hub.astype(jnp.int32), 0))
-    interpret = jax.default_backend() != "tpu"
-    rowblk_t = bg.rowblk_t if bg.chunked else None
-    return kernel.relax_sweep_pallas(keys, hub_t, bg.src_t, bg.dstloc_t,
-                                     mask_t, w_t, step, inf, clear_bit,
-                                     bg.n, bg.block_v, interpret=interpret,
-                                     rowblk_t=rowblk_t, nb=bg.nb)
+    if streams is None:
+        streams = bg.streams(edge_mask, w, hub)
+    return relax_sweep_pallas(keys, bg, streams, step, inf, clear_bit,
+                              interpret=jax.default_backend() != "tpu")
+
+
+@partial(jax.jit, static_argnames=("interpret",))
+def relax_sweep_pallas(keys: jax.Array, bg: BlockedGraph,
+                       streams: SweepStreams, step, inf, clear_bit,
+                       interpret: bool = True) -> jax.Array:
+    """Launch `kernel._relax_sweep_kernel` over built streams → [V].
+
+    The launch of `kernel.relax_sweep_pallas`, except that the hub flag
+    of each slot arrives built (`streams.hub`) where that wrapper
+    gathers it from per-row hub tiles on every call; the name is kept,
+    since a device trace names the kernel by it. The one per-edge
+    gather left here is the source keys'. With a block_e-chunked tiling
+    a sorted segment-min folds the per-row partials (min-of-mins).
+    """
+    s, nr, w = bg.src_t.shape
+    if w % kernel.LANES:
+        raise ValueError(f"tile rows must be a multiple of {kernel.LANES} "
+                         f"wide, got {w} (see block_edges_topology)")
+    params = jnp.stack([jnp.asarray(step, jnp.int32),
+                        jnp.asarray(inf, jnp.int32),
+                        jnp.asarray(clear_bit, jnp.int32)])
+    lanes = (s, nr, w // kernel.LANES, kernel.LANES)
+    edges = [jnp.take(keys, bg.src_t, axis=0), bg.dstloc_t, streams.mask,
+             streams.w]
+    if streams.hub is not None:
+        edges.append(streams.hub)
+    edge = pl.BlockSpec((None, None, w // kernel.LANES, kernel.LANES),
+                        lambda j, i: (j, i, 0, 0))
+    out = pl.pallas_call(
+        kernel._relax_sweep_kernel,
+        grid=(s, nr),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + [edge] * len(edges),
+        out_specs=pl.BlockSpec((None, None, 1, bg.block_v),
+                               lambda j, i: (j, i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((s, nr, 1, bg.block_v), jnp.int32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
+        interpret=interpret,
+    )(params, *(x.reshape(lanes) for x in edges))
+    out = kernel._reduce_rows(out.reshape(s, nr, bg.block_v),
+                              bg.rowblk_t if bg.chunked else None, bg.nb,
+                              jnp.asarray(inf, jnp.int32))
+    return out.reshape(-1)[:bg.n]
 
 
 def relax_sweep_sorted(keys: jax.Array, sg: SortedGraph,
                        edge_mask: jax.Array, step, inf, clear_bit=0,
                        hub: jax.Array | None = None,
-                       w: jax.Array | None = None) -> jax.Array:
+                       w: jax.Array | None = None,
+                       streams: SweepStreams | None = None) -> jax.Array:
     """The `sorted` impl of the same sweep: compiled XLA everywhere.
 
     Identical math to `relax_sweep` over the identical edge multiset —
@@ -378,16 +487,18 @@ def relax_sweep_sorted(keys: jax.Array, sg: SortedGraph,
     reference (`tests/test_kernel_tuning.py` pins all three). The
     reduction is a `segment_min` over the destination-sorted slots with
     `indices_are_sorted=True`, and only the occupied slots participate.
+    `streams` (`sg.streams`) stands for `edge_mask`, `w` and `hub`, as
+    in `relax_sweep`.
     """
-    mask = edge_mask[sg.perm_s]
+    if streams is None:
+        streams = sg.streams(edge_mask, w, hub)
     gathered = jnp.take(keys, sg.src_s, axis=0)
-    sw = step if w is None else step * jnp.take(w, sg.perm_s, axis=0)
+    sw = step if streams.w is None else step * streams.w
     s = gathered + sw
     cand = jnp.minimum(jnp.where(s < 0, inf, s), inf)
-    if hub is not None:
-        hub_e = jnp.take(hub, sg.dst_s, axis=0)
-        cand = jnp.where(hub_e, cand & ~jnp.int32(clear_bit), cand)
-    cand = jnp.where(mask, cand, inf)
+    if streams.hub is not None:
+        cand = jnp.where(streams.hub, cand & ~jnp.int32(clear_bit), cand)
+    cand = jnp.where(streams.mask, cand, inf)
     out = jax.ops.segment_min(cand, sg.dst_s, num_segments=sg.n,
                               indices_are_sorted=True)
     return jnp.minimum(out, inf)   # empty segments fill with int32-max

@@ -50,8 +50,10 @@ microbatch and each open-loop wait, and construction's phases
 each microbatch records its service time and the BiBFS's wave counters;
 in pipeline mode each tick counts its update dispatches by phase tag
 (`TickStats.update_chunks`) and each microbatch whether it ran between
-two of them (`between_chunks`). A finished run publishes these host
-records (`trace.last_run()`).
+two of them (`between_chunks`); either mode counts the update's fixpoint
+waves (`TickStats.update_waves`) and the sweep-stream sets it built
+(`stream_builds`). A finished run publishes these host records
+(`trace.last_run()`).
 
 Checkpointing: ``--ckpt-dir`` persists the *full* serve state each tick
 (graph topology + labelling + version + the host edge list);
@@ -206,6 +208,13 @@ class TickStats:
     #: pipelined update dispatches (`serve.update_chunk` spans) by the
     #: phase tag of each; empty on the sync path
     update_chunks: dict[str, int] = dataclasses.field(default_factory=dict)
+    #: the update's fixpoint waves, {"search": n, "repair": n}: the
+    #: `while_loop` trip counts on the sync path, the chunks times
+    #: `chunk_sweeps` pipelined; empty on the mesh sync path
+    update_waves: dict[str, int] = dataclasses.field(default_factory=dict)
+    #: sweep-stream sets the update built, one per fixpoint phase (2;
+    #: 0 under a mesh, whose waves build their own)
+    stream_builds: int = 0
 
 
 @dataclasses.dataclass
@@ -494,12 +503,14 @@ class ServeLoop:
 
     # -- update modes -------------------------------------------------------
 
-    def _update_sync(self, snap: Snapshot, batch, plan, g_next) -> Snapshot:
-        """The monolithic update: one dispatch, queries queue behind it."""
+    def _update_sync(self, snap: Snapshot, batch, plan, g_next,
+                     counts: dict) -> Snapshot:
+        """The monolithic update: one dispatch, queries queue behind it.
+        Its counters land in `counts`."""
         if self.mesh is None:
             g2, lab2, aff = batchhl_update(snap.graph, batch, snap.labelling,
                                            improved=True, plan=plan,
-                                           g_new=g_next)
+                                           g_new=g_next, stats=counts)
         else:
             g2, lab2, aff = shard_batchhl_update(self.mesh, snap.graph,
                                                  batch, snap.labelling,
@@ -512,15 +523,17 @@ class ServeLoop:
     def _update_pipelined(self, snap: Snapshot, batch, plan, g_next,
                           tick: int, tick_t0: float, offsets, qs, qt,
                           served_box: list, out,
-                          chunks: collections.Counter) -> Snapshot:
+                          chunks: collections.Counter,
+                          counts: dict) -> Snapshot:
         """The chunked update: serve one microbatch of the arrived
         queries at every yield, once the chunk before it has finished.
-        Counts each dispatch by its phase tag into `chunks`."""
+        Counts each dispatch by its phase tag into `chunks`; the
+        update's own counters land in `counts`."""
         cfg = self.cfg
         upd = pipelined_update(snap, batch, plan=plan, g_new=g_next,
                                mesh=self.mesh, improved=True,
                                chunk_sweeps=cfg.chunk_sweeps,
-                               fused=cfg.fused)
+                               fused=cfg.fused, stats=counts)
         head = snap.version + 1
         for chunk in itertools.count():
             with self.trace.span("serve.update_chunk", tick=tick,
@@ -640,13 +653,14 @@ class ServeLoop:
                 g_next, topology_changed=has_ins or event is not None)
             sp.set(retiled=self.engine.retile_count > retiles)
         chunks = collections.Counter()
+        counts: dict = {}
         if cfg.pipeline:
             nxt = self._update_pipelined(work, batch, plan, g_next,
                                          tick, tick_t0, offsets, qs, qt,
-                                         served_box, out, chunks)
+                                         served_box, out, chunks, counts)
         else:
             with span("serve.update", tick=tick):
-                nxt = self._update_sync(work, batch, plan, g_next)
+                nxt = self._update_sync(work, batch, plan, g_next, counts)
         t_upd = time.time() - tick_t0
         with span("serve.commit", tick=tick):
             self.store.commit(nxt)
@@ -684,6 +698,7 @@ class ServeLoop:
                if tick_mbs else np.zeros((1,)))
         stale = sum(int(m.staleness > 0) * m.qs.shape[0]
                     for m in tick_mbs)
+        waves = counts.get("update_waves", {})
         with span("serve.prepare.stats"):
             stats = TickStats(
                 tick=tick, version=nxt.version, update_s=t_upd,
@@ -692,7 +707,10 @@ class ServeLoop:
                 queries=int(served_box[0]),
                 grew=event is not None,
                 capacity=nxt.graph.capacity, graph_n=nxt.graph.n,
-                update_chunks=dict(chunks))
+                update_chunks=dict(chunks),
+                update_waves={k: int(waves[k]) for k in ("search", "repair")
+                              if k in waves},
+                stream_builds=int(counts.get("stream_builds", 0)))
         self._log(
             f"tick {tick}: update {t_upd * 1e3:.1f}ms "
             f"({stats.affected} affected, v{nxt.version}) | "
@@ -703,7 +721,8 @@ class ServeLoop:
             f"host prep {self.trace.seconds('serve.prepare.') * 1e3:.1f}ms"
             f" | BiBFS waves {[m.waves for m in tick_mbs]}"
             f" ({sum(bool(m.bit_packed) for m in tick_mbs)}/{len(tick_mbs)}"
-            f" bit-packed)"
+            f" bit-packed) | update waves {stats.update_waves}, "
+            f"{stats.stream_builds} stream builds"
             + (f" | {sum(chunks.values())} update chunks {dict(chunks)}, "
                f"{sum(m.between_chunks for m in tick_mbs)} microbatches "
                f"between them" if chunks else ""))
@@ -763,7 +782,9 @@ class ServeLoop:
                 m.bit_packed, m.between_chunks)
                 for m in out),
             construct_s=construct_s,
-            update_chunks=tuple(t.update_chunks for t in ticks)))
+            update_chunks=tuple(t.update_chunks for t in ticks),
+            update_waves=tuple(t.update_waves for t in ticks),
+            stream_builds=tuple(t.stream_builds for t in ticks)))
         pct = self.report.latency_percentiles()
         mode = "pipeline" if cfg.pipeline else "sync"
         engine = self.engine

@@ -116,6 +116,11 @@ class RunRecord:
     #: per committed tick, pipelined update dispatches by phase tag
     #: (empty dicts on the sync path)
     update_chunks: tuple[dict[str, int], ...] = ()
+    #: per committed tick, the update's fixpoint waves by phase
+    #: ({"search": n, "repair": n}; empty on the mesh sync path)
+    update_waves: tuple[dict[str, int], ...] = ()
+    #: per committed tick, the sweep-stream sets the update built
+    stream_builds: tuple[int, ...] = ()
 
 
 _last_run: RunRecord | None = None

@@ -8,6 +8,8 @@ off-TPU, i.e. the same kernel that compiles on TPU.
 """
 from __future__ import annotations
 
+import dataclasses
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -19,8 +21,13 @@ from repro.graphs.coo import (INF_D, apply_batch, from_edges, make_batch,
 from repro.core.construct import build_labelling, select_landmarks_by_degree
 from repro.core.batch import (batch_repair, batch_search_basic,
                               batch_search_improved, batchhl_update,
-                              batchhl_update_split, uhl_update)
-from repro.core.engine import JNP_PLAN, RelaxEngine, RelaxPlan, relax_sweep
+                              batchhl_update_split, repair_base,
+                              repair_step, repair_streams,
+                              search_basic_step, search_improved_step,
+                              uhl_update)
+from repro.core.engine import (JNP_PLAN, RelaxEngine, RelaxPlan, relax_sweep,
+                               sweep_streams)
+from repro.kernels.edge_relax import ops as er_ops
 from repro.core.labelling import INF_KEY2, INF_KEY4
 from repro.core.query import batched_query, bounded_bibfs
 from repro.core import ref
@@ -87,6 +94,76 @@ def test_sweep_parity_vmapped_planes():
 
     np.testing.assert_array_equal(np.asarray(run(plan)),
                                   np.asarray(run(JNP_PLAN)))
+
+
+# --- phase streams: built once ≡ built in every wave ----------------------
+
+STREAM_PLANS = {  # plan kind → (block_e, tile shards); None: not tiled
+    "jnp": None, "sorted": None, "kernel": (None, 1),
+    "kernel_chunked": (8, 1), "kernel_shards2": (None, 2)}
+
+
+def _streams_plan(g, kind):
+    src, dst, keep = np.asarray(g.src), np.asarray(g.dst), np.asarray(g.valid)
+    if kind == "jnp":
+        return JNP_PLAN
+    if kind == "sorted":
+        return RelaxPlan(tiles=None, backend="pallas", impl="sorted",
+                         sorted_tiles=er_ops.prepare_sorted(src, dst, keep,
+                                                            g.n))
+    block_e, shards = STREAM_PLANS[kind]
+    return RelaxPlan(er_ops.prepare_topology(src, dst, keep, g.n, 16, shards,
+                                             block_e), "pallas")
+
+
+@pytest.mark.parametrize("mutation", ["deletions", "reweights"])
+@pytest.mark.parametrize("kind", sorted(STREAM_PLANS))
+@pytest.mark.parametrize("step", ["search_improved_step", "search_basic_step",
+                                  "repair_base", "repair_step"])
+def test_phase_streams_match_per_wave_streams(step, kind, mutation):
+    """A fixpoint phase's sweep streams, built once and read by every
+    wave, give every plane after every wave bit for bit what building
+    them inside each wave gives: on each backend and tiling, with edges
+    deleted since the tiling or re-weighted (w ≠ 1) since it."""
+    n, extra, p = 61, 90, 3
+    _, g, _, _ = _instance(7, n, extra)
+    plan = _streams_plan(g, kind)
+    rng = np.random.default_rng(len(step) * 13 + len(kind))
+    if mutation == "deletions":       # validity flips; the tiling stays
+        g = dataclasses.replace(
+            g, valid=g.valid & jnp.asarray(rng.random(g.valid.shape) < 0.7))
+    else:
+        g = dataclasses.replace(g, w=jnp.where(
+            g.valid, jnp.asarray(rng.integers(1, 9, g.w.shape), jnp.int32),
+            0))
+    inf = {"search_improved_step": INF_KEY4,
+           "search_basic_step": INF_D}.get(step, INF_KEY2)
+    x0 = jnp.asarray(np.where(rng.random((p, n)) < 0.2,
+                              rng.integers(0, 64, (p, n)), int(inf)),
+                     jnp.int32)
+    bound = jnp.asarray(rng.integers(int(inf) // 2, int(inf), (p, n)),
+                        jnp.int32)
+    hub_mask = jnp.asarray(rng.random((p, n)) < 0.3)
+    aff = jnp.asarray(rng.random((p, n)) < 0.5)
+    wave = {
+        "search_improved_step": lambda x, st: search_improved_step(
+            plan, g, x, x0, bound, hub_mask, st),
+        "search_basic_step": lambda x, st: search_basic_step(
+            plan, g, x, x0, bound, st),
+        "repair_base": lambda x, st: repair_base(plan, g, aff, x, hub_mask,
+                                                 st),
+        "repair_step": lambda x, st: repair_step(plan, g, x, aff, hub_mask,
+                                                 st),
+    }[step]
+    streams = sweep_streams(plan, g, hub=hub_mask)
+    if step == "repair_step":
+        streams = repair_streams(plan, g, aff, streams)
+    wave = jax.jit(wave)
+    once = each = x0
+    for _ in range(3):
+        once, each = wave(once, streams), wave(each, None)
+        np.testing.assert_array_equal(np.asarray(once), np.asarray(each))
+    assert np.any(np.asarray(once) != np.asarray(x0))
 
 
 # --- the four sweep call-sites ---------------------------------------------

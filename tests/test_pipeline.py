@@ -9,14 +9,18 @@ The forced-8-device coverage lives in `repro.core.snapshot._selftest`
 whatever devices the session has (1 in plain CI, 8 in the mesh job)."""
 from __future__ import annotations
 
+import math
 import os
 import subprocess
 import sys
+from functools import partial
 from types import SimpleNamespace
 
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
+from jax.extend.core import ClosedJaxpr, Jaxpr
 
 from repro.graphs import generators as gen
 from repro.graphs.coo import (OP_DEL, OP_INS, OP_REW, apply_batch,
@@ -28,10 +32,12 @@ from repro.core.engine import RelaxEngine
 from repro.core.query import batched_query
 from repro.core.shard import validate_landmark_sharding
 from repro.core.snapshot import (Snapshot, SnapshotStore, pipelined_update,
+                                 repair_chunk, repair_start,
                                  restore_snapshot, run_pipelined_update,
-                                 save_snapshot)
+                                 save_snapshot, search_chunk, search_seed)
 from repro.checkpoint import manager as ckpt
 from repro.data.scenarios import SCENARIOS, get_scenario
+from repro.launch import trace
 from repro.launch.serve import ServeConfig, ServeLoop
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -286,6 +292,75 @@ def test_pipeline_and_sync_commit_identical_labellings():
         np.concatenate([m.qs for m in rep_p.microbatches]))
     # sync never serves stale; pipeline reports staleness honestly
     assert all(m.staleness == 0 for m in rep_s.microbatches)
+
+
+def test_update_waves_and_stream_builds_counted(capsys):
+    """Both serving modes count the same fixpoint waves for the same
+    batch (one wave a chunk), build two stream sets a tick, and print
+    both in the tick log and the published run record."""
+    base = dict(n=200, deg=3, landmarks=8, batches=2, batch_size=20,
+                queries=8, qps=5000.0, microbatch=8)
+    rep_s = ServeLoop(ServeConfig(**base, pipeline=False)).run()
+    rec_s = trace.last_run()
+    rep_p = ServeLoop(ServeConfig(**base, pipeline=True)).run()
+    rec_p = trace.last_run()
+    for ts, tp in zip(rep_s.ticks, rep_p.ticks, strict=True):
+        assert list(ts.update_waves) == ["search", "repair"]
+        assert min(ts.update_waves.values()) >= 1
+        assert ts.update_waves == tp.update_waves
+        assert tp.update_waves == {"search": tp.update_chunks["search"],
+                                   "repair": tp.update_chunks["repair"]}
+        assert ts.stream_builds == tp.stream_builds == 2
+    for rec, rep in ((rec_s, rep_s), (rec_p, rep_p)):
+        assert rec.update_waves == tuple(t.update_waves for t in rep.ticks)
+        assert rec.stream_builds == (2, 2)
+    out = capsys.readouterr().out
+    for t in rep_s.ticks + rep_p.ticks:
+        assert (f"tick {t.tick}: " in out and
+                f"update waves {t.update_waves}, 2 stream builds" in out)
+
+
+def _tile_gathers(jaxpr, shape_size: int) -> int:
+    """Gathers in `jaxpr`, nested programs included, whose 4-D output
+    holds `shape_size` elements."""
+    count = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "gather":
+            out = eqn.outvars[0].aval.shape
+            count += len(out) == 4 and math.prod(out) == shape_size
+        for param in eqn.params.values():
+            for sub in param if isinstance(param, (list, tuple)) else [param]:
+                if isinstance(sub, ClosedJaxpr):
+                    count += _tile_gathers(sub.jaxpr, shape_size)
+                elif isinstance(sub, Jaxpr):
+                    count += _tile_gathers(sub, shape_size)
+    return count
+
+
+@pytest.mark.parametrize("sweeps", [1, 2])
+@pytest.mark.parametrize("program", ["search_chunk", "repair_chunk"])
+def test_chunk_wave_gathers_only_source_keys(program, sweeps):
+    """A chunk's wave gathers one [planes × tile slots] operand, its
+    source keys: the mask, weight and hub-flag streams come built from
+    the phase's first dispatch, never re-gathered per wave or plane."""
+    g, lab, batch = _instance(n=60, extra=80, r=4)
+    g_new = apply_batch(g, batch)
+    plan = RelaxEngine(backend="pallas", block_v=16).prepare(g_new)
+    seed, seeded, bound, hub_mask, streams = search_seed(
+        g_new, batch, lab.dist, lab.hub, lab.landmarks, plan)
+    if program == "search_chunk":
+        fn = partial(search_chunk, improved=True, sweeps=sweeps)
+        args = (g_new, seed, seed, bound, hub_mask, plan, streams)
+    else:
+        aff = seeded | (seed < jnp.max(seed))
+        _, streams = repair_start(g_new, aff, lab.dist, lab.hub, hub_mask,
+                                  plan, streams)
+        fn = partial(repair_chunk, sweeps=sweeps)
+        args = (g_new, lab.key2(), aff, hub_mask, plan, streams)
+    planes = lab.dist.shape[0]
+    slots = math.prod(plan.tiles.src_t.shape)
+    jaxpr = jax.make_jaxpr(fn)(*args).jaxpr
+    assert _tile_gathers(jaxpr, planes * slots) == sweeps
 
 
 @pytest.mark.slow

@@ -27,6 +27,7 @@ import pytest
 
 from repro.graphs import coo
 from repro.kernels.edge_relax import kernel as er_kernel
+from repro.kernels.edge_relax import ops as er_ops
 from repro.kernels.minplus import kernel as mp_kernel
 
 V = 1 << 20          # deployment vertex count
@@ -77,23 +78,25 @@ def _assert_tpu_kernel(compiled):
 def test_relax_sweep_compiles_for_v5e(one_chip, planes, hub):
     """The sweep behind every construction, search, repair and BiBFS wave
     at deployment width: one plane, and vmapped over R planes as the
-    fixpoints run it; with and without the hub bit-clear stream."""
-    nb = V // BLOCK_V
-
-    def sweep(keys, hub_t, src, dstloc, mask, w, rowblk):
-        return er_kernel.relax_sweep_pallas(
-            keys, hub_t, src, dstloc, mask, w, 2, 1 << 29, 1, n=V,
-            block_v=BLOCK_V, interpret=False, rowblk_t=rowblk, nb=nb)
-
+    fixpoints run it; with and without the hub bit-clear stream (one
+    flag per tile slot and plane, as a phase's streams carry it)."""
     tile = _shape(one_chip, 1, ROWS, WIDTH)
+    bg = er_ops.BlockedGraph(tile, tile, tile, tile, tile,
+                             _shape(one_chip, 1, ROWS), V, BLOCK_V,
+                             V // BLOCK_V, True)
     lead = () if planes is None else (planes,)
     keys = _shape(one_chip, *lead, V)
-    hub_t = _shape(one_chip, *lead, 1, ROWS, BLOCK_V) if hub else None
+    streams = er_ops.SweepStreams(
+        tile, tile, _shape(one_chip, *lead, 1, ROWS, WIDTH) if hub else None)
+
+    def sweep(keys, streams, bg):
+        return er_ops.relax_sweep_pallas(keys, bg, streams, 2, 1 << 29, 1,
+                                         interpret=False)
+
     fn = sweep
     if planes is not None:
-        fn = jax.vmap(sweep, in_axes=(0, 0 if hub else None) + (None,) * 5)
-    compiled = jax.jit(fn).lower(keys, hub_t, tile, tile, tile, tile,
-                                 _shape(one_chip, 1, ROWS)).compile()
+        fn = jax.vmap(sweep, in_axes=(0, streams.plane_axes(), None))
+    compiled = jax.jit(fn).lower(keys, streams, bg).compile()
     _assert_tpu_kernel(compiled)
 
 
