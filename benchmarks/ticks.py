@@ -33,9 +33,8 @@ growth count and capacity trajectory.
 PR 6 adds the autotuner trajectory (DESIGN.md §7): the pallas tick and
 serve rows run with ``autotune=True`` (the engine measures its candidate
 configs once per snapshot shape and serves the winner — the winning impl
-is recorded in each row's ``derived``), pipelined serve rows run the
-fused megakernel chunks, and three new row families pin the jnp-vs-tuned
-comparison directly:
+is recorded in each row's ``derived``), and three new row families pin
+the jnp-vs-tuned comparison directly:
 
     tune/<dataset>/jnp      reference sweep, steady min-of-k
     tune/<dataset>/pallas   tuned winner, same wave, same stat
@@ -311,7 +310,7 @@ def _serve_loop(name: str, n: int, deg: int, backend: str, mode: str,
                 ticks: int, batch_size: int, queries: int, landmarks: int,
                 block_v: int, tile_shards: int, qps: float,
                 microbatch: int, capacity: int | None = None,
-                autotune: bool = False, fused: bool = False,
+                autotune: bool = False,
                 scenario: str | None = None,
                 graph: str = "ba") -> list[str]:
     """One ServeLoop run → the serve/ percentile + staleness rows.
@@ -334,7 +333,7 @@ def _serve_loop(name: str, n: int, deg: int, backend: str, mode: str,
                       capacity=capacity, grow=(mode == "growth"),
                       backend=backend, block_v=block_v,
                       tile_shards=tile_shards, autotune=autotune,
-                      fused=fused, quiet=True)
+                      quiet=True)
     rep = ServeLoop(cfg).run()
     warm = 2 if ticks > 2 else 1 if ticks > 1 else 0
     mbs = [m for m in rep.microbatches if m.tick >= warm]
@@ -483,25 +482,21 @@ def run(datasets=("ba_2k",), backends=("jnp", "pallas"),
         e0 = DATASETS[ds]().shape[0]
         for backend in backends:
             for mode in serve_modes:
-                # pallas serve rows run autotuned, and the pipelined mode
-                # uses the fused megakernel chunks (sync updates are the
-                # monolithic dispatch — nothing to fuse). The growth row
-                # stays untuned: a re-tune fires inside every growth
-                # event (capacity changes the table key), and putting
-                # tuner compiles on the serving path would make the row
-                # track compile noise instead of the growth cost.
+                # pallas serve rows run autotuned. The growth row stays
+                # untuned: a re-tune fires inside every growth event
+                # (capacity changes the table key), and putting tuner
+                # compiles on the serving path would make the row track
+                # compile noise instead of the growth cost.
                 rows += _serve_loop(f"serve/{ds}/{backend}/{mode}", n, deg,
                                     backend, mode, ticks, batch_size,
                                     queries, landmarks, block_v,
                                     tile_shards, qps, microbatch,
-                                    autotune=(backend == "pallas"),
-                                    fused=(mode == "pipeline"))
+                                    autotune=(backend == "pallas"))
             rows += _serve_loop(f"serve/{ds}/{backend}/growth", n, deg,
                                 backend, "growth", ticks, batch_size,
                                 queries, landmarks, block_v, tile_shards,
                                 qps, microbatch,
-                                capacity=e0 + 7 * batch_size // 2,
-                                fused=True)
+                                capacity=e0 + 7 * batch_size // 2)
             # PR 8: the replica-tier saturation trajectory (DESIGN.md
             # §9) — how much client qps a real multi-process topology
             # (1 updater + R readers behind the coalescing router)
@@ -539,7 +534,7 @@ def run(datasets=("ba_2k",), backends=("jnp", "pallas"),
                             "pipeline", ticks, batch_size, queries,
                             landmarks, block_v, tile_shards, qps,
                             microbatch, autotune=(backend == "pallas"),
-                            fused=True, scenario="traffic", graph="road")
+                            scenario="traffic", graph="road")
     return rows
 
 

@@ -527,6 +527,75 @@ def batch_repair(g_new: Graph, aff: jax.Array,
 
 
 # ---------------------------------------------------------------------------
+# Bounded chunks (the serving pipeline's phase bodies, DESIGN.md §5)
+# ---------------------------------------------------------------------------
+#
+# `core/snapshot.py` runs the fixpoints above as bounded dispatches of
+# `sweeps` waves each, and `core/shard.py` runs the same bodies under
+# shard_map. Frontier mode (DESIGN.md §10): the per-plane changed-block
+# bitmap `front` [P, NBf] is chunk-carried state like the plane, None
+# when `use_frontier` is false; the waves are chosen at trace time on
+# it. The convergence flag is "is the frontier empty" with a bitmap and
+# "did any key change" without: the same fixpoint condition, values
+# bit-identical either way (the parity suites pin it).
+
+def chunk_waves(plan, g_new, full_step, masked_step, x, front, sweeps):
+    """`sweeps` waves from `x` → (x', changed, front').
+
+    Without a bitmap (`front` None) each wave is `full_step`; with one
+    each is a `frontier_wave` of `full_step`/`masked_step`."""
+    out = x
+    for _ in range(sweeps):
+        if front is None:
+            out = full_step(out)
+        else:
+            out, front = frontier_wave(plan, g_new, full_step, masked_step,
+                                       out, front)
+    changed = jnp.any(out != x) if front is None else jnp.any(front)
+    return out, changed, front
+
+
+def search_chunk_body(g_new, best, seed, bound, hub_mask, plan, streams,
+                      front, improved, sweeps):
+    """`sweeps` search waves (Algo 3, or Algo 2) from `best` →
+    (best', changed, front')."""
+    def full(b):
+        if improved:
+            return search_improved_step(plan, g_new, b, seed, bound,
+                                        hub_mask, streams)
+        return search_basic_step(plan, g_new, b, seed, bound, streams)
+
+    def masked(b, rows_g):
+        return search_step_rows(rows_g, b, bound,
+                                hub_mask if improved else None,
+                                improved=improved)
+    return chunk_waves(plan, g_new, full, masked, best, front, sweeps)
+
+
+def repair_start_body(g_new, aff, dist, hub, hub_mask, plan, streams):
+    """Algo-4 boundary seeding → (base, front): in frontier mode masked
+    to the affected blocks, with `front` the blocks `base` seeded; else
+    a full sweep and None."""
+    if not use_frontier(plan, g_new):
+        return repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask,
+                           streams), None
+    base = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
+                                hub_mask, streams)
+    return base, plan.frontier.changed_blocks(base < INF_KEY2)
+
+
+def repair_chunk_body(g_new, cur, aff, hub_mask, plan, streams, front,
+                      sweeps):
+    """`sweeps` interior repair waves from `cur` → (cur', changed,
+    front')."""
+    return chunk_waves(
+        plan, g_new,
+        lambda c: repair_step(plan, g_new, c, aff, hub_mask, streams),
+        lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask),
+        cur, front, sweeps)
+
+
+# ---------------------------------------------------------------------------
 # BatchHL — Algorithm 1
 # ---------------------------------------------------------------------------
 

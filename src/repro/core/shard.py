@@ -59,18 +59,15 @@ from jax.sharding import PartitionSpec as P
 
 from repro.graphs.coo import (Graph, BatchUpdate, INF_D, apply_batch,
                               resolve_seed_weights)
-from repro.core.batch import (check_labelling_width, frontier_wave,
-                              repair_base, repair_base_frontier,
-                              repair_merge, repair_planes,
-                              repair_step, repair_step_rows,
-                              search_basic_planes,
-                              search_basic_seed, search_basic_step,
-                              search_improved_planes, search_improved_seed,
-                              search_improved_step, search_step_rows)
+from repro.core.batch import (check_labelling_width, repair_chunk_body,
+                              repair_merge, repair_planes, repair_start_body,
+                              search_basic_planes, search_basic_seed,
+                              search_chunk_body, search_improved_planes,
+                              search_improved_seed)
 from repro.core.construct import construct_key2_planes
 from repro.core.engine import RelaxPlan
-from repro.core.labelling import (HighwayLabelling, INF_KEY2, key2_dist,
-                                  key2_hub, key2_make, per_plane_hub_mask)
+from repro.core.labelling import (HighwayLabelling, key2_dist, key2_hub,
+                                  key2_make, per_plane_hub_mask)
 from repro.core.query import bounded_bibfs, effective_label_planes
 
 #: Plane-sharding spec during maintenance: landmark planes over the whole
@@ -231,17 +228,31 @@ def affected_vertices(mesh, aff: jax.Array) -> jax.Array:
 # `core/snapshot.pipelined_update` runs the batch update as bounded
 # dispatches so query microbatches interleave on the device queue. These
 # are the mesh twins of the unsharded chunk jits in `core/snapshot.py`:
-# the same seed/step functions from `core/batch.py`, under shard_map on
-# the maintenance plane grouping (landmark planes over ("model", "data")),
-# with the graph, batch, and plan replicated. The per-chunk `changed`
-# flag is the one cross-shard reduction (a pmax OR-merge); everything
-# else is all-local, exactly like the monolithic maintenance bodies.
+# the same chunk bodies (`core/batch.py`), under shard_map on the
+# maintenance plane grouping (landmark planes over ("model", "data")),
+# with the graph, batch, and plan replicated. Each twin but the seed
+# takes its unsharded program's arguments after `mesh`, so
+# `pipelined_update` calls either set alike; the bodies build their
+# sweep streams in every wave, so `streams` is None in and out. The
+# frontier bitmap `front` [P, NBf] shards like the labelling planes
+# (rv): each device propagates and relaxes the
+# frontier of its own plane slice, and takes the masked/full density
+# branch against it (a tighter mask than a global one, still exact per
+# plane). The per-chunk `changed` flag is the one cross-shard reduction
+# (a pmax OR-merge); everything else is all-local, exactly like the
+# monolithic maintenance bodies.
+
+def _any_shard(changed: jax.Array) -> jax.Array:
+    """OR-merge a per-shard convergence flag over the maintenance grouping."""
+    return jax.lax.pmax(changed.astype(jnp.int32), MAINT_AXES) > 0
+
 
 @partial(jax.jit, static_argnames=("mesh", "improved"))
 def shard_search_seed(mesh, g_new: Graph, batch: BatchUpdate,
                       dist: jax.Array, hub: jax.Array, landmarks: jax.Array,
                       improved: bool = True):
-    """Mesh twin of `snapshot.search_seed`; outputs plane-sharded rv."""
+    """Mesh twin of `snapshot.search_seed`; outputs plane-sharded rv, and
+    None for the streams."""
     _check_planes(landmarks.shape[0], _maint_size(mesh), "maintenance")
     check_labelling_width(g_new, dist)
 
@@ -255,411 +266,75 @@ def shard_search_seed(mesh, g_new: Graph, batch: BatchUpdate,
         return seed, seeded, dist, hub_mask
 
     rv = P(MAINT_AXES, None)
-    return jax.shard_map(
+    seed, seeded, bound, hub_mask = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P()),
         out_specs=(rv, rv, rv, rv),
         check_vma=False)(g_new, batch, dist, hub, landmarks, landmarks)
+    return seed, seeded, bound, hub_mask, None
 
 
 @partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"))
 def shard_search_chunk(mesh, g_new: Graph, best: jax.Array, seed: jax.Array,
                        bound: jax.Array, hub_mask: jax.Array,
-                       plan: RelaxPlan | None, improved: bool = True,
+                       plan: RelaxPlan | None, streams=None,
+                       front: jax.Array | None = None, improved: bool = True,
                        sweeps: int = 1):
-    """Mesh twin of `snapshot.search_chunk` → (best', changed scalar)."""
+    """Mesh twin of `snapshot.search_chunk` →
+    (best', changed scalar, front')."""
 
-    def body(g_new, best, seed, bound, hub_mask, plan):
-        cur = best
-        for _ in range(sweeps):
-            if improved:
-                cur = search_improved_step(plan, g_new, cur, seed, bound,
-                                           hub_mask)
-            else:
-                cur = search_basic_step(plan, g_new, cur, seed, bound)
-        changed = jax.lax.pmax(
-            jnp.any(cur != best).astype(jnp.int32), MAINT_AXES)
-        return cur, changed > 0
+    def body(g_new, best, seed, bound, hub_mask, plan, front):
+        cur, changed, front = search_chunk_body(
+            g_new, best, seed, bound, hub_mask, plan, None, front, improved,
+            sweeps)
+        return cur, _any_shard(changed), front
 
     rv = P(MAINT_AXES, None)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, P()),
-        check_vma=False)(g_new, best, seed, bound, hub_mask, plan)
+        in_specs=(P(), rv, rv, rv, rv, P(), rv),
+        out_specs=(rv, P(), rv),
+        check_vma=False)(g_new, best, seed, bound, hub_mask, plan, front)
 
 
 @partial(jax.jit, static_argnames=("mesh",))
 def shard_repair_start(mesh, g_new: Graph, aff: jax.Array, dist: jax.Array,
                        hub: jax.Array, hub_mask: jax.Array,
-                       plan: RelaxPlan | None) -> jax.Array:
-    """Mesh twin of `snapshot.repair_start` (Algo-4 boundary seeding)."""
+                       plan: RelaxPlan | None, streams=None):
+    """Mesh twin of `snapshot.repair_start` (Algo-4 boundary seeding) →
+    (base, None, front)."""
 
     def body(g_new, aff, dist, hub, hub_mask, plan):
-        return repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask)
+        return repair_start_body(g_new, aff, dist, hub, hub_mask, plan, None)
 
     rv = P(MAINT_AXES, None)
-    return jax.shard_map(
+    base, front = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=rv,
+        out_specs=(rv, rv),
         check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
+    return base, None, front
 
 
 @partial(jax.jit, static_argnames=("mesh", "sweeps"))
 def shard_repair_chunk(mesh, g_new: Graph, cur: jax.Array, aff: jax.Array,
                        hub_mask: jax.Array, plan: RelaxPlan | None,
+                       streams=None, front: jax.Array | None = None,
                        sweeps: int = 1):
-    """Mesh twin of `snapshot.repair_chunk` → (cur', changed scalar)."""
+    """Mesh twin of `snapshot.repair_chunk` →
+    (cur', changed scalar, front')."""
 
-    def body(g_new, cur, aff, hub_mask, plan):
-        out = cur
-        for _ in range(sweeps):
-            out = repair_step(plan, g_new, out, aff, hub_mask)
-        changed = jax.lax.pmax(
-            jnp.any(out != cur).astype(jnp.int32), MAINT_AXES)
-        return out, changed > 0
+    def body(g_new, cur, aff, hub_mask, plan, front):
+        out, changed, front = repair_chunk_body(g_new, cur, aff, hub_mask,
+                                                plan, None, front, sweeps)
+        return out, _any_shard(changed), front
 
     rv = P(MAINT_AXES, None)
     return jax.shard_map(
         body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, P()),
-        out_specs=(rv, P()),
-        check_vma=False)(g_new, cur, aff, hub_mask, plan)
-
-
-# --- fused chunk twins (seed + K sweeps in one dispatch; donated planes) ---
-#
-# Mesh versions of `snapshot.fused_*`: same fusion boundaries, same
-# donation contract (the labelling plane argument is donated and must be
-# rebound by the caller after every chunk), with the per-chunk `changed`
-# flag pmax-merged across the maintenance grouping like the unfused
-# chunk twins above.
-
-@partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"))
-def shard_fused_search_start(mesh, g_new: Graph, batch: BatchUpdate,
-                             dist: jax.Array, hub: jax.Array,
-                             landmarks: jax.Array, plan: RelaxPlan | None,
-                             improved: bool = True, sweeps: int = 1):
-    """Mesh twin of `snapshot.fused_search_start` →
-    (best, seed, seeded, bound, hub_mask, changed)."""
-    _check_planes(landmarks.shape[0], _maint_size(mesh), "maintenance")
-    check_labelling_width(g_new, dist)
-
-    def body(g_new, batch, dist, hub, own, landmarks_full, plan):
-        hub_mask = per_plane_hub_mask(landmarks_full, own, g_new.n)
-        if improved:
-            seed, seeded, bound = search_improved_seed(g_new, batch, dist,
-                                                       hub, hub_mask)
-        else:
-            seed, seeded = search_basic_seed(g_new, batch, dist)
-            bound = dist
-        best = seed
-        for _ in range(sweeps):
-            if improved:
-                best = search_improved_step(plan, g_new, best, seed, bound,
-                                            hub_mask)
-            else:
-                best = search_basic_step(plan, g_new, best, seed, bound)
-        changed = jax.lax.pmax(
-            jnp.any(best != seed).astype(jnp.int32), MAINT_AXES)
-        return best, seed, seeded, bound, hub_mask, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P(), P()),
-        out_specs=(rv, rv, rv, rv, rv, P()),
-        check_vma=False)(g_new, batch, dist, hub, landmarks, landmarks,
-                         plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"),
-         donate_argnums=(2,))
-def shard_fused_search_chunk(mesh, g_new: Graph, best: jax.Array,
-                             seed: jax.Array, bound: jax.Array,
-                             hub_mask: jax.Array, plan: RelaxPlan | None,
-                             improved: bool = True, sweeps: int = 1):
-    """`shard_search_chunk` with the labelling plane donated."""
-
-    def body(g_new, best, seed, bound, hub_mask, plan):
-        cur = best
-        for _ in range(sweeps):
-            if improved:
-                cur = search_improved_step(plan, g_new, cur, seed, bound,
-                                           hub_mask)
-            else:
-                cur = search_basic_step(plan, g_new, cur, seed, bound)
-        changed = jax.lax.pmax(
-            jnp.any(cur != best).astype(jnp.int32), MAINT_AXES)
-        return cur, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, P()),
-        check_vma=False)(g_new, best, seed, bound, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "sweeps"))
-def shard_fused_repair_start_chunk(mesh, g_new: Graph, aff: jax.Array,
-                                   dist: jax.Array, hub: jax.Array,
-                                   hub_mask: jax.Array,
-                                   plan: RelaxPlan | None, sweeps: int = 1):
-    """Mesh twin of `snapshot.fused_repair_start_chunk` → (cur, changed)."""
-
-    def body(g_new, aff, dist, hub, hub_mask, plan):
-        cur0 = repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask)
-        cur = cur0
-        for _ in range(sweeps):
-            cur = repair_step(plan, g_new, cur, aff, hub_mask)
-        changed = jax.lax.pmax(
-            jnp.any(cur != cur0).astype(jnp.int32), MAINT_AXES)
-        return cur, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, P()),
-        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "sweeps"), donate_argnums=(2,))
-def shard_fused_repair_chunk(mesh, g_new: Graph, cur: jax.Array,
-                             aff: jax.Array, hub_mask: jax.Array,
-                             plan: RelaxPlan | None, sweeps: int = 1):
-    """`shard_repair_chunk` with the key2 plane donated."""
-
-    def body(g_new, cur, aff, hub_mask, plan):
-        out = cur
-        for _ in range(sweeps):
-            out = repair_step(plan, g_new, out, aff, hub_mask)
-        changed = jax.lax.pmax(
-            jnp.any(out != cur).astype(jnp.int32), MAINT_AXES)
-        return out, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, P()),
-        out_specs=(rv, P()),
-        check_vma=False)(g_new, cur, aff, hub_mask, plan)
-
-
-# --- frontier chunk twins (change propagation, DESIGN.md §10) --------------
-#
-# Mesh versions of `snapshot.*_frontier`: the per-plane changed-block
-# bitmap `front` [P, NBf] shards over the maintenance grouping exactly
-# like the labelling planes (rv), so each device propagates and relaxes
-# the frontier of *its own* plane slice — the masked/full density branch
-# is taken per device, against its local frontier (a tighter mask than a
-# global one, and still exact per plane). The convergence flag is the
-# usual pmax OR-merge of "is my local frontier non-empty".
-
-def _shard_search_wave_fns(plan, g_new, seed, bound, hub_mask, improved):
-    if improved:
-        return (lambda b: search_improved_step(plan, g_new, b, seed, bound,
-                                               hub_mask),
-                lambda b, rows_g: search_step_rows(rows_g, b, bound,
-                                                   hub_mask, improved=True))
-    return (lambda b: search_basic_step(plan, g_new, b, seed, bound),
-            lambda b, rows_g: search_step_rows(rows_g, b, bound, None,
-                                               improved=False))
-
-
-@partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"))
-def shard_search_chunk_frontier(mesh, g_new: Graph, best: jax.Array,
-                                front: jax.Array, seed: jax.Array,
-                                bound: jax.Array, hub_mask: jax.Array,
-                                plan: RelaxPlan, improved: bool = True,
-                                sweeps: int = 1):
-    """Mesh twin of `snapshot.search_chunk_frontier` →
-    (best', front', changed scalar)."""
-
-    def body(g_new, best, front, seed, bound, hub_mask, plan):
-        full, masked = _shard_search_wave_fns(plan, g_new, seed, bound,
-                                              hub_mask, improved)
-        cur = best
-        for _ in range(sweeps):
-            cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-        changed = jax.lax.pmax(
-            jnp.any(front).astype(jnp.int32), MAINT_AXES)
-        return cur, front, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, rv, P()),
-        out_specs=(rv, rv, P()),
-        check_vma=False)(g_new, best, front, seed, bound, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh",))
-def shard_repair_start_frontier(mesh, g_new: Graph, aff: jax.Array,
-                                dist: jax.Array, hub: jax.Array,
-                                hub_mask: jax.Array, plan: RelaxPlan):
-    """Mesh twin of `snapshot.repair_start_frontier` → (base, front)."""
-
-    def body(g_new, aff, dist, hub, hub_mask, plan):
-        base = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
-                                    hub_mask)
-        return base, plan.frontier.changed_blocks(base < INF_KEY2)
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, rv),
-        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "sweeps"))
-def shard_repair_chunk_frontier(mesh, g_new: Graph, cur: jax.Array,
-                                front: jax.Array, aff: jax.Array,
-                                hub_mask: jax.Array, plan: RelaxPlan,
-                                sweeps: int = 1):
-    """Mesh twin of `snapshot.repair_chunk_frontier` →
-    (cur', front', changed scalar)."""
-
-    def body(g_new, cur, front, aff, hub_mask, plan):
-        full = lambda c: repair_step(plan, g_new, c, aff, hub_mask)
-        masked = lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask)
-        out = cur
-        for _ in range(sweeps):
-            out, front = frontier_wave(plan, g_new, full, masked, out, front)
-        changed = jax.lax.pmax(
-            jnp.any(front).astype(jnp.int32), MAINT_AXES)
-        return out, front, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, rv, P()),
-        check_vma=False)(g_new, cur, front, aff, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"))
-def shard_fused_search_start_frontier(mesh, g_new: Graph,
-                                      batch: BatchUpdate, dist: jax.Array,
-                                      hub: jax.Array, landmarks: jax.Array,
-                                      plan: RelaxPlan, improved: bool = True,
-                                      sweeps: int = 1):
-    """Mesh twin of `snapshot.fused_search_start_frontier` →
-    (best, front, seed, seeded, bound, hub_mask, changed)."""
-    _check_planes(landmarks.shape[0], _maint_size(mesh), "maintenance")
-    check_labelling_width(g_new, dist)
-
-    def body(g_new, batch, dist, hub, own, landmarks_full, plan):
-        hub_mask = per_plane_hub_mask(landmarks_full, own, g_new.n)
-        if improved:
-            seed, seeded, bound = search_improved_seed(g_new, batch, dist,
-                                                       hub, hub_mask)
-        else:
-            seed, seeded = search_basic_seed(g_new, batch, dist)
-            bound = dist
-        front = plan.frontier.changed_blocks(seeded)
-        full, masked = _shard_search_wave_fns(plan, g_new, seed, bound,
-                                              hub_mask, improved)
-        best = seed
-        for _ in range(sweeps):
-            best, front = frontier_wave(plan, g_new, full, masked, best,
-                                        front)
-        changed = jax.lax.pmax(
-            jnp.any(front).astype(jnp.int32), MAINT_AXES)
-        return best, front, seed, seeded, bound, hub_mask, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), P(), rv, rv, P(MAINT_AXES), P(), P()),
-        out_specs=(rv, rv, rv, rv, rv, rv, P()),
-        check_vma=False)(g_new, batch, dist, hub, landmarks, landmarks,
-                         plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "improved", "sweeps"),
-         donate_argnums=(2,))
-def shard_fused_search_chunk_frontier(mesh, g_new: Graph, best: jax.Array,
-                                      front: jax.Array, seed: jax.Array,
-                                      bound: jax.Array, hub_mask: jax.Array,
-                                      plan: RelaxPlan, improved: bool = True,
-                                      sweeps: int = 1):
-    """`shard_search_chunk_frontier` with the labelling plane donated."""
-
-    def body(g_new, best, front, seed, bound, hub_mask, plan):
-        full, masked = _shard_search_wave_fns(plan, g_new, seed, bound,
-                                              hub_mask, improved)
-        cur = best
-        for _ in range(sweeps):
-            cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-        changed = jax.lax.pmax(
-            jnp.any(front).astype(jnp.int32), MAINT_AXES)
-        return cur, front, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, rv, P()),
-        out_specs=(rv, rv, P()),
-        check_vma=False)(g_new, best, front, seed, bound, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "sweeps"))
-def shard_fused_repair_start_chunk_frontier(mesh, g_new: Graph,
-                                            aff: jax.Array, dist: jax.Array,
-                                            hub: jax.Array,
-                                            hub_mask: jax.Array,
-                                            plan: RelaxPlan,
-                                            sweeps: int = 1):
-    """Mesh twin of `snapshot.fused_repair_start_chunk_frontier` →
-    (cur, front, changed)."""
-
-    def body(g_new, aff, dist, hub, hub_mask, plan):
-        cur = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
-                                   hub_mask)
-        front = plan.frontier.changed_blocks(cur < INF_KEY2)
-        full = lambda c: repair_step(plan, g_new, c, aff, hub_mask)
-        masked = lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask)
-        for _ in range(sweeps):
-            cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-        changed = jax.lax.pmax(
-            jnp.any(front).astype(jnp.int32), MAINT_AXES)
-        return cur, front, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, rv, P()),
-        check_vma=False)(g_new, aff, dist, hub, hub_mask, plan)
-
-
-@partial(jax.jit, static_argnames=("mesh", "sweeps"), donate_argnums=(2,))
-def shard_fused_repair_chunk_frontier(mesh, g_new: Graph, cur: jax.Array,
-                                      front: jax.Array, aff: jax.Array,
-                                      hub_mask: jax.Array, plan: RelaxPlan,
-                                      sweeps: int = 1):
-    """`shard_repair_chunk_frontier` with the key2 plane donated."""
-
-    def body(g_new, cur, front, aff, hub_mask, plan):
-        full = lambda c: repair_step(plan, g_new, c, aff, hub_mask)
-        masked = lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask)
-        out = cur
-        for _ in range(sweeps):
-            out, front = frontier_wave(plan, g_new, full, masked, out, front)
-        changed = jax.lax.pmax(
-            jnp.any(front).astype(jnp.int32), MAINT_AXES)
-        return out, front, changed > 0
-
-    rv = P(MAINT_AXES, None)
-    return jax.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(), rv, rv, rv, rv, P()),
-        out_specs=(rv, rv, P()),
-        check_vma=False)(g_new, cur, front, aff, hub_mask, plan)
+        in_specs=(P(), rv, rv, rv, P(), rv),
+        out_specs=(rv, P(), rv),
+        check_vma=False)(g_new, cur, aff, hub_mask, plan, front)
 
 
 @partial(jax.jit, static_argnames=("mesh",))

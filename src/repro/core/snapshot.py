@@ -53,15 +53,13 @@ import numpy as np
 from repro.graphs.coo import (Graph, BatchUpdate, INF_D, apply_batch, grow,
                               resolve_seed_weights)
 from repro.checkpoint import manager as ckpt
-from repro.core.batch import (check_labelling_width, frontier_wave,
-                              repair_base, repair_base_frontier,
-                              repair_merge, repair_step, repair_step_rows,
+from repro.core.batch import (check_labelling_width, repair_chunk_body,
+                              repair_merge, repair_start_body,
                               repair_streams, search_basic_seed,
-                              search_basic_step, search_improved_seed,
-                              search_improved_step, search_step_rows,
+                              search_chunk_body, search_improved_seed,
                               use_frontier)
 from repro.core.engine import RelaxPlan, SweepStreams, sweep_streams
-from repro.core.labelling import (HighwayLabelling, INF_KEY2, INF_KEY4,
+from repro.core.labelling import (HighwayLabelling, INF_KEY4,
                                   grow_labelling,
                                   key2_dist, key2_hub, key2_make,
                                   per_plane_hub_mask)
@@ -152,10 +150,15 @@ def grow_snapshot(snap: Snapshot, *, capacity: int | None = None,
 # phase's first dispatch, and passed to every later chunk of the phase:
 # `search_seed` builds the search set, `repair_start` the repair set (the
 # interior masks, over the search set's weights and hub flags). A chunk's
-# wave then gathers only its source keys. The repair starts take the
+# wave then gathers only its source keys. `repair_start` takes the
 # search set donated, so the hub flags it hands on keep their buffer (a
 # copy would hold a third [R, S, NR, W] stream at once); callers rebind
 # to the returned set.
+#
+# Frontier mode (DESIGN.md §10): the chunk programs take the changed-block
+# bitmap `front` as an optional argument (None when `use_frontier(plan,
+# g_new)` is false) and return it; `repair_start` derives it from its
+# seeding. The bodies (`core/batch.py`) are shared with the mesh twins.
 
 @partial(jax.jit, static_argnames=("improved",))
 def search_seed(g_new: Graph, batch: BatchUpdate, dist: jax.Array,
@@ -180,28 +183,22 @@ def search_seed(g_new: Graph, batch: BatchUpdate, dist: jax.Array,
     return seed, seeded, dist, hub_mask, streams
 
 
-def _search_waves(plan, g_new, cur, seed, bound, hub_mask, streams,
-                  improved, sweeps):
-    """`sweeps` search waves (Algo 3, or Algo 2) from `cur`."""
-    for _ in range(sweeps):
-        if improved:
-            cur = search_improved_step(plan, g_new, cur, seed, bound,
-                                       hub_mask, streams)
-        else:
-            cur = search_basic_step(plan, g_new, cur, seed, bound, streams)
-    return cur
+@jax.jit
+def frontier_seed_blocks(plan: RelaxPlan, seeded: jax.Array) -> jax.Array:
+    """Initial changed-block bitmap: wave 0 'changed' the seeded vertices."""
+    return plan.frontier.changed_blocks(seeded)
 
 
 @partial(jax.jit, static_argnames=("improved", "sweeps"))
 def search_chunk(g_new: Graph, best: jax.Array, seed: jax.Array,
                  bound: jax.Array, hub_mask: jax.Array,
                  plan: RelaxPlan | None, streams: SweepStreams,
-                 improved: bool = True,
-                 sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
-    """`sweeps` search waves in one bounded dispatch → (best', changed)."""
-    cur = _search_waves(plan, g_new, best, seed, bound, hub_mask, streams,
-                        improved, sweeps)
-    return cur, jnp.any(cur != best)
+                 front: jax.Array | None = None, improved: bool = True,
+                 sweeps: int = 1):
+    """`sweeps` search waves in one bounded dispatch →
+    (best', changed, front')."""
+    return search_chunk_body(g_new, best, seed, bound, hub_mask, plan,
+                             streams, front, improved, sweeps)
 
 
 @partial(jax.jit, static_argnames=("improved",))
@@ -215,265 +212,25 @@ def search_finish(best: jax.Array, seeded: jax.Array,
 @partial(jax.jit, donate_argnames=("streams",))
 def repair_start(g_new: Graph, aff: jax.Array, dist: jax.Array,
                  hub: jax.Array, hub_mask: jax.Array,
-                 plan: RelaxPlan | None, streams: SweepStreams
-                 ) -> tuple[jax.Array, SweepStreams]:
+                 plan: RelaxPlan | None, streams: SweepStreams):
     """Algo-4 boundary seeding as one bounded dispatch → (base, the
     repair phase's streams, built from the search phase's `streams`,
-    which are donated)."""
-    base = repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask,
-                       streams)
-    return base, repair_streams(plan, g_new, aff, streams)
+    which are donated, front): `front` marks the blocks the seeding
+    reached in frontier mode, and is None otherwise."""
+    base, front = repair_start_body(g_new, aff, dist, hub, hub_mask, plan,
+                                    streams)
+    return base, repair_streams(plan, g_new, aff, streams), front
 
 
 @partial(jax.jit, static_argnames=("sweeps",))
 def repair_chunk(g_new: Graph, cur: jax.Array, aff: jax.Array,
                  hub_mask: jax.Array, plan: RelaxPlan | None,
-                 streams: SweepStreams,
-                 sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
-    """`sweeps` interior repair waves in one bounded dispatch."""
-    out = cur
-    for _ in range(sweeps):
-        out = repair_step(plan, g_new, out, aff, hub_mask, streams)
-    return out, jnp.any(out != cur)
-
-
-# --- frontier chunk variants (change propagation, DESIGN.md §10) -----------
-#
-# The masked-sweep twins of the chunks above, used by `pipelined_update`
-# when the plan carries a `FrontierTiles`. Each threads the per-plane
-# changed-block bitmap `front` [P, NBf] through the chunk loop as extra
-# carried state; the per-chunk convergence flag becomes "is the frontier
-# empty", which is the same fixpoint condition expressed one wave earlier
-# (values are bit-identical either way — the parity suite pins it). The
-# full-sweep fallback of a wave reads the phase's streams.
-
-def _search_wave_fns(plan, g_new, seed, bound, hub_mask, streams, improved):
-    """(full_step, masked_step) pair for one search wave (Algo 2/3)."""
-    if improved:
-        return (lambda b: search_improved_step(plan, g_new, b, seed, bound,
-                                               hub_mask, streams),
-                lambda b, rows_g: search_step_rows(rows_g, b, bound,
-                                                   hub_mask, improved=True))
-    return (lambda b: search_basic_step(plan, g_new, b, seed, bound,
-                                        streams),
-            lambda b, rows_g: search_step_rows(rows_g, b, bound, None,
-                                               improved=False))
-
-
-def _repair_wave_fns(plan, g_new, aff, hub_mask, streams):
-    """(full_step, masked_step) pair for one interior repair wave."""
-    return (lambda c: repair_step(plan, g_new, c, aff, hub_mask, streams),
-            lambda c, rows_g: repair_step_rows(rows_g, c, aff, hub_mask))
-
-
-@jax.jit
-def frontier_seed_blocks(plan: RelaxPlan, seeded: jax.Array) -> jax.Array:
-    """Initial changed-block bitmap: wave 0 'changed' the seeded vertices."""
-    return plan.frontier.changed_blocks(seeded)
-
-
-@partial(jax.jit, static_argnames=("improved", "sweeps"))
-def search_chunk_frontier(g_new: Graph, best: jax.Array, front: jax.Array,
-                          seed: jax.Array, bound: jax.Array,
-                          hub_mask: jax.Array, plan: RelaxPlan,
-                          streams: SweepStreams, improved: bool = True,
-                          sweeps: int = 1):
-    """`search_chunk` with frontier waves → (best', front', changed)."""
-    full, masked = _search_wave_fns(plan, g_new, seed, bound, hub_mask,
-                                    streams, improved)
-    cur = best
-    for _ in range(sweeps):
-        cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-    return cur, front, jnp.any(front)
-
-
-@partial(jax.jit, donate_argnames=("streams",))
-def repair_start_frontier(g_new: Graph, aff: jax.Array, dist: jax.Array,
-                          hub: jax.Array, hub_mask: jax.Array,
-                          plan: RelaxPlan, streams: SweepStreams):
-    """`repair_start` masked to the affected blocks →
-    (base, front, streams)."""
-    base = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
-                                hub_mask, streams)
-    return (base, plan.frontier.changed_blocks(base < INF_KEY2),
-            repair_streams(plan, g_new, aff, streams))
-
-
-@partial(jax.jit, static_argnames=("sweeps",))
-def repair_chunk_frontier(g_new: Graph, cur: jax.Array, front: jax.Array,
-                          aff: jax.Array, hub_mask: jax.Array,
-                          plan: RelaxPlan, streams: SweepStreams,
-                          sweeps: int = 1):
-    """`repair_chunk` with frontier waves → (cur', front', changed)."""
-    full, masked = _repair_wave_fns(plan, g_new, aff, hub_mask, streams)
-    out = cur
-    for _ in range(sweeps):
-        out, front = frontier_wave(plan, g_new, full, masked, out, front)
-    return out, front, jnp.any(front)
-
-
-@partial(jax.jit, static_argnames=("improved", "sweeps"))
-def fused_search_start_frontier(g_new: Graph, batch: BatchUpdate,
-                                dist: jax.Array, hub: jax.Array,
-                                landmarks: jax.Array, plan: RelaxPlan,
-                                improved: bool = True, sweeps: int = 1):
-    """`fused_search_start` with frontier waves →
-    (best, front, seed, seeded, bound, hub_mask, streams, changed).
-
-    Returned `best` is a fresh buffer distinct from `seed` (each masked
-    wave's scatter-min is functional), so the donation contract of the
-    fused chunks holds unchanged.
-    """
-    check_labelling_width(g_new, dist)
-    hub_mask = per_plane_hub_mask(landmarks, landmarks, g_new.n)
-    streams = sweep_streams(plan, g_new, hub=hub_mask)
-    if improved:
-        seed, seeded, bound = search_improved_seed(g_new, batch, dist, hub,
-                                                   hub_mask)
-    else:
-        seed, seeded = search_basic_seed(g_new, batch, dist)
-        bound = dist
-    front = plan.frontier.changed_blocks(seeded)
-    full, masked = _search_wave_fns(plan, g_new, seed, bound, hub_mask,
-                                    streams, improved)
-    best = seed
-    for _ in range(sweeps):
-        best, front = frontier_wave(plan, g_new, full, masked, best, front)
-    return (best, front, seed, seeded, bound, hub_mask, streams,
-            jnp.any(front))
-
-
-@partial(jax.jit, static_argnames=("improved", "sweeps"), donate_argnums=(1,))
-def fused_search_chunk_frontier(g_new: Graph, best: jax.Array,
-                                front: jax.Array, seed: jax.Array,
-                                bound: jax.Array, hub_mask: jax.Array,
-                                plan: RelaxPlan, streams: SweepStreams,
-                                improved: bool = True, sweeps: int = 1):
-    """`search_chunk_frontier` with the labelling plane donated."""
-    full, masked = _search_wave_fns(plan, g_new, seed, bound, hub_mask,
-                                    streams, improved)
-    cur = best
-    for _ in range(sweeps):
-        cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-    return cur, front, jnp.any(front)
-
-
-@partial(jax.jit, static_argnames=("sweeps",), donate_argnames=("streams",))
-def fused_repair_start_chunk_frontier(g_new: Graph, aff: jax.Array,
-                                      dist: jax.Array, hub: jax.Array,
-                                      hub_mask: jax.Array, plan: RelaxPlan,
-                                      streams: SweepStreams,
-                                      sweeps: int = 1):
-    """`fused_repair_start_chunk` with frontier waves →
-    (cur, front, streams, changed)."""
-    cur = repair_base_frontier(plan, g_new, aff, key2_make(dist, hub),
-                               hub_mask, streams)
-    front = plan.frontier.changed_blocks(cur < INF_KEY2)
-    streams = repair_streams(plan, g_new, aff, streams)
-    full, masked = _repair_wave_fns(plan, g_new, aff, hub_mask, streams)
-    for _ in range(sweeps):
-        cur, front = frontier_wave(plan, g_new, full, masked, cur, front)
-    return cur, front, streams, jnp.any(front)
-
-
-@partial(jax.jit, static_argnames=("sweeps",), donate_argnums=(1,))
-def fused_repair_chunk_frontier(g_new: Graph, cur: jax.Array,
-                                front: jax.Array, aff: jax.Array,
-                                hub_mask: jax.Array, plan: RelaxPlan,
-                                streams: SweepStreams, sweeps: int = 1):
-    """`repair_chunk_frontier` with the key2 plane donated."""
-    full, masked = _repair_wave_fns(plan, g_new, aff, hub_mask, streams)
-    out = cur
-    for _ in range(sweeps):
-        out, front = frontier_wave(plan, g_new, full, masked, out, front)
-    return out, front, jnp.any(front)
-
-
-# --- fused chunk variants (one dispatch per pipeline phase boundary) -------
-#
-# The unfused pipeline pays one dispatch for the seed plus one per chunk,
-# and every chunk re-reads its input labelling plane from a fresh buffer.
-# The fused variants collapse the seed→first-K-sweeps prefix of each
-# fixpoint into a single executable and *donate* the labelling plane
-# (`best` / `cur`) on every subsequent chunk, so XLA updates it in place
-# instead of allocating per chunk. Donation contract (DESIGN.md §7): a
-# donated plane is invalid the moment the chunk is dispatched — callers
-# must rebind to the chunk's output and never touch the old reference
-# (the pipeline loop below does exactly that; `tests/test_pipeline.py`
-# runs every fused update twice and compares to prove no freed buffer is
-# ever read). The first chunk is safe to donate *because* it is fused
-# with the seed: the unfused pipeline's first chunk receives `best` and
-# `seed` as the same buffer (donating it would invalidate `seed`, which
-# later chunks still read), while `fused_search_start` returns `best` as
-# a fresh output buffer distinct from `seed`.
-
-@partial(jax.jit, static_argnames=("improved", "sweeps"))
-def fused_search_start(g_new: Graph, batch: BatchUpdate, dist: jax.Array,
-                       hub: jax.Array, landmarks: jax.Array,
-                       plan: RelaxPlan | None, improved: bool = True,
-                       sweeps: int = 1):
-    """Seed + first `sweeps` search waves in ONE dispatch.
-
-    Returns (best, seed, seeded, bound, hub_mask, streams, changed).
-    Convergence flag semantics match the unfused seed-then-chunk pair:
-    the fixpoint is monotone, so `best == seed` after `sweeps` waves
-    means settled.
-    """
-    check_labelling_width(g_new, dist)
-    hub_mask = per_plane_hub_mask(landmarks, landmarks, g_new.n)
-    streams = sweep_streams(plan, g_new, hub=hub_mask)
-    if improved:
-        seed, seeded, bound = search_improved_seed(g_new, batch, dist, hub,
-                                                   hub_mask)
-    else:
-        seed, seeded = search_basic_seed(g_new, batch, dist)
-        bound = dist
-    best = _search_waves(plan, g_new, seed, seed, bound, hub_mask, streams,
-                         improved, sweeps)
-    return (best, seed, seeded, bound, hub_mask, streams,
-            jnp.any(best != seed))
-
-
-@partial(jax.jit, static_argnames=("improved", "sweeps"), donate_argnums=(1,))
-def fused_search_chunk(g_new: Graph, best: jax.Array, seed: jax.Array,
-                       bound: jax.Array, hub_mask: jax.Array,
-                       plan: RelaxPlan | None, streams: SweepStreams,
-                       improved: bool = True,
-                       sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
-    """`search_chunk` with the labelling plane donated (updated in place
-    on backends that honor donation; a perf no-op where they don't)."""
-    cur = _search_waves(plan, g_new, best, seed, bound, hub_mask, streams,
-                        improved, sweeps)
-    return cur, jnp.any(cur != best)
-
-
-@partial(jax.jit, static_argnames=("sweeps",), donate_argnames=("streams",))
-def fused_repair_start_chunk(g_new: Graph, aff: jax.Array, dist: jax.Array,
-                             hub: jax.Array, hub_mask: jax.Array,
-                             plan: RelaxPlan | None, streams: SweepStreams,
-                             sweeps: int = 1):
-    """Algo-4 boundary seeding + first `sweeps` interior waves in ONE
-    dispatch → (cur, streams, changed), the repair phase's streams built
-    from the search phase's; returns a fresh `cur` safe to donate."""
-    cur0 = repair_base(plan, g_new, aff, key2_make(dist, hub), hub_mask,
-                       streams)
-    streams = repair_streams(plan, g_new, aff, streams)
-    cur = cur0
-    for _ in range(sweeps):
-        cur = repair_step(plan, g_new, cur, aff, hub_mask, streams)
-    return cur, streams, jnp.any(cur != cur0)
-
-
-@partial(jax.jit, static_argnames=("sweeps",), donate_argnums=(1,))
-def fused_repair_chunk(g_new: Graph, cur: jax.Array, aff: jax.Array,
-                       hub_mask: jax.Array, plan: RelaxPlan | None,
-                       streams: SweepStreams,
-                       sweeps: int = 1) -> tuple[jax.Array, jax.Array]:
-    """`repair_chunk` with the key2 plane donated."""
-    out = cur
-    for _ in range(sweeps):
-        out = repair_step(plan, g_new, out, aff, hub_mask, streams)
-    return out, jnp.any(out != cur)
+                 streams: SweepStreams, front: jax.Array | None = None,
+                 sweeps: int = 1):
+    """`sweeps` interior repair waves in one bounded dispatch →
+    (cur', changed, front')."""
+    return repair_chunk_body(g_new, cur, aff, hub_mask, plan, streams,
+                             front, sweeps)
 
 
 @jax.jit
@@ -487,78 +244,23 @@ def update_finish(aff: jax.Array, settled: jax.Array, dist: jax.Array,
     return HighwayLabelling(landmarks, ndist, nhub, highway)
 
 
-def _mesh_twins(mesh, fused: bool) -> dict:
-    """The `core/shard.py` twins of the chunk programs, behind the
-    unsharded programs' signatures. The mesh bodies build their sweep
-    streams in every wave, so these take the streams and return None
-    for them."""
+def _phase_programs(mesh) -> tuple:
+    """(seed, search chunk, repair start, repair chunk, finish): the
+    unsharded programs, or their `core/shard.py` twins with `mesh`
+    bound. The twins share the signatures (the seed's sweeps nothing, so
+    it takes no plan); their bodies build the sweep streams in every
+    wave, so they take and return None for them."""
+    if mesh is None:
+        return (search_seed, search_chunk, repair_start, repair_chunk,
+                update_finish)
     from repro.core import shard
 
-    search = shard.shard_fused_search_chunk if fused else \
-        shard.shard_search_chunk
-    repair = shard.shard_fused_repair_chunk if fused else \
-        shard.shard_repair_chunk
-    f_search = shard.shard_fused_search_chunk_frontier if fused else \
-        shard.shard_search_chunk_frontier
-    f_repair = shard.shard_fused_repair_chunk_frontier if fused else \
-        shard.shard_repair_chunk_frontier
-
-    def seed(g, b, dist, hub, lms, plan, improved):
-        return (*shard.shard_search_seed(mesh, g, b, dist, hub, lms,
-                                         improved=improved), None)
-
-    def chunk(g, best, seed, bound, hub_mask, plan, streams, improved,
-              sweeps):
-        return search(mesh, g, best, seed, bound, hub_mask, plan,
-                      improved=improved, sweeps=sweeps)
-
-    def fstart(g, b, dist, hub, lms, plan, improved, sweeps):
-        *out, changed = shard.shard_fused_search_start(
-            mesh, g, b, dist, hub, lms, plan, improved=improved,
-            sweeps=sweeps)
-        return (*out, None, changed)
-
-    def rstart(g, aff, dist, hub, hub_mask, plan, streams):
-        return shard.shard_repair_start(mesh, g, aff, dist, hub, hub_mask,
-                                        plan), None
-
-    def rchunk(g, cur, aff, hub_mask, plan, streams, sweeps):
-        return repair(mesh, g, cur, aff, hub_mask, plan, sweeps=sweeps)
-
-    def frstart(g, aff, dist, hub, hub_mask, plan, streams, sweeps):
-        cur, changed = shard.shard_fused_repair_start_chunk(
-            mesh, g, aff, dist, hub, hub_mask, plan, sweeps=sweeps)
-        return cur, None, changed
-
-    def f_chunk(g, best, front, seed, bound, hub_mask, plan, streams,
-                improved, sweeps):
-        return f_search(mesh, g, best, front, seed, bound, hub_mask, plan,
-                        improved=improved, sweeps=sweeps)
-
-    def f_fstart(g, b, dist, hub, lms, plan, improved, sweeps):
-        *out, changed = shard.shard_fused_search_start_frontier(
-            mesh, g, b, dist, hub, lms, plan, improved=improved,
-            sweeps=sweeps)
-        return (*out, None, changed)
-
-    def f_rstart(g, aff, dist, hub, hub_mask, plan, streams):
-        return (*shard.shard_repair_start_frontier(
-            mesh, g, aff, dist, hub, hub_mask, plan), None)
-
-    def f_rchunk(g, cur, front, aff, hub_mask, plan, streams, sweeps):
-        return f_repair(mesh, g, cur, front, aff, hub_mask, plan,
-                        sweeps=sweeps)
-
-    def f_frstart(g, aff, dist, hub, hub_mask, plan, streams, sweeps):
-        cur, front, changed = shard.shard_fused_repair_start_chunk_frontier(
-            mesh, g, aff, dist, hub, hub_mask, plan, sweeps=sweeps)
-        return cur, front, None, changed
-
-    return dict(seed=seed, chunk=chunk, fstart=fstart, rstart=rstart,
-                rchunk=rchunk, frstart=frstart,
-                finish=partial(shard.shard_update_finish, mesh),
-                f_chunk=f_chunk, f_fstart=f_fstart, f_rstart=f_rstart,
-                f_rchunk=f_rchunk, f_frstart=f_frstart)
+    def seed(g_new, batch, dist, hub, landmarks, plan, improved):
+        return shard.shard_search_seed(mesh, g_new, batch, dist, hub,
+                                       landmarks, improved=improved)
+    return (seed,) + tuple(partial(fn, mesh) for fn in (
+        shard.shard_search_chunk, shard.shard_repair_start,
+        shard.shard_repair_chunk, shard.shard_update_finish))
 
 
 # ---------------------------------------------------------------------------
@@ -569,7 +271,7 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
                      plan: RelaxPlan | None = None,
                      g_new: Graph | None = None, mesh=None,
                      improved: bool = True, chunk_sweeps: int = 1,
-                     fused: bool = False, stats: dict | None = None):
+                     stats: dict | None = None):
     """BatchHL update against `snapshot` as a generator of bounded
     dispatches; returns (snapshot N+1, aff[R, V]) via StopIteration.
 
@@ -581,11 +283,6 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
     must be prepared from the post-update snapshot (pass the
     materialized graph as `g_new` to skip the recompute). With `mesh`, chunks run through
     the `core/shard.py` wrappers on the maintenance plane grouping.
-
-    `fused=True` runs the megakernel chunk variants: each phase's
-    seed + first K sweeps fuse into one dispatch, and subsequent chunks
-    donate the labelling plane so sweeps update it in place (same phase
-    tags, same bit-identical result — the fused-parity tests pin it).
 
     `stats`, when given, receives the counts `batchhl_update` gives:
     "update_waves", the waves each fixpoint ran (chunks × chunk_sweeps),
@@ -599,30 +296,8 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
             serve_pending_queries()      # interleaved work goes here
         # StopIteration.value is the (snapshot, aff) result
     """
-    if mesh is None:
-        seed_fn = search_seed
-        chunk_fn = fused_search_chunk if fused else search_chunk
-        fstart_fn = fused_search_start
-        rstart_fn = repair_start
-        rchunk_fn = fused_repair_chunk if fused else repair_chunk
-        frstart_fn = fused_repair_start_chunk
-        finish_fn = update_finish
-        f_chunk_fn = (fused_search_chunk_frontier if fused
-                      else search_chunk_frontier)
-        f_fstart_fn = fused_search_start_frontier
-        f_rstart_fn = repair_start_frontier
-        f_rchunk_fn = (fused_repair_chunk_frontier if fused
-                       else repair_chunk_frontier)
-        f_frstart_fn = fused_repair_start_chunk_frontier
-    else:
-        tw = _mesh_twins(mesh, fused)
-        seed_fn, chunk_fn, fstart_fn = tw["seed"], tw["chunk"], tw["fstart"]
-        rstart_fn, rchunk_fn = tw["rstart"], tw["rchunk"]
-        frstart_fn, finish_fn = tw["frstart"], tw["finish"]
-        f_chunk_fn, f_fstart_fn = tw["f_chunk"], tw["f_fstart"]
-        f_rstart_fn, f_rchunk_fn = tw["f_rstart"], tw["f_rchunk"]
-        f_frstart_fn = tw["f_frstart"]
-
+    seed_fn, chunk_fn, rstart_fn, rchunk_fn, finish_fn = \
+        _phase_programs(mesh)
     lab = snapshot.labelling
     if g_new is None:
         g_new = apply_batch(snapshot.graph, batch)
@@ -632,93 +307,38 @@ def pipelined_update(snapshot: Snapshot, batch: BatchUpdate, *,
     # original post-update weights.
     batch = resolve_seed_weights(snapshot.graph, batch)
 
-    if use_frontier(plan, g_new):
-        # Frontier mode (DESIGN.md §10): swap in the chunk twins that
-        # thread the changed-block bitmap, closing over it so the driver
-        # below (and its yield discipline) stays identical. The bitmap is
-        # chunk-carried state like `best`/`cur`, never surfaced to
-        # callers.
-        fr = {"front": None}
-        base_seed_fn = seed_fn
-
-        def seed_fn(g, b, dist, hub, lms, plan_, improved):
-            out = base_seed_fn(g, b, dist, hub, lms, plan_,
-                               improved=improved)
-            fr["front"] = frontier_seed_blocks(plan, out[1])
-            return out
-
-        def chunk_fn(g, best, seed, bound, hub_mask, plan_, streams,
-                     improved, sweeps):
-            best, fr["front"], changed = f_chunk_fn(
-                g, best, fr["front"], seed, bound, hub_mask, plan_, streams,
-                improved=improved, sweeps=sweeps)
-            return best, changed
-
-        def fstart_fn(g, b, dist, hub, lms, plan_, improved, sweeps):
-            (best, fr["front"], seed, seeded, bound, hub_mask, streams,
-             changed) = f_fstart_fn(g, b, dist, hub, lms, plan_,
-                                    improved=improved, sweeps=sweeps)
-            return best, seed, seeded, bound, hub_mask, streams, changed
-
-        def rstart_fn(g, aff, dist, hub, hub_mask, plan_, streams):
-            cur, fr["front"], streams = f_rstart_fn(g, aff, dist, hub,
-                                                    hub_mask, plan_, streams)
-            return cur, streams
-
-        def rchunk_fn(g, cur, aff, hub_mask, plan_, streams, sweeps):
-            cur, fr["front"], changed = f_rchunk_fn(
-                g, cur, fr["front"], aff, hub_mask, plan_, streams,
-                sweeps=sweeps)
-            return cur, changed
-
-        def frstart_fn(g, aff, dist, hub, hub_mask, plan_, streams, sweeps):
-            cur, fr["front"], streams, changed = f_frstart_fn(
-                g, aff, dist, hub, hub_mask, plan_, streams, sweeps=sweeps)
-            return cur, streams, changed
-
     waves = {"search": 0, "repair": 0}
-    builds = 0
-    if fused:
-        best, seed, seeded, bound, hub_mask, streams, changed = fstart_fn(
-            g_new, batch, lab.dist, lab.hub, lab.landmarks, plan,
-            improved=improved, sweeps=chunk_sweeps)
-        waves["search"] += chunk_sweeps
-    else:
-        seed, seeded, bound, hub_mask, streams = seed_fn(
-            g_new, batch, lab.dist, lab.hub, lab.landmarks, plan,
-            improved=improved)
-        best, changed = seed, True
-    builds += streams is not None
+    seed, seeded, bound, hub_mask, streams = seed_fn(
+        g_new, batch, lab.dist, lab.hub, lab.landmarks, plan,
+        improved=improved)
+    builds = int(streams is not None)
+    # The changed-block bitmap (frontier mode) is carried like `best`/`cur`
+    # and never surfaced to callers; None runs full waves.
+    front = (frontier_seed_blocks(plan, seeded)
+             if use_frontier(plan, g_new) else None)
+    best, changed = seed, True
     jax.block_until_ready(best)
     yield "search-seed"
     while bool(changed):
-        # A donated `best` (fused path) is dead after this dispatch; the
-        # rebind below is the only reference kept.
-        best, changed = chunk_fn(g_new, best, seed, bound, hub_mask, plan,
-                                 streams, improved=improved,
-                                 sweeps=chunk_sweeps)
+        best, changed, front = chunk_fn(
+            g_new, best, seed, bound, hub_mask, plan, streams, front,
+            improved=improved, sweeps=chunk_sweeps)
         waves["search"] += chunk_sweeps
         jax.block_until_ready(best)
         yield "search"
     aff = search_finish(best, seeded, improved=improved)
 
-    # The rebinds of `streams` below drop the search phase's set: only
-    # its weights and hub flags live on, in the repair set.
-    if fused:
-        cur, streams, changed = frstart_fn(g_new, aff, lab.dist, lab.hub,
-                                           hub_mask, plan, streams,
-                                           sweeps=chunk_sweeps)
-        waves["repair"] += chunk_sweeps
-    else:
-        cur, streams = rstart_fn(g_new, aff, lab.dist, lab.hub, hub_mask,
-                                 plan, streams)
-        changed = True
+    # The rebind of `streams` drops the search phase's set: only its
+    # weights and hub flags live on, in the repair set.
+    cur, streams, front = rstart_fn(g_new, aff, lab.dist, lab.hub,
+                                    hub_mask, plan, streams)
+    changed = True
     builds += streams is not None
     jax.block_until_ready(cur)
     yield "repair-seed"
     while bool(changed):
-        cur, changed = rchunk_fn(g_new, cur, aff, hub_mask, plan, streams,
-                                 sweeps=chunk_sweeps)
+        cur, changed, front = rchunk_fn(g_new, cur, aff, hub_mask, plan,
+                                        streams, front, sweeps=chunk_sweeps)
         waves["repair"] += chunk_sweeps
         jax.block_until_ready(cur)
         yield "repair"
@@ -894,20 +514,16 @@ def _selftest() -> None:
     for model in [m for m in (1, 2, 4, 8) if n_dev % m == 0]:
         mesh = make_host_mesh(model=model)
         for backend, pln in (("jnp", None), ("pallas", plan1)):
-            for fused in (False, True):
-                snap = Snapshot(0, g, lab0, pln)
-                nxt, aff = run_pipelined_update(pipelined_update(
-                    snap, batch, plan=pln, mesh=mesh, chunk_sweeps=2,
-                    fused=fused))
-                np.testing.assert_array_equal(np.asarray(aff),
-                                              np.asarray(aff1))
-                for f in ("dist", "hub", "highway"):
-                    np.testing.assert_array_equal(
-                        np.asarray(getattr(nxt.labelling, f)),
-                        np.asarray(getattr(lab1, f)))
-                print(f"mesh (data={mesh.shape['data']}, model={model}) "
-                      f"backend={backend} fused={fused}: "
-                      f"pipelined update bit-parity OK")
+            snap = Snapshot(0, g, lab0, pln)
+            nxt, aff = run_pipelined_update(pipelined_update(
+                snap, batch, plan=pln, mesh=mesh, chunk_sweeps=2))
+            np.testing.assert_array_equal(np.asarray(aff), np.asarray(aff1))
+            for f in ("dist", "hub", "highway"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(nxt.labelling, f)),
+                    np.asarray(getattr(lab1, f)))
+            print(f"mesh (data={mesh.shape['data']}, model={model}) "
+                  f"backend={backend}: pipelined update bit-parity OK")
 
     # End-to-end: pipelined serving on a real mesh (if the device count
     # allows a model axis), every answer checked at its served version.

@@ -279,62 +279,6 @@ def edge_relax_pallas(keys: jax.Array, src_t: jax.Array, dstloc_t: jax.Array,
     return out.reshape(-1)[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("n", "block_v", "nb",
-                                             "interpret"))
-def relax_sweep_pallas(keys: jax.Array, hub_t: jax.Array | None,
-                       src_t: jax.Array,
-                       dstloc_t: jax.Array, mask_t: jax.Array,
-                       w_t: jax.Array,
-                       step: jax.Array, inf: jax.Array, clear_bit: jax.Array,
-                       n: int, block_v: int, interpret: bool = True,
-                       rowblk_t: jax.Array | None = None,
-                       nb: int | None = None) -> jax.Array:
-    """Generalized sweep: keys [V] + per-row hub tiles [S, NR, BV] (None:
-    no hub bit-clearing) + tiled edges/weights [S, NR, W] → [V].
-
-    cand[v] = min over masked edges (u, v) of
-        clear_hub_bit_if_hub(v, sat(keys[u] + step·w(u,v), inf));
-    `inf` if none. The add saturates at `inf` (int32 wrap → inf).
-    The grid walks (vertex shard, tile row); each step owns one disjoint
-    [BV] output tile, so S is a pure launch-structure knob. With a
-    block_e-chunked tiling (`rowblk_t`/`nb` set) several rows feed one
-    destination block and a sorted segment-min folds the per-row partials
-    — bit-identical to the unchunked reduction (min-of-mins).
-
-    The per-edge gathers (source key, destination hub flag) run in XLA;
-    the kernel gets every edge stream as [C, 128] lane rows and the three
-    scalars in SMEM.
-    """
-    s, nr, w = src_t.shape
-    if w % LANES:
-        raise ValueError(f"tile rows must be a multiple of {LANES} wide, "
-                         f"got {w} (see block_edges_topology)")
-    params = jnp.stack([jnp.asarray(step, jnp.int32),
-                        jnp.asarray(inf, jnp.int32),
-                        jnp.asarray(clear_bit, jnp.int32)])
-    lanes = (s, nr, w // LANES, LANES)
-    streams = [jnp.take(keys, src_t, axis=0), dstloc_t, mask_t, w_t]
-    if hub_t is not None:
-        streams.append(jnp.take_along_axis(hub_t, dstloc_t, axis=2))
-    edge = pl.BlockSpec((None, None, w // LANES, LANES),
-                        lambda j, i: (j, i, 0, 0))
-    out = pl.pallas_call(
-        _relax_sweep_kernel,
-        grid=(s, nr),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + [edge] * len(streams),
-        out_specs=pl.BlockSpec((None, None, 1, block_v),
-                               lambda j, i: (j, i, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((s, nr, 1, block_v), jnp.int32),
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel")),
-        interpret=interpret,
-    )(params, *(x.reshape(lanes) for x in streams))
-    out = _reduce_rows(out.reshape(s, nr, block_v), rowblk_t, nb,
-                       jnp.asarray(inf, jnp.int32))
-    return out.reshape(-1)[:n]
-
-
 def _frontier_or_kernel(rowblk_ref, words_ref, dst_ref, o_ref):
     """OR sweep over one tile row: each edge's packed source word lands
     on its destination, `acc |= where(hit, word, 0)` in place of the
@@ -389,7 +333,7 @@ def frontier_or_pallas(words: jax.Array, src_t: jax.Array, dst_t: jax.Array,
     [S, NR] names each row's local destination block (the identity on
     an unchunked tiling). The grid walks (word, vertex shard, tile row)
     and chunked rows fold into their block inside the kernel. Like
-    `relax_sweep_pallas`, the per-edge source gather runs in XLA; here
+    `ops.relax_sweep_pallas`, the per-edge source gather runs in XLA; here
     it is one packed word per slot instead of one key per plane.
     """
     s, nr, w = src_t.shape

@@ -435,11 +435,14 @@ def relax_sweep_pallas(keys: jax.Array, bg: BlockedGraph,
                        interpret: bool = True) -> jax.Array:
     """Launch `kernel._relax_sweep_kernel` over built streams → [V].
 
-    The launch of `kernel.relax_sweep_pallas`, except that the hub flag
-    of each slot arrives built (`streams.hub`) where that wrapper
-    gathers it from per-row hub tiles on every call; the name is kept,
-    since a device trace names the kernel by it. The one per-edge
-    gather left here is the source keys'. With a block_e-chunked tiling
+    cand[v] = min over masked edges (u, v) of
+        clear_hub_bit_if_hub(v, sat(keys[u] + step·w(u,v), inf));
+    `inf` if none. Every edge stream arrives built (`streams`: mask,
+    weights and, when bits clear, each slot's hub flag) as [C, 128] lane
+    rows, and the three scalars in SMEM; the one per-edge gather left
+    here is the source keys'. A device trace names the kernel by this
+    launch's name. The grid walks (vertex shard, tile row), each step
+    owning one disjoint [BV] output tile; with a block_e-chunked tiling
     a sorted segment-min folds the per-row partials (min-of-mins).
     """
     s, nr, w = bg.src_t.shape

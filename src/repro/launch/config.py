@@ -9,7 +9,7 @@ surface into five composable specs —
   * `GraphSpec`       — the graph under service (family, size, capacity,
                         grow-in-place policy)
   * `EngineSpec`      — the relaxation engine + mesh (backend, tiling,
-                        autotune/fusion, shard_map axes)
+                        autotune, frontier, shard_map axes)
   * `StreamSpec`      — the workload (update batches, scenario, open-loop
                         query stream, serving mode, verification)
   * `CheckpointSpec`  — durability (checkpoint dir, resume, prune keep)
@@ -97,8 +97,6 @@ class EngineSpec:
                         "shape and adopt the fastest (DESIGN.md §7)")
     tune_table: str | None = _f(None, "on-disk tuning table path (implies "
                                 "--autotune)", arg_type=str)
-    fused: bool = _f(False, "pipelined chunks as fused megakernel "
-                     "dispatches with donated planes (DESIGN.md §7)")
     frontier: bool = _f(False, "frontier-proportional sweeps: relax only "
                         "the tile rows the batch's change frontier touches, "
                         "falling back to full sweeps past the density "

@@ -6,7 +6,7 @@ win is capped by a single process's query throughput. This module splits
 the single-writer/many-reader seam that `SnapshotStore` already implies
 in-process across *process* boundaries (DESIGN.md §9):
 
-* **updater** — runs the (pipelined, fused, autotuned) batch-update loop
+* **updater** — runs the (pipelined, autotuned) batch-update loop
   of `ServeLoop` with the query stream turned off, and commits each
   version *durably*: the step tree is fsync'd and atomically renamed by
   `core/snapshot.save_snapshot`, and only then is the ``CURRENT``

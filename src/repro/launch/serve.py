@@ -131,13 +131,11 @@ class ServeConfig:
     use_minplus_kernel: bool = False
     mesh: str = "none"
     shards: int = 1
-    # autotuning + fusion (DESIGN.md §7)
+    # autotuning (DESIGN.md §7)
     autotune: bool = False       # measure & adopt the fastest sweep impl
                                  # per snapshot shape (core/autotune.py)
     tune_table: str | None = None  # on-disk tuning table; restarts skip
                                    # the measurement entirely
-    fused: bool = False          # pipelined chunks as fused megakernel
-                                 # dispatches with donated planes
     # frontier-proportional sweeps (DESIGN.md §10)
     frontier: bool = False       # relax only the tile rows the change
                                  # frontier touches (masked sweeps)
@@ -533,7 +531,7 @@ class ServeLoop:
         upd = pipelined_update(snap, batch, plan=plan, g_new=g_next,
                                mesh=self.mesh, improved=True,
                                chunk_sweeps=cfg.chunk_sweeps,
-                               fused=cfg.fused, stats=counts)
+                               stats=counts)
         head = snap.version + 1
         for chunk in itertools.count():
             with self.trace.span("serve.update_chunk", tick=tick,

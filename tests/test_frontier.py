@@ -12,9 +12,10 @@ the frontier is nonempty). Bit-identity is the whole contract — the
 frontier is a performance mode, never an approximation — so every
 assertion here is exact array equality, not allclose.
 
-A slow-marked subprocess repeats the check on a forced 8-device host
-mesh through the pipelined chunked updater (the `shard_*_frontier`
-twins), against the unsharded full-sweep reference.
+The pipelined chunked updater is checked the same way, its chunk
+programs threading the bitmap across dispatches; a slow-marked
+subprocess repeats that on a forced 8-device host mesh (the
+`shard_*` chunk twins), against the unsharded full-sweep reference.
 """
 from __future__ import annotations
 
@@ -40,6 +41,8 @@ from repro.graphs.coo import apply_batch, from_edges, make_batch
 from repro.core.batch import batchhl_update
 from repro.core.construct import build_labelling, select_landmarks_by_degree
 from repro.core.engine import RelaxEngine
+from repro.core.snapshot import (Snapshot, pipelined_update,
+                                 run_pipelined_update)
 
 BACKENDS = ("jnp", "pallas")
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -75,7 +78,7 @@ def _one_tick(g, batch, lab, g_next, engine):
 
 
 def _check_case(backend, n, seed, n_ins, n_del, n_rew, max_w, threshold,
-                improved):
+                improved, pipelined=False):
     edges = gen.random_connected(n, extra_edges=n // 2, seed=seed)
     g = from_edges(n, edges, edges.shape[0] + 16)
     lab = build_labelling(g, select_landmarks_by_degree(g, 3))
@@ -94,10 +97,18 @@ def _check_case(backend, n, seed, n_ins, n_del, n_rew, max_w, threshold,
                          g_new=g_next)
     fr_engine = RelaxEngine(backend=backend, block_v=16, frontier=True,
                             frontier_threshold=threshold, frontier_block=8)
-    got = batchhl_update(g, batch, lab, improved,
-                         plan=fr_engine.prepare(g_next), g_new=g_next)
+    fr_plan = fr_engine.prepare(g_next)
+    if pipelined:
+        nxt, aff = run_pipelined_update(pipelined_update(
+            Snapshot(0, g, lab, None), batch, plan=fr_plan, g_new=g_next,
+            improved=improved, chunk_sweeps=2))
+        got = (nxt.graph, nxt.labelling, aff)
+    else:
+        got = batchhl_update(g, batch, lab, improved, plan=fr_plan,
+                             g_new=g_next)
     _assert_same(ref, got,
-                 f"[backend={backend} th={threshold} improved={improved}]")
+                 f"[backend={backend} th={threshold} improved={improved} "
+                 f"pipelined={pipelined}]")
 
 
 # Representative corners, one per row: pure inserts, pure deletes, pure
@@ -123,6 +134,16 @@ def test_frontier_update_bit_identical(backend, case):
     """Masked ≡ full across mixed batches, backends, and the threshold's
     boundary behaviours (fallback-always / default / masked-always)."""
     _check_case(backend, *case)
+
+
+@pytest.mark.parametrize("backend", BACKENDS)
+@pytest.mark.parametrize("case", CASES,
+                         ids=[f"n{c[0]}s{c[1]}" for c in CASES])
+def test_frontier_pipelined_bit_identical(backend, case):
+    """The pipelined update's chunk programs, carrying the changed-block
+    bitmap across dispatches, drain to the full-sweep `batchhl_update`
+    bit for bit on the same corners."""
+    _check_case(backend, *case, pipelined=True)
 
 
 if HAVE_HYPOTHESIS:
@@ -198,18 +219,16 @@ _MESH_SCRIPT = textwrap.dedent("""
     _, labref, affref = batchhl_update(g, batch, lab, True, None)
     mesh = make_host_mesh(model=2)
     for backend in ("jnp", "pallas"):
-        for fused in (False, True):
-            eng = RelaxEngine(backend=backend, block_v=64, frontier=True)
-            plan = eng.prepare(g_new)
-            snap = Snapshot(0, g, lab, plan)
-            s1, aff = run_pipelined_update(pipelined_update(
-                snap, batch, plan=plan, g_new=g_new, mesh=mesh,
-                improved=True, chunk_sweeps=2, fused=fused))
-            assert bool(jnp.all(aff == affref)), (backend, fused)
-            for f in ("dist", "hub", "highway"):
-                assert bool(jnp.all(getattr(s1.labelling, f)
-                                    == getattr(labref, f))), \\
-                    (backend, fused, f)
+        eng = RelaxEngine(backend=backend, block_v=64, frontier=True)
+        plan = eng.prepare(g_new)
+        snap = Snapshot(0, g, lab, plan)
+        s1, aff = run_pipelined_update(pipelined_update(
+            snap, batch, plan=plan, g_new=g_new, mesh=mesh,
+            improved=True, chunk_sweeps=2))
+        assert bool(jnp.all(aff == affref)), backend
+        for f in ("dist", "hub", "highway"):
+            assert bool(jnp.all(getattr(s1.labelling, f)
+                                == getattr(labref, f))), (backend, f)
     print("MESH FRONTIER PARITY OK")
 """)
 
@@ -217,7 +236,7 @@ _MESH_SCRIPT = textwrap.dedent("""
 @pytest.mark.slow
 def test_frontier_mesh_multidevice_parity(tmp_path):
     """Masked ≡ full through the sharded pipelined updater on a forced
-    8-device host mesh, both backends, fused and unfused."""
+    8-device host mesh, both backends."""
     script = tmp_path / "mesh_frontier_parity.py"
     script.write_text(_MESH_SCRIPT)
     env = dict(os.environ)

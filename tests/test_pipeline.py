@@ -55,7 +55,7 @@ def _instance(seed=3, n=150, extra=200, r=8):
 # --- chunked update ≡ monolithic update ------------------------------------
 
 @pytest.mark.parametrize("improved", [True, False])
-@pytest.mark.parametrize("chunk_sweeps", [1, 3])
+@pytest.mark.parametrize("chunk_sweeps", [1, 2, 3])
 def test_pipelined_update_matches_monolithic(improved, chunk_sweeps):
     g, lab, batch = _instance()
     gm, labm, affm = batchhl_update(g, batch, lab, improved=improved)
@@ -132,80 +132,38 @@ def test_resolve_seed_weights_semantics():
                                       np.asarray(getattr(batch, f)))
 
 
-def test_pipelined_update_pallas_plan():
-    """The chunked path composes with a prepared Pallas tiling."""
-    g, lab, batch = _instance()
-    gm, labm, affm = batchhl_update(g, batch, lab)
-    g_next = apply_batch(g, batch)
-    plan = RelaxEngine(backend="pallas", block_v=32,
-                       shards=2).prepare(g_next)
-    nxt, aff = run_pipelined_update(pipelined_update(
-        Snapshot(0, g, lab, None), batch, plan=plan, g_new=g_next))
-    np.testing.assert_array_equal(np.asarray(aff), np.asarray(affm))
-    np.testing.assert_array_equal(np.asarray(nxt.labelling.dist),
-                                  np.asarray(labm.dist))
-
-
-def test_pipelined_update_mesh_matches():
-    """Mesh chunks (maintenance plane grouping) ≡ unsharded monolith."""
-    from repro.launch.mesh import make_host_mesh
-    g, lab, batch = _instance()
-    gm, labm, affm = batchhl_update(g, batch, lab)
-    nxt, aff = run_pipelined_update(pipelined_update(
-        Snapshot(0, g, lab, None), batch, mesh=make_host_mesh(),
-        chunk_sweeps=2))
-    np.testing.assert_array_equal(np.asarray(aff), np.asarray(affm))
-    for f in ("dist", "hub", "highway"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(nxt.labelling, f)),
-            np.asarray(getattr(labm, f)))
-
-
-# --- fused megakernel chunks ≡ monolithic update ---------------------------
-
-@pytest.mark.parametrize("improved", [True, False])
-@pytest.mark.parametrize("chunk_sweeps", [1, 2, 3])
-def test_fused_update_matches_monolithic(improved, chunk_sweeps):
-    """The fused path (seed + K sweeps in one dispatch, later chunks
-    donating the labelling plane) is bit-identical to `batchhl_update`
-    for every chunk size × variant."""
-    g, lab, batch = _instance()
-    gm, labm, affm = batchhl_update(g, batch, lab, improved=improved)
-    nxt, aff = run_pipelined_update(pipelined_update(
-        Snapshot(0, g, lab, None), batch, improved=improved,
-        chunk_sweeps=chunk_sweeps, fused=True))
-    np.testing.assert_array_equal(np.asarray(aff), np.asarray(affm))
-    for f in ("dist", "hub", "highway"):
-        np.testing.assert_array_equal(
-            np.asarray(getattr(nxt.labelling, f)),
-            np.asarray(getattr(labm, f)))
-    np.testing.assert_array_equal(np.asarray(nxt.graph.valid),
-                                  np.asarray(gm.valid))
-
-
-@pytest.mark.parametrize("impl", ["kernel", "sorted"])
-def test_fused_update_pallas_plans(impl):
-    """Fused chunks compose with both Pallas plan impls: the tiled
-    kernel tiling and the autotuned dst-sorted twin."""
-    g, lab, batch = _instance()
-    gm, labm, affm = batchhl_update(g, batch, lab)
-    g_next = apply_batch(g, batch)
+def _pallas_plan(g_next, impl: str):
+    """A Pallas plan of `impl`: the tiled kernel tiling, or the
+    autotuned dst-sorted twin."""
     if impl == "kernel":
         engine = RelaxEngine(backend="pallas", block_v=32, shards=2)
     else:
         engine = RelaxEngine(backend="pallas", block_v=32, autotune=True)
     plan = engine.prepare(g_next)
     assert plan.impl == impl
+    return plan
+
+
+@pytest.mark.parametrize("chunk_sweeps", [1, 2])
+@pytest.mark.parametrize("impl", ["kernel", "sorted"])
+def test_pipelined_update_pallas_plan(impl, chunk_sweeps):
+    """The chunked path composes with both Pallas plan impls, at one
+    sweep a chunk (the served configuration) and at two."""
+    g, lab, batch = _instance()
+    gm, labm, affm = batchhl_update(g, batch, lab)
+    g_next = apply_batch(g, batch)
+    plan = _pallas_plan(g_next, impl)
     nxt, aff = run_pipelined_update(pipelined_update(
         Snapshot(0, g, lab, None), batch, plan=plan, g_new=g_next,
-        fused=True, chunk_sweeps=2))
+        chunk_sweeps=chunk_sweeps))
     np.testing.assert_array_equal(np.asarray(aff), np.asarray(affm))
     np.testing.assert_array_equal(np.asarray(nxt.labelling.dist),
                                   np.asarray(labm.dist))
 
 
-def test_fused_update_mesh_matches():
-    """Fused mesh twins (pmax convergence + donated mesh plane) ≡ the
+@pytest.mark.parametrize("chunk_sweeps", [1, 2])
+def test_pipelined_update_mesh_matches(chunk_sweeps):
+    """Mesh chunks (maintenance plane grouping, pmax convergence) ≡
     unsharded monolith on this session's device mesh; the full
     factorization sweep lives in `repro.core.snapshot._selftest`."""
     from repro.launch.mesh import make_host_mesh
@@ -213,7 +171,7 @@ def test_fused_update_mesh_matches():
     gm, labm, affm = batchhl_update(g, batch, lab)
     nxt, aff = run_pipelined_update(pipelined_update(
         Snapshot(0, g, lab, None), batch, mesh=make_host_mesh(),
-        chunk_sweeps=2, fused=True))
+        chunk_sweeps=chunk_sweeps))
     np.testing.assert_array_equal(np.asarray(aff), np.asarray(affm))
     for f in ("dist", "hub", "highway"):
         np.testing.assert_array_equal(
@@ -221,18 +179,23 @@ def test_fused_update_mesh_matches():
             np.asarray(getattr(labm, f)))
 
 
-def test_fused_donation_safety():
-    """Donation must never alias live inputs: running the identical
-    fused update twice from the same snapshot gives the same bits, and
-    the input labelling survives both runs untouched (a donated-buffer
-    reuse would corrupt one or the other)."""
+def test_pipelined_donation_safety():
+    """`repair_start` donates the search phase's streams: the donation
+    must never free a live buffer. Running the identical update twice
+    from one snapshot and one Pallas plan gives the same bits, and the
+    input labelling and the plan's tiles survive both runs untouched (a
+    stream that aliased a plan or labelling buffer would be freed by the
+    first run and corrupt, or fail, the second)."""
     g, lab, batch = _instance()
+    g_next = apply_batch(g, batch)
+    plan = _pallas_plan(g_next, "kernel")
     before = {f: np.array(getattr(lab, f)) for f in ("dist", "hub",
                                                      "highway")}
+    tiles = [np.array(x) for x in jax.tree.leaves(plan)]
     outs = []
     for _ in range(2):
         nxt, aff = run_pipelined_update(pipelined_update(
-            Snapshot(0, g, lab, None), batch, fused=True, chunk_sweeps=1))
+            Snapshot(0, g, lab, None), batch, plan=plan, g_new=g_next))
         outs.append((np.asarray(aff),
                      {f: np.asarray(getattr(nxt.labelling, f))
                       for f in before}))
@@ -241,6 +204,8 @@ def test_fused_donation_safety():
         np.testing.assert_array_equal(outs[0][1][f], outs[1][1][f])
         np.testing.assert_array_equal(np.asarray(getattr(lab, f)),
                                       before[f])
+    for want, got in zip(tiles, jax.tree.leaves(plan), strict=True):
+        np.testing.assert_array_equal(np.asarray(got), want)
 
 
 # --- pipelined serving: exact at the served version ------------------------
@@ -353,8 +318,8 @@ def test_chunk_wave_gathers_only_source_keys(program, sweeps):
         args = (g_new, seed, seed, bound, hub_mask, plan, streams)
     else:
         aff = seeded | (seed < jnp.max(seed))
-        _, streams = repair_start(g_new, aff, lab.dist, lab.hub, hub_mask,
-                                  plan, streams)
+        _, streams, _ = repair_start(g_new, aff, lab.dist, lab.hub,
+                                     hub_mask, plan, streams)
         fn = partial(repair_chunk, sweeps=sweeps)
         args = (g_new, lab.key2(), aff, hub_mask, plan, streams)
     planes = lab.dist.shape[0]

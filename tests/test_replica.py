@@ -327,7 +327,7 @@ def test_ack_barrier_times_out_on_live_laggard(tmp_path):
 def _nondefault_spec() -> ServeSpec:
     return ServeSpec(
         graph=GraphSpec(n=500, deg=3, landmarks=8, capacity=640, grow=True),
-        engine=EngineSpec(backend="pallas", block_v=128, fused=True),
+        engine=EngineSpec(backend="pallas", block_v=128, frontier=True),
         stream=StreamSpec(batches=3, qps=123.5, pipeline=True, verify=True),
         topology=TopologySpec(readers=3, coalesce_ms=5.0, restart=True))
 
